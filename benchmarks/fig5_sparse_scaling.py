@@ -20,13 +20,15 @@ factor — ~1.6 GB at N=10k, plus an O(N^3) factorization on one CPU core)
 per-iteration time should scale ~linearly in N (acceptance: the measured
 scaling exponent over the sweep stays near 1, far from quadratic).
 
-`--devices 1,2,4,8` adds a device-count column: per count, a subprocess
-with that many forced host devices times the row-sharded backend
-(sparse/sharding.py) on a (devices, 1) mesh — the XLA device count must be
-fixed before jax initializes, hence the subprocess per count.  On one CPU
-core the emulated devices share the core, so this measures sharding
-OVERHEAD (psum + padding), not speedup; on real hardware the same flag
-wiring gives the scaling curve.
+`--devices 1,2,4,8` adds a device-count column: per count, the
+row-sharded backend (sparse/sharding.py) on a (devices, 1) mesh.  A count
+this process can see runs in process over its first devices (on a TPU
+host, where the process holds the chips); a larger count on the CPU
+backend runs in a child with that many forced host devices, since the
+CPU device count is fixed before jax initializes.  On one CPU core the
+emulated devices share the core, so this measures sharding OVERHEAD
+(psum + padding), not speedup.  A count that fails prints a FAILED row
+and the run exits non-zero.
 
 The JSON output is keyed {model: {n: columns}} and MERGES into an
 existing `--out` file at the model level, so successive runs (e.g. an ee
@@ -191,10 +193,10 @@ def sharded_point(Y: Array, mesh, kind: str, lam: float, iters: int,
 _WORKER_MARK = "FIG5_WORKER_JSON "
 
 
-def _sharded_worker(n_devices: int, ns, kind, lam, iters, perplexity, k, m,
-                    dim) -> None:
-    """Child-process entry: jax was initialized with `n_devices` forced
-    host devices (XLA_FLAGS set by the parent before spawn)."""
+def _sharded_rows(n_devices: int, ns, kind, lam, iters, perplexity, k, m,
+                  dim) -> dict:
+    """sharded_point rows over a (n_devices, 1) mesh of this process's
+    first `n_devices` devices: {n: row}."""
     from repro.launch.mesh import axis_types_kwargs
 
     assert len(jax.devices()) >= n_devices, (len(jax.devices()), n_devices)
@@ -206,42 +208,77 @@ def _sharded_worker(n_devices: int, ns, kind, lam, iters, perplexity, k, m,
         Y, _ = mnist_like(n=n, dim=dim)
         out[n] = sharded_point(jnp.asarray(Y), mesh, kind, lam, iters,
                                perplexity, k, m)
-    print(_WORKER_MARK + json.dumps(out), flush=True)
+    return out
+
+
+def _sharded_worker(n_devices: int, ns, kind, lam, iters, perplexity, k, m,
+                    dim) -> None:
+    """Child-process entry: jax was initialized with `n_devices` forced
+    host devices (XLA_FLAGS set by the parent before spawn)."""
+    rows = _sharded_rows(n_devices, ns, kind, lam, iters, perplexity, k, m,
+                         dim)
+    print(_WORKER_MARK + json.dumps(rows), flush=True)
+
+
+def _sharded_child(dev, ns, kind, lam, iters, perplexity, k, m, dim):
+    """sharded_point rows from a child process with `dev` forced host
+    devices (the CPU backend fixes its device count at start-up), or the
+    child's last error line when it failed."""
+    env = dict(os.environ)
+    # keep the parent's other XLA flags (identical configs for the
+    # sparse vs sharded columns), replacing only the device count
+    inherited = [f for f in env.get("XLA_FLAGS", "").split()
+                 if not f.startswith("--xla_force_host_platform_device_count")]
+    env["XLA_FLAGS"] = " ".join(
+        inherited + [f"--xla_force_host_platform_device_count={dev}"])
+    argv = [sys.executable, "-m", "benchmarks.fig5_sparse_scaling",
+            "--worker-devices", str(dev),
+            "--ns", ",".join(str(n) for n in ns), "--model", kind,
+            "--lam", str(lam), "--iters", str(iters), "--k", str(k),
+            "--perplexity", str(perplexity), "--m", str(m),
+            "--dim", str(dim)]
+    proc = subprocess.run(argv, capture_output=True, text=True, env=env)
+    if proc.returncode != 0:
+        return None, (proc.stderr.strip().splitlines()[-1] if proc.stderr
+                      else f"rc={proc.returncode}")
+    payload = [ln for ln in proc.stdout.splitlines()
+               if ln.startswith(_WORKER_MARK)]
+    return {int(n): row for n, row in
+            json.loads(payload[-1][len(_WORKER_MARK):]).items()}, None
 
 
 def _run_sharded_sweep(devices, ns, kind, lam, iters, perplexity, k, m,
                        dim) -> dict:
-    """Per device count, spawn a subprocess with that many forced host
-    devices and collect its sharded_point rows: {n: {n_devices: row}}."""
+    """Per device count, the sharded_point rows: {n: {n_devices: row}}.
+
+    A count this process can see runs in process over its first `dev`
+    devices — the only way on an accelerator, where the parent already
+    holds the chips.  On the CPU backend a larger count needs a child
+    with that many forced host devices.  Any failed count prints a
+    FAILED row and the sweep raises once every count has run."""
     out: dict = {n: {} for n in ns}
+    failed = []
     for dev in devices:
-        env = dict(os.environ)
-        # keep the parent's other XLA flags (identical configs for the
-        # sparse vs sharded columns), replacing only the device count
-        inherited = [f for f in env.get("XLA_FLAGS", "").split()
-                     if not f.startswith("--xla_force_host_platform_device_count")]
-        env["XLA_FLAGS"] = " ".join(
-            inherited + [f"--xla_force_host_platform_device_count={dev}"])
-        argv = [sys.executable, "-m", "benchmarks.fig5_sparse_scaling",
-                "--worker-devices", str(dev),
-                "--ns", ",".join(str(n) for n in ns), "--model", kind,
-                "--lam", str(lam), "--iters", str(iters), "--k", str(k),
-                "--perplexity", str(perplexity), "--m", str(m),
-                "--dim", str(dim)]
-        proc = subprocess.run(argv, capture_output=True, text=True, env=env)
-        if proc.returncode != 0:
-            csv_row("fig5", kind, f"sharded@{dev}dev", "FAILED",
-                    proc.stderr.strip().splitlines()[-1] if proc.stderr
-                    else "")
+        args = (dev, ns, kind, lam, iters, perplexity, k, m, dim)
+        if dev <= jax.device_count():
+            rows, err = _sharded_rows(*args), None
+        elif jax.default_backend() == "cpu":
+            rows, err = _sharded_child(*args)
+        else:
+            rows, err = None, (f"{dev} devices requested, "
+                               f"{jax.device_count()} visible")
+        if rows is None:
+            csv_row("fig5", kind, f"sharded@{dev}dev", "FAILED", err)
+            failed.append(f"{dev}: {err}")
             continue
-        payload = [ln for ln in proc.stdout.splitlines()
-                   if ln.startswith(_WORKER_MARK)]
-        rows = json.loads(payload[-1][len(_WORKER_MARK):])
-        for n_str, row in rows.items():
-            out[int(n_str)][dev] = row
-            csv_row("fig5", kind, f"sharded@{dev}dev", int(n_str),
+        for n, row in rows.items():
+            out[n][dev] = row
+            csv_row("fig5", kind, f"sharded@{dev}dev", n,
                     f"{row['build_s']:.2f}", f"{row['iter_s']:.4f}",
                     f"{row['energy']:.6g}")
+    if failed:
+        raise RuntimeError(f"sharded sweep failed for device counts "
+                           f"{failed}")
     return out
 
 

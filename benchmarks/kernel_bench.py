@@ -4,14 +4,14 @@
 
 Times the two kernel hot spots (`kernels.ops.ell_lap_matvec` and
 `pairwise_terms`) per shape on three dispatch variants — the jnp oracle
-path, Pallas with the old fixed block_rows=256 tiling, and the
-autotuner's pick — plus a bfloat16-storage run of the autotuned path.
-On CPU every Pallas run is interpret-mode, so the absolute numbers model
-the paper's scaling, not TPU wall-clock; the *ratio* autotuned/fixed is
-still meaningful (the autotuner times the same interpret paths it
-serves) and is what the CI gate checks (autotuned must not lose to the
-fixed tiling it replaced — kernels/autotune.py keeps 256 in every
-candidate list, so this holds by construction up to timing noise).
+path, Pallas with the old fixed block_rows=256 tiling, and the tile
+selector's pick (the "autotuned" columns) — plus a bfloat16-storage run
+of the selected path.  On CPU every Pallas run is interpret-mode, so the
+absolute numbers model the paper's scaling, not TPU wall-clock; the
+*ratio* autotuned/fixed is what the CI gate checks (the selected tile
+must not lose to the fixed tiling — kernels/autotune.py puts 256 first
+in every candidate list and takes the first that compiles, so the two
+are the same tile wherever 256 is legal).
 
 The gated ratio compares the autotuner's *chosen config* re-timed
 through the explicit-block_rows code path against fixed 256 through that

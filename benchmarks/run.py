@@ -33,6 +33,8 @@ def main() -> None:
                          "the committed results/fig5.json baseline")
     a, _ = ap.parse_known_args()
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     os.makedirs("results", exist_ok=True)
     print("table,fields...,derived")
     if a.smoke:

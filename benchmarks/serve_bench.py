@@ -15,11 +15,13 @@ numbers the CI serve gate compares against the committed
     <= 1e-5 (the rowwise solver is batch-invariant, so this is exact on
     one device; the budget only absorbs XLA reduction-order tiling).
 
-`--http-smoke` instead drives the wire path: saves an artifact, launches
-`python -m repro.serve.http` as a SUBPROCESS, fires concurrent HTTP
-clients at it, checks response parity and p99, then SIGTERMs and verifies
-the graceful drain (exit code 0).  The CI serve-smoke job runs exactly
-this.
+`--http-smoke` instead drives the wire path: a first child process fits,
+saves the artifact and computes the direct-transform reference; then
+`python -m repro.serve.http` runs as a second child, concurrent HTTP
+clients hit it, response parity and p99 are checked, and a SIGTERM must
+drain it gracefully (exit code 0).  The parent never computes with JAX,
+so on an accelerator the server child can hold the chip.  The CI
+serve-smoke job runs exactly this.
 
     PYTHONPATH=src python -m benchmarks.serve_bench [--http-smoke]
 """
@@ -126,6 +128,24 @@ def run(n=512, n_queries=64, kind="ee", iters=30, perplexity=8.0,
     return out
 
 
+def http_prepare(out_dir: str, n=300, n_queries=12, kind="ee", iters=20,
+                 perplexity=8.0) -> None:
+    """First child of `http_smoke`: fit, save `model.npz`, and write the
+    queries with their direct-transform answers to `reference.npz`."""
+    Y, est = _problem(n, kind, iters, perplexity, dim=8)
+    rng = np.random.default_rng(2)
+    Yq = (Y[rng.choice(n, size=n_queries, replace=False)]
+          + rng.normal(scale=0.01, size=(n_queries, Y.shape[1]))
+          .astype(np.float32))
+    # the HTTP CLI serves the DEFAULT rowwise spec; the parity reference
+    # must resolve the same way (same iters/negatives/tol from est.spec)
+    tspec = TransformSpec(solver="rowwise")
+    direct = np.asarray(est.transform(Yq, spec=tspec))
+    est.save(os.path.join(out_dir, "model.npz"))
+    np.savez(os.path.join(out_dir, "reference.npz"), queries=Yq,
+             direct=direct)
+
+
 def http_smoke(n=300, n_queries=12, kind="ee", iters=20, perplexity=8.0,
                n_clients=4, p99_budget_ms=None) -> dict:
     """End-to-end wire check for CI: subprocess HTTP server from a saved
@@ -139,30 +159,27 @@ def http_smoke(n=300, n_queries=12, kind="ee", iters=20, perplexity=8.0,
     if p99_budget_ms is None:
         p99_budget_ms = float(os.environ.get("SERVE_P99_BUDGET_MS", 30000))
 
-    Y, est = _problem(n, kind, iters, perplexity, dim=8)
-    rng = np.random.default_rng(2)
-    Yq = (Y[rng.choice(n, size=n_queries, replace=False)]
-          + rng.normal(scale=0.01, size=(n_queries, Y.shape[1]))
-          .astype(np.float32))
-    # the HTTP CLI serves the DEFAULT rowwise spec; the parity reference
-    # must resolve the same way (same iters/negatives/tol from est.spec)
-    tspec = TransformSpec(solver="rowwise")
-    direct = np.asarray(est.transform(Yq, spec=tspec))
-
     import socket
     s = socket.socket()
     s.bind(("127.0.0.1", 0))
     port = s.getsockname()[1]
     s.close()
 
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.pathsep.join(
+        [os.path.join(root, "src"), root, env.get("PYTHONPATH", "")])
     with tempfile.TemporaryDirectory() as d:
+        # one process per chip: the fit and the reference run in a child
+        # that exits before the server child starts
+        subprocess.run(
+            [sys.executable, "-m", "benchmarks.serve_bench",
+             "--http-prepare", d, "--n", str(n), "--queries",
+             str(n_queries), "--kind", kind, "--iters", str(iters)],
+            env=env, check=True)
         path = os.path.join(d, "model.npz")
-        est.save(path)
-        env = dict(os.environ)
-        env["PYTHONPATH"] = os.pathsep.join(
-            [os.path.join(os.path.dirname(os.path.dirname(
-                os.path.abspath(__file__))), "src"),
-             env.get("PYTHONPATH", "")])
+        with np.load(os.path.join(d, "reference.npz")) as ref:
+            Yq, direct = ref["queries"], ref["direct"]
         proc = subprocess.Popen(
             [sys.executable, "-m", "repro.serve.http", "--artifact", path,
              "--port", str(port), "--max-batch", "8",
@@ -258,7 +275,12 @@ def main():
     ap.add_argument("--http-smoke", action="store_true",
                     help="run the subprocess HTTP end-to-end check "
                          "instead of the in-process load benchmark")
+    ap.add_argument("--http-prepare", default=None, help=argparse.SUPPRESS)
     a = ap.parse_args()
+    if a.http_prepare is not None:      # internal: http_smoke's first child
+        http_prepare(a.http_prepare, n=a.n, n_queries=a.queries,
+                     kind=a.kind, iters=a.iters)
+        return
     if a.http_smoke:
         http_smoke(kind=a.kind)
         return

@@ -39,8 +39,7 @@ import threading
 import jax
 
 # The per-compile signal: fires once for every XLA backend compilation,
-# including first-touch eager ops.  Stable across the jax versions CI
-# exercises (0.4.x and 0.7.x).
+# including first-touch eager ops.
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 _lock = threading.Lock()

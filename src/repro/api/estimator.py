@@ -72,10 +72,14 @@ class Embedding:
             strategy=self.spec.strategy)
 
     def _mesh_for(self, backend: str):
-        if registries.BACKENDS[backend].needs_mesh and self.mesh is None:
-            from repro.launch.mesh import make_host_mesh
+        """The mesh a mesh backend runs on, with Auto axes: a caller's mesh
+        built by plain `jax.make_mesh` (Explicit axes) is converted."""
+        if not registries.BACKENDS[backend].needs_mesh:
+            return self.mesh
+        from repro.launch.mesh import auto_axes, make_host_mesh
+        if self.mesh is None:
             self.mesh = make_host_mesh()
-        return self.mesh
+        return auto_axes(self.mesh)
 
     def fit(self, Y: Array | None, X0: Array | None = None,
             aff=None,

@@ -72,7 +72,9 @@ class EmbedSpec:
                                    # | 'jnp' — forwarded to kernels.ops;
                                    # 'auto' = Pallas on TPU, jnp elsewhere
     kernel_precision: str = "float32"   # 'float32' | 'bfloat16' storage
-                                        # (accumulation is always f32)
+                                        # (accumulation is always f32;
+                                        # saves memory on `dense` only —
+                                        # the gather backends just round)
     # Barnes-Hut tree backend (docs/farfield.md)
     theta: float = 0.5            # opening criterion; 0 = exact (O(N^2))
     tree_depth: int = 0           # finest grid level; 0 => auto (log4 N/4)
@@ -158,7 +160,8 @@ class TransformSpec:
                                   # summed over every training anchor)
     knn_method: str = "auto"      # cross-kNN: 'exact'|'approx'|'auto'
     solver: str = "engine"        # 'engine' | 'rowwise' (batch-invariant)
-    batch_size: int = 0           # rowwise chunking cap; 0 => one batch
+    batch_size: int = 0           # rowwise rows per solve program;
+                                  # 0 => transform.ROW_CHUNK
     tol: float | None = None      # None => EmbedSpec.tol
     seed: int = 0                 # negative-anchor draw (sampled mode)
     # approx cross-kNN knobs (sparse/graph.py::knn_cross_approx)

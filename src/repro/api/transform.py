@@ -33,9 +33,11 @@ Two solvers realize the same anchored objective (`TransformSpec.solver`):
     backtracking on the row's own anchored energy, per-row adaptive-grow
     step, per-row convergence freezing.  No host round-trip per iteration
     and, because nothing couples rows (the sampled negative-anchor draw
-    is a pure function of (seed, iteration)), results are INDEPENDENT of
-    batch composition — the property `repro.serve`'s micro-batching and
-    padding correctness rests on (docs/serving.md).
+    is a pure function of (seed, iteration)) and every query runs through
+    one fixed row-chunk program shape (`TransformSpec.batch_size`, default
+    `ROW_CHUNK`), results are INDEPENDENT of batch composition — the
+    property `repro.serve`'s micro-batching and padding correctness rests
+    on (docs/serving.md).
 """
 from __future__ import annotations
 
@@ -238,7 +240,7 @@ def _rowwise_fn(kind: str, m: int, exhaustive: bool, max_iters: int,
         frozen0 = jnp.zeros((n_rows,), bool)
         it, X, _, frozen = jax.lax.while_loop(
             outer_cond, outer_body, (it0, X0, alpha0, frozen0))
-        return X, it, jnp.sum(frozen)
+        return X, it, frozen
 
     return jax.jit(solve)
 
@@ -246,10 +248,12 @@ def _rowwise_fn(kind: str, m: int, exhaustive: bool, max_iters: int,
 def rowwise_transform(kind: str, lam, anchors: Array, nn_idx: Array,
                       nn_w: Array, X0: Array, *,
                       n_negatives: int | None, max_iters: int, tol: float,
-                      seed: int, ls) -> RowwiseResult:
+                      seed: int, ls, n_valid: int | None = None
+                      ) -> RowwiseResult:
     """Solve the anchored problem row-independently (see module docstring).
     `n_negatives=None` (or >= n_train) is the exhaustive deterministic
-    mode.  Returns a `RowwiseResult`."""
+    mode.  Only the first `n_valid` rows (default: all) are real; the rest
+    are padding, left out of the counts.  Returns a `RowwiseResult`."""
     n_train = anchors.shape[0]
     exhaustive = n_negatives is None or n_negatives >= n_train
     fn = _rowwise_fn(kind, 0 if exhaustive else int(n_negatives),
@@ -257,24 +261,12 @@ def rowwise_transform(kind: str, lam, anchors: Array, nn_idx: Array,
                      float(ls.c1), float(ls.rho), int(ls.max_backtracks),
                      None if ls.max_rel_move is None
                      else float(ls.max_rel_move))
-    n_rows = int(X0.shape[0])
-    if n_rows == 1:
-        # XLA lowers the (1, ...) reductions differently from every n >= 2
-        # (which are all bit-identical to each other), and the Armijo
-        # branch amplifies that last-bit drift into visible divergence —
-        # duplicating the row keeps single-row calls exactly on the batch
-        # trajectory (tests/test_api.py pins this)
-        nn_idx = jnp.concatenate([nn_idx, nn_idx], axis=0)
-        nn_w = jnp.concatenate([nn_w, nn_w], axis=0)
-        X0 = jnp.concatenate([X0, X0], axis=0)
-    X, it, n_conv = fn(anchors, nn_idx, nn_w, X0, lam)
-    if n_rows == 1:
-        X = X[:1]
-        n_conv = jnp.minimum(n_conv, 1)
+    n_rows = int(X0.shape[0]) if n_valid is None else int(n_valid)
+    X, it, frozen = fn(anchors, nn_idx, nn_w, X0, lam)
     # one batched transfer for both counters (RPR001) — a serve-path
     # call pays a single device->host round-trip, not two
-    it_h, n_conv_h = jax.device_get((it, n_conv))
-    return RowwiseResult(X=X, n_iters=int(it_h), n_rows=n_rows,
+    it_h, n_conv_h = jax.device_get((it, jnp.sum(frozen[:n_rows])))
+    return RowwiseResult(X=X[:n_rows], n_iters=int(it_h), n_rows=n_rows,
                          n_converged=int(n_conv_h))
 
 
@@ -295,6 +287,10 @@ def _anchor_affinities(Y_new, Y_train, k: int, perplexity: float,
                                perplexity)
     return idx, w
 
+
+#: rows per rowwise solve program when `TransformSpec.batch_size` is 0
+#: (one sublane tile of f32 rows on a TPU)
+ROW_CHUNK = 8
 
 #: distinguishes "use spec.transform_negatives" (unset) from an explicit
 #: ``n_negatives=None`` (exhaustive, deterministic repulsion)
@@ -357,63 +353,54 @@ def transform_points(spec, Y_train: Array, X_train: Array, Y_new: Array,
     anchors = jnp.asarray(X_train)
     if Y_new.shape[0] == 0:
         return jnp.zeros((0, anchors.shape[1]), anchors.dtype), None
-    single = tspec.solver == "rowwise" and Y_new.shape[0] == 1
-    if single:
-        # XLA lowers the lone-query pipeline (kNN reduction, calibration
-        # bisection, solve) differently from every n >= 2 batch — which
-        # are all bit-identical to each other — and the branchy solver
-        # amplifies the last-bit drift.  Duplicating the row keeps
-        # single-row transforms exactly on the batch trajectory, which is
-        # the serving invariance guarantee (tests/test_api.py pins it).
-        Y_new = jnp.concatenate([Y_new, Y_new], axis=0)
     n_train = Y_train.shape[0]
     k = _resolve_k(spec, tspec, n_train, spec.perplexity)
     from repro.sparse.graph import CROSS_APPROX_N
     method = tspec.knn_method
     if method == "auto":
         method = "exact" if n_train <= CROSS_APPROX_N else "approx"
-    with span("cross-knn", phase=True, n_new=int(Y_new.shape[0]), k=k,
-              method=method):
-        idx, w = jax.block_until_ready(_anchor_affinities(
-            Y_new, Y_train, k, float(spec.perplexity), method=method,
-            n_projections=tspec.n_projections, window=tspec.window,
-            knn_seed=tspec.seed))
-
     m = None if tspec.exhaustive else tspec.n_negatives
 
-    # init each new point at its calibrated anchor barycenter — already a
-    # good embedding when the neighborhood is coherent; the fit sharpens it
-    X0 = jnp.einsum("mk,mkd->md", w, anchors[idx])
+    def affinities(Yq):
+        with span("cross-knn", phase=True, n_new=int(Yq.shape[0]), k=k,
+                  method=method):
+            idx, w = jax.block_until_ready(_anchor_affinities(
+                Yq, Y_train, k, float(spec.perplexity), method=method,
+                n_projections=tspec.n_projections, window=tspec.window,
+                knn_seed=tspec.seed))
+        # init each new point at its calibrated anchor barycenter — already
+        # a good embedding when the neighborhood is coherent; the fit
+        # sharpens it
+        return idx, w, jnp.einsum("mk,mkd->md", w, anchors[idx])
 
     if tspec.solver == "rowwise":
-        bs = tspec.batch_size
-        if bs and Y_new.shape[0] > bs:
-            # chunked serving: the rowwise solver is batch-invariant, so
-            # chunk boundaries cannot change any row's result
-            outs, iters, conv = [], 0, 0
-            for i in range(0, Y_new.shape[0], bs):
-                r = rowwise_transform(
-                    spec.kind, spec.lam, anchors, idx[i:i + bs],
-                    w[i:i + bs], X0[i:i + bs], n_negatives=m,
-                    max_iters=tspec.max_iters, tol=tspec.tol,
-                    seed=tspec.seed, ls=spec.resolved_ls())
-                outs.append(r.X)
-                iters = max(iters, r.n_iters)
-                conv += r.n_converged
-            res = RowwiseResult(X=jnp.concatenate(outs, axis=0),
-                                n_iters=iters, n_rows=int(Y_new.shape[0]),
-                                n_converged=conv)
-        else:
+        # every query runs through ONE program shape: chunks of `c` rows,
+        # the last padded with copies of its first row.  XLA lowers
+        # reductions per shape and the per-row Armijo branch amplifies
+        # last-bit differences, so a batch-sized program would make a
+        # row's answer depend on the batch it arrived in
+        c = tspec.batch_size or ROW_CHUNK
+        n_new = int(Y_new.shape[0])
+        outs, iters, conv = [], 0, 0
+        for i in range(0, n_new, c):
+            Yc = Y_new[i:i + c]
+            r = int(Yc.shape[0])
+            if r < c:
+                Yc = jnp.concatenate(
+                    [Yc, jnp.repeat(Yc[:1], c - r, axis=0)], axis=0)
+            idx, w, X0 = affinities(Yc)
             res = rowwise_transform(
                 spec.kind, spec.lam, anchors, idx, w, X0, n_negatives=m,
                 max_iters=tspec.max_iters, tol=tspec.tol, seed=tspec.seed,
-                ls=spec.resolved_ls())
-        if single:
-            res = RowwiseResult(X=res.X[:1], n_iters=res.n_iters,
-                                n_rows=1,
-                                n_converged=min(res.n_converged, 1))
+                ls=spec.resolved_ls(), n_valid=r)
+            outs.append(res.X)
+            iters = max(iters, res.n_iters)
+            conv += res.n_converged
+        res = RowwiseResult(X=jnp.concatenate(outs, axis=0), n_iters=iters,
+                            n_rows=n_new, n_converged=conv)
         return res.X, res
 
+    idx, w, X0 = affinities(Y_new)
     obj = TransformObjective(spec.kind, spec.lam, anchors, idx, w, m)
     cfg = LoopConfig(
         max_iters=tspec.max_iters,

@@ -1,36 +1,37 @@
-"""At-first-dispatch autotuner for the Pallas kernel suite.
+"""Tile selection at first dispatch for the Pallas kernel suite.
 
-The kernels in this package are tiled, and the right tile sizes depend on
-shape, dtype, and hardware generation — FUnc-SNE's speedups (PAPERS.md)
-and Helion's entire design are built on the premise that tiles must be
-*searched*, not guessed.  This module is the small search harness ops.py
-consults whenever a caller leaves tile sizes unset:
+ops.py consults this module whenever a caller leaves tile sizes unset:
 
   * a **candidate list** of `KernelConfig`s (block_rows, block_cols,
-    layout, gather chunk) is generated per kernel kind, pruned to the
-    shapes that are legal for the request (hardware sublane multiples,
-    VMEM budget, divisibility constraints);
-  * each candidate is **timed** on synthetic inputs of the request's
-    shape bucket (one warmup to compile, then best-of-`reps` with
-    `block_until_ready`); candidates that fail to compile or run score
-    `inf` and are skipped;
+    layout, gather chunk) is generated per kernel kind in a fixed order
+    of preference — the long-standing 256-row default first, then
+    smaller tiles — pruned to the shapes that are legal for the request
+    (hardware sublane multiples, the SMEM bound on index/weight tiles,
+    divisibility constraints);
+  * the **first candidate that compiles and runs** on synthetic inputs of
+    the request's shape bucket wins.  A candidate that fails is skipped
+    and its error kept (`failures(key)`, which ops.py copies into the
+    dispatch record); when no candidate runs, the search raises with
+    every candidate's error instead of guessing.  Nothing is timed: the
+    tile fixes the kernels' summation order, so a pick that followed
+    timing noise would make a fit's result differ from run to run (two
+    runs on one v5e picked different tiles and ended 0.7% apart);
   * the winner is cached **in-process** under a key of
     (kernel kind, shape bucket, dtype, device kind, interpret) and
     optionally **on disk**: point `REPRO_AUTOTUNE_CACHE` at a JSON file
-    and every process that shares it skips the search (CI uploads the
-    file as an artifact so local runs can reuse a runner's winners).
+    and every process that shares it skips the compile checks.
 
 Shape bucketing rounds N up to the next power of two (saturating at a
-per-kernel cap so the synthetic search inputs stay affordable), so all
-Ns in a bucket share one config and the search runs once per bucket —
-the "at first dispatch" contract.  The first search wins: later calls
-with the same key return the cached config even if re-timing would now
-pick differently, which is what makes dispatch deterministic within and
-across processes (pinned in tests/test_kernels_autotune.py).
+per-kernel cap so the synthetic inputs stay affordable), so all Ns in a
+bucket share one config and the check runs once per bucket.  The first
+search wins: later calls with the same key return the cached config,
+which keeps dispatch deterministic within and across processes (pinned
+in tests/test_kernels_autotune.py).
 
-ops.py supplies the `runner` that actually executes a candidate (it owns
-padding and kernel invocation); this module stays free of kernel imports
-so the dependency points one way.
+ops.py supplies the `runner` that executes a candidate (it owns padding
+and kernel invocation, and compiles ahead of time so a search started
+while an outer jit traces still compiles each candidate); this module
+stays free of kernel imports so the dependency points one way.
 """
 from __future__ import annotations
 
@@ -38,7 +39,6 @@ import dataclasses
 import json
 import os
 import tempfile
-import time
 from typing import Any, Callable, Sequence
 
 import jax
@@ -74,6 +74,7 @@ class KernelConfig:
 # -- cache ---------------------------------------------------------------------
 
 _CACHE: dict[str, KernelConfig] = {}
+_FAILED: dict[str, list[dict[str, Any]]] = {}
 _DISK_LOADED_FROM: str | None = None
 
 CACHE_ENV = "REPRO_AUTOTUNE_CACHE"
@@ -88,6 +89,7 @@ def clear_cache() -> None:
     will be re-read on the next lookup)."""
     global _DISK_LOADED_FROM
     _CACHE.clear()
+    _FAILED.clear()
     _DISK_LOADED_FROM = None
 
 
@@ -173,52 +175,52 @@ def cache_key(kernel: str, *, n: int, k: int = 0, d: int = 0,
 
 # -- candidate generation ------------------------------------------------------
 
-_ELL_BLOCK_ROWS = (64, 128, 256, 512, 1024)
-_PAIRWISE_TILES = ((128, 128), (256, 256), (512, 512), (128, 512),
-                   (512, 128))
-_HBM_CHUNKS = (8, 32)
+# preference order: the legacy fixed 256 first, smaller tiles as fallbacks
+# for the compiler's memory limits (a larger tile would not fit either)
+_ELL_BLOCK_ROWS = (256, 128, 64)
+_PAIRWISE_TILES = ((256, 256), (128, 128))
+_HBM_CHUNK = 8
 
 
 def _round_up(x: int, m: int) -> int:
     return -(-x // m) * m
 
 
+def fit_divisor(n: int, cap: int, step: int = 1) -> int:
+    """The largest multiple of `step` that is at most `cap` and divides
+    `n` (a multiple of `step`): a tile that covers `n` rows exactly."""
+    t = max(step, min(cap, n) // step * step)
+    while n % t:
+        t -= step
+    return t
+
+
 def ell_candidates(*, n: int, sublane: int, layouts: Sequence[str],
-                   interpret: bool) -> list[KernelConfig]:
+                   max_rows: int | None = None,
+                   max_chunk: int | None = None) -> list[KernelConfig]:
     """ELL gather candidates, legal for this request: block_rows are
-    sublane multiples clipped to the (bucketed) row count; the "hbm"
-    layout adds the double-buffer chunk size (a divisor of block_rows).
-    Interpret mode keeps the list short — its timings only order the
-    per-grid-step interpreter overhead, not real device behavior.  Both
-    modes always include the legacy fixed default (256) so the autotuned
-    pick can never lose to it (the kernel-bench acceptance check)."""
-    rows = (64, 128, 256) if interpret else _ELL_BLOCK_ROWS
+    sublane multiples clipped to the (bucketed) row count and to
+    `max_rows` (the SMEM bound on the index/weight tiles, ops.smem_rows);
+    the "hbm" layout adds the double-buffer chunk size (a divisor of
+    block_rows, at most `max_chunk`)."""
     out: list[KernelConfig] = []
-    for br in rows:
+    for br in _ELL_BLOCK_ROWS:
         br = _round_up(min(br, max(sublane, n)), sublane)
+        if max_rows is not None:
+            br = min(br, max_rows)
         for layout in layouts:
-            if layout == "vmem":
-                cfg = KernelConfig(block_rows=br, layout="vmem")
-                if cfg not in out:
-                    out.append(cfg)
-            else:
-                chunks = _HBM_CHUNKS[:1] if interpret else _HBM_CHUNKS
-                for chunk in chunks:
-                    chunk = min(chunk, br)
-                    while br % chunk:
-                        chunk -= 1
-                    cfg = KernelConfig(block_rows=br, layout="hbm",
-                                       chunk=chunk)
-                    if cfg not in out:
-                        out.append(cfg)
+            chunk = 0
+            if layout == "hbm":
+                chunk = fit_divisor(br, min(_HBM_CHUNK, max_chunk or br))
+            cfg = KernelConfig(block_rows=br, layout=layout, chunk=chunk)
+            if cfg not in out:
+                out.append(cfg)
     return out
 
 
-def pairwise_candidates(*, n: int, sublane: int,
-                        interpret: bool) -> list[KernelConfig]:
-    tiles = ((128, 128), (256, 256)) if interpret else _PAIRWISE_TILES
+def pairwise_candidates(*, n: int, sublane: int) -> list[KernelConfig]:
     out: list[KernelConfig] = []
-    for br, bc in tiles:
+    for br, bc in _PAIRWISE_TILES:
         br = _round_up(min(br, max(sublane, n)), sublane)
         bc = _round_up(min(bc, max(sublane, n)), sublane)
         cfg = KernelConfig(block_rows=br, block_cols=bc, layout="tiled")
@@ -230,20 +232,16 @@ def pairwise_candidates(*, n: int, sublane: int,
 # -- search --------------------------------------------------------------------
 
 
-def _time_once(fn: Callable[[], Any]) -> float:
-    t0 = time.perf_counter()
-    jax.block_until_ready(fn())
-    return time.perf_counter() - t0
+def _error_line(e: Exception) -> str:
+    first = (str(e).strip().splitlines() or [""])[0]
+    return f"{type(e).__name__}: {first}"[:300]
 
 
-def measure(fn: Callable[[], Any], reps: int = 3) -> float:
-    """Best-of-`reps` wall-clock of `fn` after one warmup (the warmup
-    absorbs compilation); `inf` when the candidate fails to run."""
-    try:
-        _time_once(fn)                      # warmup / compile
-        return min(_time_once(fn) for _ in range(max(1, reps)))
-    except Exception:
-        return float("inf")
+def failures(key: str) -> list[dict[str, Any]]:
+    """The candidates this process's search for `key` saw fail, each as
+    {"config": ..., "error": "<type>: <first line>"}; empty for keys
+    found in the cache or searched without failures."""
+    return list(_FAILED.get(key, ()))
 
 
 def get_config(
@@ -256,17 +254,17 @@ def get_config(
     interpret: bool = False,
     candidates: Sequence[KernelConfig],
     runner: Callable[[KernelConfig, int], Callable[[], Any]],
-    reps: int = 3,
 ) -> tuple[KernelConfig, bool]:
-    """The autotuned config for this request: cache hit or search.
+    """The config for this request: cache hit or search.
 
     `runner(cfg, bucket_n)` returns a zero-argument callable executing
     the kernel once at the bucket's synthetic shape under `cfg` (ops.py
     owns padding/invocation).  Returns ``(config, from_cache)``; the
-    search result is stored in-process and mirrored to the
-    `REPRO_AUTOTUNE_CACHE` file when set.  With every candidate scoring
-    `inf` (e.g. nothing compiles on this backend) the first candidate is
-    returned as a safe default — and cached, so the failure is paid once.
+    first candidate, in list order, whose runner compiles and runs is
+    stored in-process and mirrored to the `REPRO_AUTOTUNE_CACHE` file
+    when set.  Candidates that fail before it are listed by
+    `failures(key)`; when every candidate fails the search raises
+    RuntimeError with each one's error, and nothing is cached.
     """
     if not candidates:
         raise ValueError(f"no candidates for kernel {kernel!r}")
@@ -277,14 +275,20 @@ def get_config(
         return hit, True
 
     bucket = shape_bucket(kernel, n, interpret)
-    timings: list[tuple[float, int]] = []
-    for i, cfg in enumerate(candidates):
-        timings.append((measure(runner(cfg, bucket), reps=reps), i))
-    best_t, best_i = min(timings)
-    best = candidates[0] if best_t == float("inf") else candidates[best_i]
-    _CACHE[key] = best
-    _save_disk()
-    return best, False
+    failed: list[dict[str, Any]] = []
+    _FAILED[key] = failed
+    for cfg in candidates:
+        try:
+            jax.block_until_ready(runner(cfg, bucket)())
+        except Exception as e:  # a candidate the backend refuses
+            failed.append({"config": cfg.to_json(), "error": _error_line(e)})
+            continue
+        _CACHE[key] = cfg
+        _save_disk()
+        return cfg, False
+    detail = "; ".join(f"{f['config']}: {f['error']}" for f in failed)
+    raise RuntimeError(
+        f"autotune {key}: no candidate compiled and ran ({detail})")
 
 
 def cached_entries() -> dict[str, KernelConfig]:

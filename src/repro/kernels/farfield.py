@@ -12,24 +12,22 @@ with (sp, b) = negative_pair_terms(kind, t) and t the squared distance to
 the target.  Layout and conventions mirror the ELL gather kernel
 (sparse_attractive.py):
 
-  * grid over row tiles; idx/w/x-row tiles stream through VMEM, the
-    target table is resident whole (index map pinned to block (0, 0)) —
-    tables are cell-aggregate grids (4^level rows), far smaller than X;
-    when the table IS X (the near-field listed pairs at large N) ops.py
-    falls back to the jnp oracle above the VMEM budget instead of
-    dispatching here,
-  * the target gather is a vector gather on the sublane axis (jnp.take),
-  * inputs may be stored in bf16; the arithmetic upcasts after the gather
-    and accumulates in f32, outputs are always f32,
+  * grid over row tiles; the idx/w tiles ride SMEM (scalar reads), the
+    x-row tile streams through VMEM, and the target table is resident
+    whole and unpipelined in VMEM — tables are cell-aggregate grids
+    (4^level rows) or, for the near-field listed pairs, X itself; ops.py
+    falls back to the jnp oracle when the table exceeds the VMEM budget,
+  * each target is one dynamic-offset row read `tab[pl.ds(i, 1), :]`
+    addressed by a scalar index from SMEM (the TPU compiler refuses an
+    in-kernel vector gather), and the per-target math runs on that row:
+    t = |x_n - c|^2 by a lane reduction, then (sp, b), then the
+    weighted accumulation — the same recipe as the oracle,
+  * tables may be stored bf16-rounded; rows are read as f32 and every
+    sum accumulates in f32, outputs are always f32,
   * d is pre-padded to the lane width and N to the tile size by ops.py;
     padding rows carry w = 0, which contributes exactly zero (the same
     masking invariant that covers rejected cells, empty cells and self
     pairs — see bh_interaction_ref).
-
-The per-slot squared distance is computed Gram-style
-(|x|^2 + |c|^2 - 2 x.c, the x.c term on the MXU) so the (TR, W, dp)
-difference tensor is never materialized — with lane-padded dp = 128 that
-tensor would blow the VMEM budget at the far-field slot widths.
 """
 from __future__ import annotations
 
@@ -38,40 +36,35 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .ref import negative_pair_terms
 
 
 def _bh_kernel(idx_ref, w_ref, x_row_ref, tab_ref, s_ref, f_ref, *, kind):
-    idx = idx_ref[...]                                  # (TR, W) int32
-    w = w_ref[...].astype(jnp.float32)                  # (TR, W)
-    x = x_row_ref[...].astype(jnp.float32)              # (TR, dp)
-    tab = tab_ref[...]                                  # (M, dp) storage dtype
+    width = idx_ref.shape[1]
+    dp = f_ref.shape[-1]
 
-    tr, width = idx.shape
-    g = jnp.take(tab, idx.reshape(-1), axis=0,
-                 unique_indices=False, indices_are_sorted=False)
-    g = g.reshape(tr, width, tab.shape[-1]).astype(jnp.float32)
+    def row(r, carry):
+        x = x_row_ref[pl.ds(r, 1), :].astype(jnp.float32)     # (1, dp)
 
-    # t via the Gram identity: the cross term runs on the MXU and the
-    # (TR, W, dp) difference tensor is never formed
-    xg = jax.lax.dot_general(
-        x[:, None, :], g, (((2,), (2,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
-    )[:, 0, :]                                           # (TR, W)
-    t = (jnp.sum(x * x, axis=-1, keepdims=True)
-         + jnp.sum(g * g, axis=-1) - 2.0 * xg)
-    t = jnp.maximum(t, 0.0)
+        def target(j, acc):
+            s_acc, f_acc = acc
+            wj = w_ref[r, j]
+            diff = x - tab_ref[pl.ds(idx_ref[r, j], 1), :].astype(
+                jnp.float32)
+            t = jnp.sum(diff * diff, axis=-1, keepdims=True)  # (1, 1)
+            sp, b = negative_pair_terms(kind, t)
+            return s_acc + wj * sp, f_acc + (wj * b) * diff
 
-    sp, b = negative_pair_terms(kind, t)
-    wb = w * b
-    acc = jax.lax.dot_general(
-        wb[:, None, :], g, (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
-    )[:, 0, :]                                           # (TR, dp)
-    f_ref[...] = jnp.sum(wb, axis=-1, keepdims=True) * x - acc
-    s_ref[...] = jnp.broadcast_to(
-        jnp.sum(w * sp, axis=-1, keepdims=True), s_ref.shape)
+        s_acc, f_acc = jax.lax.fori_loop(
+            0, width, target,
+            (jnp.zeros((1, 1), jnp.float32), jnp.zeros((1, dp), jnp.float32)))
+        f_ref[pl.ds(r, 1), :] = f_acc
+        s_ref[pl.ds(r, 1), :] = jnp.broadcast_to(s_acc, (1, s_ref.shape[-1]))
+        return carry
+
+    jax.lax.fori_loop(0, idx_ref.shape[0], row, 0)
 
 
 def bh_interaction_pallas(
@@ -82,6 +75,7 @@ def bh_interaction_pallas(
     kind: str,
     *,
     block_rows: int = 256,
+    vmem_limit_bytes: int | None = None,
     interpret: bool = False,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Pallas implementation of ref.bh_interaction_ref, vmem layout.
@@ -92,16 +86,17 @@ def bh_interaction_pallas(
     n, dp = X.shape
     assert n % block_rows == 0, (n, block_rows)
     width = idx.shape[1]
-    m = table.shape[0]
 
     s, f = pl.pallas_call(
         functools.partial(_bh_kernel, kind=kind),
         grid=(n // block_rows,),
         in_specs=[
-            pl.BlockSpec((block_rows, width), lambda i: (i, 0)),
-            pl.BlockSpec((block_rows, width), lambda i: (i, 0)),
+            pl.BlockSpec((block_rows, width), lambda i: (i, 0),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((block_rows, width), lambda i: (i, 0),
+                         memory_space=pltpu.SMEM),
             pl.BlockSpec((block_rows, dp), lambda i: (i, 0)),
-            pl.BlockSpec((m, dp), lambda i: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.VMEM),
         ],
         out_specs=(
             pl.BlockSpec((block_rows, 128), lambda i: (i, 0)),
@@ -111,6 +106,8 @@ def bh_interaction_pallas(
             jax.ShapeDtypeStruct((n, 128), jnp.float32),
             jax.ShapeDtypeStruct((n, dp), jnp.float32),
         ),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_limit_bytes),
         interpret=interpret,
     )(idx, w, X, table)
     return s[:, 0], f

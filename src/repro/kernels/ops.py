@@ -8,18 +8,21 @@ time, outside any jit) wrapping jitted implementations:
      ('auto' | 'pallas' | 'pallas-interpret' | 'jnp'; the legacy
      `use_pallas` bool still works) — 'auto' means Pallas on TPU, jnp
      elsewhere.
-  2. **Layout + tiles**: when the caller leaves `block_rows` unset the
-     autotuner (autotune.py) times a candidate list at the request's
-     shape bucket and caches the winner; the ELL matvec additionally
+  2. **Layout + tiles**: when the caller leaves `block_rows` unset,
+     autotune.py takes the first candidate of a fixed preference list
+     that compiles at the request's shape bucket and caches it; the ELL
+     matvec additionally
      picks its layout — whole-X-in-VMEM while X fits the VMEM budget
-     (`REPRO_VMEM_X_BUDGET`, default 8 MiB), the HBM-resident
+     (`REPRO_VMEM_X_BUDGET`, default 12 MiB), the HBM-resident
      double-buffered gather above it — so large N stays on Pallas
      instead of silently falling back.
-  3. **Precision**: `storage_dtype="bfloat16"` stores X/weights in bf16
-     (halving resident-X VMEM and gather traffic — and doubling the
-     vmem-layout N cap) while every kernel accumulates in f32; outputs
-     are always f32.  The jnp path rounds through bf16 too, so both
-     paths see the same quantization.
+  3. **Precision**: `storage_dtype="bfloat16"` rounds X/weights through
+     bf16 while every kernel accumulates in f32; outputs are always f32.
+     The pairwise kernel streams bf16 blocks; the gather kernels (ELL,
+     Barnes-Hut) read rows one 32-bit word per element (a bf16 row read
+     at an odd row offset does not lower), so there the rounded values
+     are held as f32.  The jnp path rounds through bf16 too, so every
+     path sees the same quantization.
 
 Every decision is recorded — never silent:
 
@@ -45,6 +48,7 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.obs import current_tracer, span
 
@@ -58,8 +62,19 @@ from .sparse_attractive import (ell_lap_matvec_local_pallas,
                                 ell_lap_matvec_pallas_hbm)
 
 VMEM_X_BUDGET_ENV = "REPRO_VMEM_X_BUDGET"
-_DEFAULT_VMEM_X_BUDGET = 8 * 1024 * 1024   # bytes the resident-X layout may
-                                           # claim (~16k f32 rows at dp=128)
+_DEFAULT_VMEM_X_BUDGET = 12 * 1024 * 1024  # bytes the resident-X layout may
+                                           # claim (24k f32 rows at dp=128)
+# scoped VMEM a resident-table kernel asks for on top of the table: its
+# streamed tiles and scratch (v5e has 128 MiB of VMEM; the compiler's
+# default scoped limit would not hold a 12 MiB table plus tiles)
+_VMEM_TILE_HEADROOM = 16 * 1024 * 1024
+# SMEM (1 MiB on v5e) holds the double-buffered (rows, k) int32 index and
+# f32 weight tiles of the gather kernels; lanes pad to 128
+_SMEM_TILE_BUDGET = 768 * 1024
+# the HBM layout's (2, chunk * k, dp) f32 gather double buffer
+_HBM_BUFFER_BUDGET = 4 * 1024 * 1024
+# the gather kernels read and write 32-bit rows: f32 sublane tiling
+_ROW_SUB = 8
 
 IMPLS = ("auto", "pallas", "pallas-interpret", "jnp")
 STORAGE_DTYPES = ("float32", "bfloat16")
@@ -82,6 +97,24 @@ def vmem_x_budget() -> int:
         return _DEFAULT_VMEM_X_BUDGET
 
 
+def vmem_limit() -> int:
+    """Scoped VMEM the resident-table kernels request: the table budget
+    plus headroom for their streamed tiles."""
+    return vmem_x_budget() + _VMEM_TILE_HEADROOM
+
+
+def smem_rows(k: int) -> int:
+    """Largest row tile (a multiple of 8) whose index and weight tiles,
+    double-buffered, fit the SMEM budget at ELL width `k`."""
+    per_row = 2 * 2 * _round_up(max(k, 1), 128) * 4
+    return max(_ROW_SUB, _SMEM_TILE_BUDGET // per_row // _ROW_SUB * _ROW_SUB)
+
+
+def hbm_max_chunk(k: int, dp: int) -> int:
+    """Largest HBM-layout chunk whose gather double buffer fits."""
+    return max(1, _HBM_BUFFER_BUDGET // (2 * k * dp * 4))
+
+
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
@@ -101,6 +134,16 @@ def legal_tile(requested: int, n: int, sub: int) -> int:
     multiple (the satellite fix: `min(block_rows, n)` alone hands the
     kernel a misaligned tile whenever n is not a multiple of `sub`)."""
     return _round_up(min(requested, max(sub, n)), sub)
+
+
+def _candidate(fn, *args, **static):
+    """An autotune runner's thunk: `fn` compiled ahead of time for the
+    concrete host arrays `args`.  Dispatch usually runs while an outer jit
+    traces; a plain call would be traced into that program, so the search
+    would neither compile nor time the candidate.  Compile errors raise
+    here, where the search records them."""
+    compiled = fn.lower(*args, **static).compile()
+    return lambda: compiled(*args)
 
 
 def _pad_to(x: jnp.ndarray, rows: int, cols: int) -> jnp.ndarray:
@@ -151,6 +194,11 @@ def _maybe_bf16(x: jnp.ndarray, storage: str) -> jnp.ndarray:
     return x
 
 
+def _rows32(x: jnp.ndarray, storage: str) -> jnp.ndarray:
+    """The storage-rounded values as f32: what the gather kernels read."""
+    return _maybe_bf16(x.astype(jnp.float32), storage).astype(jnp.float32)
+
+
 # -- ELL Laplacian matvec --------------------------------------------------------
 
 
@@ -172,23 +220,24 @@ def _ell_jnp(X, indices, weights, storage):
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "block_rows", "layout", "chunk", "interpret", "lane", "storage"))
+    "block_rows", "layout", "chunk", "interpret", "lane", "storage",
+    "vlimit"))
 def _ell_pallas(X, indices, weights, *, block_rows, layout, chunk,
-                interpret, lane, storage):
+                interpret, lane, storage, vlimit):
     n, d = X.shape
     n_pad = _round_up(n, block_rows)
     dp = max(lane, d)
-    Xp = _pad_to(_maybe_bf16(X.astype(jnp.float32), storage), n_pad, dp)
+    Xp = _pad_to(_rows32(X, storage), n_pad, dp)
     idx_p = jnp.pad(indices.astype(jnp.int32), ((0, n_pad - n), (0, 0)))
-    w_p = _pad_to(_maybe_bf16(weights.astype(jnp.float32), storage),
-                  n_pad, weights.shape[1])
+    w_p = _pad_to(_rows32(weights, storage), n_pad, weights.shape[1])
     if layout == "hbm":
         out = ell_lap_matvec_pallas_hbm(
             Xp, idx_p, w_p, block_rows=block_rows, chunk=chunk,
             interpret=interpret)
     else:
         out = ell_lap_matvec_pallas(
-            Xp, idx_p, w_p, block_rows=block_rows, interpret=interpret)
+            Xp, idx_p, w_p, block_rows=block_rows,
+            vmem_limit_bytes=vlimit, interpret=interpret)
     return out[:n, :d]
 
 
@@ -205,8 +254,7 @@ def _ell_decide(n, k, d, impl, interpret, layout, storage, lane):
     if interpret is None:
         interpret = impl == "pallas-interpret" or not _on_tpu()
     if layout is None:
-        itemsize = 2 if storage == "bfloat16" else 4
-        resident = _round_up(n, sublane(storage)) * max(lane, d) * itemsize
+        resident = _round_up(n, _ROW_SUB) * max(lane, d) * 4
         if resident > vmem_x_budget():
             layout, reason = "hbm", "vmem-cap"
         else:
@@ -244,44 +292,46 @@ def ell_lap_matvec(
         with span("kernel/ell_lap_matvec", n=n, k=k, **info):
             return _ell_jnp(X, indices, weights, storage)
 
-    sub = sublane(storage)
+    max_rows = smem_rows(k)
+    max_chunk = hbm_max_chunk(k, max(lane, d))
+    vlimit = vmem_limit()
     autotuned = cache_hit = False
+    failed: list = []
     if block_rows is not None:
-        br = legal_tile(block_rows, n, sub)
-        ch = chunk if chunk is not None else min(8, br)
-        while br % ch:
-            ch -= 1
+        br = min(legal_tile(block_rows, n, _ROW_SUB), max_rows)
+        ch = autotune.fit_divisor(br, min(chunk or 8, max_chunk))
     else:
         cands = autotune.ell_candidates(
-            n=n, sublane=sub, layouts=[lay], interpret=interp)
+            n=n, sublane=_ROW_SUB, layouts=[lay], max_rows=max_rows,
+            max_chunk=max_chunk)
 
         def runner(cfg, bucket_n):
-            Xs = jnp.ones((bucket_n, d), jnp.float32)
-            idx = jnp.zeros((bucket_n, k), jnp.int32)
-            w = jnp.ones((bucket_n, k), jnp.float32)
-            return lambda: _ell_pallas(
-                Xs, idx, w, block_rows=cfg.block_rows, layout=cfg.layout,
+            return _candidate(
+                _ell_pallas, np.ones((bucket_n, d), np.float32),
+                np.zeros((bucket_n, k), np.int32),
+                np.ones((bucket_n, k), np.float32),
+                block_rows=cfg.block_rows, layout=cfg.layout,
                 chunk=cfg.chunk, interpret=interp, lane=lane,
-                storage=storage)
+                storage=storage, vlimit=vlimit)
 
+        key = dict(n=n, k=k, d=d, dtype=storage, interpret=interp)
         cfg, cache_hit = autotune.get_config(
-            "ell", n=n, k=k, d=d, dtype=storage, interpret=interp,
-            candidates=cands, runner=runner)
+            "ell", **key, candidates=cands, runner=runner)
+        failed = autotune.failures(autotune.cache_key("ell", **key))
         autotuned = True
-        br = legal_tile(cfg.block_rows, n, sub)
-        ch = cfg.chunk or min(8, br)
-        while br % ch:
-            ch -= 1
+        br = min(legal_tile(cfg.block_rows, n, _ROW_SUB), max_rows)
+        ch = autotune.fit_divisor(br, min(cfg.chunk or 8, max_chunk))
 
     info = {"path": "pallas", "reason": reason, "layout": lay,
             "storage": storage, "block_rows": br,
             "chunk": ch if lay == "hbm" else 0, "interpret": interp,
-            "autotuned": autotuned, "cache_hit": cache_hit}
+            "autotuned": autotuned, "cache_hit": cache_hit,
+            "failed": failed}
     _record("ell_lap_matvec", info)
     with span("kernel/ell_lap_matvec", n=n, k=k, **info):
         return _ell_pallas(X, indices, weights, block_rows=br, layout=lay,
                            chunk=ch, interpret=interp, lane=lane,
-                           storage=storage)
+                           storage=storage, vlimit=vlimit)
 
 
 # -- fused pairwise terms --------------------------------------------------------
@@ -342,24 +392,25 @@ def pairwise_terms(
         interpret = impl == "pallas-interpret" or not _on_tpu()
     sub = sublane(storage)
     autotuned = cache_hit = False
+    failed: list = []
     if block_rows is not None or block_cols is not None:
         br = legal_tile(block_rows or 256, n, sub)
         bc = legal_tile(block_cols or br, n, sub)
     else:
-        cands = autotune.pairwise_candidates(
-            n=n, sublane=sub, interpret=interpret)
+        cands = autotune.pairwise_candidates(n=n, sublane=sub)
 
         def runner(cfg, bucket_n):
-            Xs = jnp.ones((bucket_n, d), jnp.float32)
-            W = jnp.ones((bucket_n, bucket_n), jnp.float32)
-            return lambda: _pairwise_pallas(
-                Xs, W, W, kind=kind, block_rows=cfg.block_rows,
+            W = np.ones((bucket_n, bucket_n), np.float32)
+            return _candidate(
+                _pairwise_pallas, np.ones((bucket_n, d), np.float32), W, W,
+                kind=kind, block_rows=cfg.block_rows,
                 block_cols=cfg.block_cols, interpret=interpret, lane=lane,
                 storage=storage)
 
+        key = dict(n=n, d=d, dtype=storage, interpret=interpret)
         cfg, cache_hit = autotune.get_config(
-            "pairwise", n=n, d=d, dtype=storage, interpret=interpret,
-            candidates=cands, runner=runner)
+            "pairwise", **key, candidates=cands, runner=runner)
+        failed = autotune.failures(autotune.cache_key("pairwise", **key))
         autotuned = True
         br = legal_tile(cfg.block_rows, n, sub)
         bc = legal_tile(cfg.block_cols, n, sub)
@@ -367,7 +418,7 @@ def pairwise_terms(
     info = {"path": "pallas", "reason": reason, "layout": "tiled",
             "storage": storage, "block_rows": br, "block_cols": bc,
             "interpret": interpret, "autotuned": autotuned,
-            "cache_hit": cache_hit}
+            "cache_hit": cache_hit, "failed": failed}
     _record("pairwise_terms", info)
     with span("kernel/pairwise_terms", n=n, kind=kind, **info):
         return _pairwise_pallas(X, Wa, Wb, kind=kind, block_rows=br,
@@ -376,11 +427,6 @@ def pairwise_terms(
 
 
 # -- Barnes-Hut cell interaction -------------------------------------------------
-
-# VMEM the gathered target tensor (block_rows, W, lane) f32 may claim in
-# the Pallas body; candidates whose tile would exceed it are pruned.
-_BH_GATHER_BUDGET = 4 * 1024 * 1024
-
 
 @functools.partial(jax.jit, static_argnames=("kind", "storage"))
 def _bh_jnp(X, idx, w, table, kind, storage):
@@ -392,20 +438,19 @@ def _bh_jnp(X, idx, w, table, kind, storage):
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "kind", "block_rows", "interpret", "lane", "storage"))
+    "kind", "block_rows", "interpret", "lane", "storage", "vlimit"))
 def _bh_pallas(X, idx, w, table, *, kind, block_rows, interpret, lane,
-               storage):
+               storage, vlimit):
     n, d = X.shape
     n_pad = _round_up(n, block_rows)
     dp = max(lane, d)
-    Xp = _pad_to(_maybe_bf16(X.astype(jnp.float32), storage), n_pad, dp)
-    tab_p = _pad_to(_maybe_bf16(table.astype(jnp.float32), storage),
-                    table.shape[0], dp)
+    Xp = _pad_to(_rows32(X, storage), n_pad, dp)
+    tab_p = _pad_to(_rows32(table, storage), table.shape[0], dp)
     idx_p = jnp.pad(idx.astype(jnp.int32), ((0, n_pad - n), (0, 0)))
     w_p = _pad_to(w.astype(jnp.float32), n_pad, w.shape[1])
     s, F = bh_interaction_pallas(
         Xp, idx_p, w_p, tab_p, kind, block_rows=block_rows,
-        interpret=interpret)
+        vmem_limit_bytes=vlimit, interpret=interpret)
     return s[:n], F[:n, :d]
 
 
@@ -442,10 +487,8 @@ def bh_interaction(
     reason = None
     if impl == "jnp" or (impl == "auto" and not _on_tpu()):
         reason = "forced-off" if impl == "jnp" else "no-tpu"
-    else:
-        itemsize = 2 if storage == "bfloat16" else 4
-        if m * dp * itemsize > vmem_x_budget():
-            reason = "vmem-cap"
+    elif m * dp * 4 > vmem_x_budget():
+        reason = "vmem-cap"
     if reason is not None:
         info = {"path": "jnp", "reason": reason, "storage": storage}
         _record("bh_interaction", info)
@@ -456,51 +499,57 @@ def bh_interaction(
     reason = "tpu-default" if impl == "auto" else "forced-on"
     if interpret is None:
         interpret = impl == "pallas-interpret" or not _on_tpu()
-    sub = sublane(storage)
+    max_rows = smem_rows(width)
+    vlimit = vmem_limit()
     autotuned = cache_hit = False
+    failed: list = []
     if block_rows is not None:
-        br = legal_tile(block_rows, n, sub)
+        br = min(legal_tile(block_rows, n, _ROW_SUB), max_rows)
     else:
-        cands = [c for c in autotune.ell_candidates(
-                     n=n, sublane=sub, layouts=["vmem"], interpret=interpret)
-                 if c.block_rows * width * dp * 4 <= _BH_GATHER_BUDGET]
-        if not cands:
-            cands = [autotune.KernelConfig(block_rows=sub)]
+        cands = autotune.ell_candidates(
+            n=n, sublane=_ROW_SUB, layouts=["vmem"], max_rows=max_rows)
 
         def runner(cfg, bucket_n):
-            Xs = jnp.ones((bucket_n, d), jnp.float32)
-            ii = jnp.zeros((bucket_n, width), jnp.int32)
-            ws = jnp.ones((bucket_n, width), jnp.float32)
-            tab = jnp.ones((m, d), jnp.float32)
-            return lambda: _bh_pallas(
-                Xs, ii, ws, tab, kind=kind, block_rows=cfg.block_rows,
-                interpret=interpret, lane=lane, storage=storage)
+            return _candidate(
+                _bh_pallas, np.ones((bucket_n, d), np.float32),
+                np.zeros((bucket_n, width), np.int32),
+                np.ones((bucket_n, width), np.float32),
+                np.ones((m, d), np.float32), kind=kind,
+                block_rows=cfg.block_rows, interpret=interpret, lane=lane,
+                storage=storage, vlimit=vlimit)
 
+        key = dict(n=n, k=width, d=d, dtype=storage, interpret=interpret)
         cfg, cache_hit = autotune.get_config(
-            "bh", n=n, k=width, d=d, dtype=storage, interpret=interpret,
-            candidates=cands, runner=runner)
+            "bh", **key, candidates=cands, runner=runner)
+        failed = autotune.failures(autotune.cache_key("bh", **key))
         autotuned = True
-        br = legal_tile(cfg.block_rows, n, sub)
+        br = min(legal_tile(cfg.block_rows, n, _ROW_SUB), max_rows)
 
     info = {"path": "pallas", "reason": reason, "layout": "vmem",
             "storage": storage, "block_rows": br, "interpret": interpret,
-            "autotuned": autotuned, "cache_hit": cache_hit}
+            "autotuned": autotuned, "cache_hit": cache_hit,
+            "failed": failed}
     _record("bh_interaction", info)
     with span("kernel/bh_interaction", n=n, w=width, m=m, kind=kind, **info):
         return _bh_pallas(X, idx, w, table, kind=kind, block_rows=br,
-                          interpret=interpret, lane=lane, storage=storage)
+                          interpret=interpret, lane=lane, storage=storage,
+                          vlimit=vlimit)
 
 
 # -- sharded local-rows ELL matvec -----------------------------------------------
 
 
-def resolve_local_ell(nb: int, k: int, d: int, *, impl: str = "auto",
-                      storage_dtype=None, interpret: bool | None = None):
+def resolve_local_ell(nb: int, k: int, d: int, *, n_rep: int,
+                      impl: str = "auto", storage_dtype=None,
+                      interpret: bool | None = None, lane: int = 128):
     """Build-time dispatch for the shard_map-local ELL kernel
     (sparse/sharding.py): returns ``None`` when the jnp per-shard gather
     should be used, else a dict of static kwargs for
     `ell_lap_matvec_local` — the decision must be made OUTSIDE the
-    shard_map trace, where the autotuner may still run eagerly.
+    shard_map trace, where the autotuner may still run eagerly.  The
+    kernel keeps the replicated X (`n_rep` rows) resident in VMEM and has
+    no HBM layout, so above the VMEM budget it routes to the jnp gather
+    with reason ``"vmem-cap"``.
 
     `block_rows` is the autotuned pick rounded DOWN to a divisor of `nb`
     (the local grid must tile the shard exactly, and the BlockSpec row
@@ -508,34 +557,43 @@ def resolve_local_ell(nb: int, k: int, d: int, *, impl: str = "auto",
     sublane multiple)."""
     impl = _resolve_impl(impl, None)
     storage = _resolve_storage(storage_dtype)
+    reason = None
     if impl == "jnp" or (impl == "auto" and not _on_tpu()):
         reason = "forced-off" if impl == "jnp" else "no-tpu"
+    elif _round_up(n_rep, _ROW_SUB) * max(lane, d) * 4 > vmem_x_budget():
+        reason = "vmem-cap"
+    if reason is not None:
         _record("ell_lap_matvec_local",
                 {"path": "jnp", "reason": reason, "storage": storage})
         return None
     if interpret is None:
         interpret = impl == "pallas-interpret" or not _on_tpu()
-    sub = sublane(storage)
+    max_rows = smem_rows(k)
+    vlimit = vmem_limit()
     cands = autotune.ell_candidates(
-        n=nb, sublane=sub, layouts=["vmem"], interpret=interpret)
+        n=nb, sublane=_ROW_SUB, layouts=["vmem"], max_rows=max_rows)
 
     def runner(cfg, bucket_n):
-        Xs = jnp.ones((bucket_n, max(128, d)), jnp.float32)
-        idx = jnp.zeros((bucket_n, k), jnp.int32)
-        w = jnp.ones((bucket_n, k), jnp.float32)
-        return lambda: ell_lap_matvec_local_pallas(
-            Xs, idx, w, 0, block_rows=cfg.block_rows, interpret=interpret)
+        rows = _round_up(bucket_n, cfg.block_rows)   # the grid tiles rows
+        fn = jax.jit(functools.partial(
+            ell_lap_matvec_local_pallas, block_rows=cfg.block_rows,
+            vmem_limit_bytes=vlimit, interpret=interpret))
+        return _candidate(
+            fn, np.ones((rows, max(lane, d)), np.float32),
+            np.zeros((rows, k), np.int32), np.ones((rows, k), np.float32),
+            np.int32(0))
 
+    key = dict(n=nb, k=k, d=d, dtype=storage, interpret=interpret)
     cfg, cache_hit = autotune.get_config(
-        "ell_local", n=nb, k=k, d=d, dtype=storage, interpret=interpret,
-        candidates=cands, runner=runner)
-    br = min(legal_tile(cfg.block_rows, nb, sub), nb)
-    while nb % br:
-        br -= sub
+        "ell_local", **key, candidates=cands, runner=runner)
+    br = autotune.fit_divisor(
+        nb, min(legal_tile(cfg.block_rows, nb, _ROW_SUB), max_rows), _ROW_SUB)
     info = {"path": "pallas", "reason": "forced-on" if impl != "auto"
             else "tpu-default", "layout": "vmem", "storage": storage,
             "block_rows": br, "interpret": interpret, "autotuned": True,
-            "cache_hit": cache_hit}
+            "cache_hit": cache_hit,
+            "failed": autotune.failures(autotune.cache_key("ell_local",
+                                                           **key))}
     _record("ell_lap_matvec_local", info)
     return {"block_rows": br, "interpret": interpret, "storage": storage}
 
@@ -548,9 +606,9 @@ def ell_lap_matvec_local(X_rep, indices, weights, row0, *, block_rows,
     trace inside shard_map (no dispatch, no autotune)."""
     d = X_rep.shape[1]
     dp = max(lane, d)
-    Xk = _maybe_bf16(jnp.pad(X_rep, ((0, 0), (0, dp - d))), storage)
-    w = _maybe_bf16(weights, storage)
+    Xk = jnp.pad(_rows32(X_rep, storage), ((0, 0), (0, dp - d)))
     out = ell_lap_matvec_local_pallas(
-        Xk, indices.astype(jnp.int32), w, row0,
-        block_rows=block_rows, interpret=interpret)
+        Xk, indices.astype(jnp.int32), _rows32(weights, storage), row0,
+        block_rows=block_rows, interpret=interpret,
+        vmem_limit_bytes=vmem_limit())
     return out[:, :d]

@@ -10,7 +10,9 @@ TPU adaptation of the paper's O(N^2 d) bottleneck (DESIGN.md §3.1):
   * kernel evaluation + weighting runs on the VPU,
   * row-block accumulators (la_x, lb_x) live in VMEM across the column-tile
     sweep (output BlockSpec maps every j to the same row block),
-  * scalar accumulators (e_plus, s) persist in VMEM across the whole grid,
+  * scalar accumulators (e_plus, s) persist across the whole grid in one
+    lane-dense (8, 128) VMEM block each, every element holding the value
+    (the TPU cannot store a lone scalar to VMEM; ops read element [0, 0]),
   * the N x N matrix is never materialized in HBM.
 
 Grid iteration order on TPU is sequential with the last axis minor, which is
@@ -30,6 +32,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from .ref import KINDS, PairwiseTerms
+
+# one f32 vreg: the smallest block a vector value can be stored to
+_SCALAR_BLOCK = (8, 128)
 
 
 def _tile_terms(kind: str, t, wa, wb, xi, xj):
@@ -102,8 +107,8 @@ def _pairwise_kernel(x_row_ref, x_col_ref, wa_ref, wb_ref,
         ep_ref[...] = jnp.zeros_like(ep_ref)
         s_ref[...] = jnp.zeros_like(s_ref)
 
-    ep_ref[0, 0] += ep_tile
-    s_ref[0, 0] += s_tile
+    ep_ref[...] += ep_tile
+    s_ref[...] += s_tile
 
 
 def pairwise_terms_pallas(
@@ -140,14 +145,14 @@ def pairwise_terms_pallas(
         out_specs=[
             pl.BlockSpec((block_rows, dp), lambda i, j: (i, 0)),
             pl.BlockSpec((block_rows, dp), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
-            pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
+            pl.BlockSpec(_SCALAR_BLOCK, lambda i, j: (0, 0)),
+            pl.BlockSpec(_SCALAR_BLOCK, lambda i, j: (0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((n, dp), jnp.float32),
             jax.ShapeDtypeStruct((n, dp), jnp.float32),
-            jax.ShapeDtypeStruct((1, 1), jnp.float32),
-            jax.ShapeDtypeStruct((1, 1), jnp.float32),
+            jax.ShapeDtypeStruct(_SCALAR_BLOCK, jnp.float32),
+            jax.ShapeDtypeStruct(_SCALAR_BLOCK, jnp.float32),
         ],
         interpret=interpret,
     )(X, X, Wa, Wb)

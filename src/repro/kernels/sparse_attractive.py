@@ -12,31 +12,33 @@ tile over, while the gather half is the regular-access hot path.
 Three layouts of the same contract (ops.py picks one per dispatch, see
 docs/kernels.md):
 
-  * `ell_lap_matvec_pallas` — "vmem": X is additionally passed whole
-    (index map pinned to block (0, 0)) so neighbor rows gather straight
-    from VMEM.  Fastest when X fits the VMEM budget; caps N at ~16k rows
-    for f32 at the 128-lane d padding (twice that for bf16 storage).
+  * `ell_lap_matvec_pallas` — "vmem": X is additionally passed whole and
+    unpipelined (one VMEM copy, not a double-buffered block), so neighbor
+    rows are read straight from VMEM.  Used while X fits the VMEM budget
+    (ops.vmem_x_budget).
   * `ell_lap_matvec_pallas_hbm` — "hbm": X stays in HBM
-    (`memory_space=ANY`); the kernel DMAs each row tile's neighbor rows
-    into a double-buffered VMEM scratch, overlapping the next chunk's
-    copies with the current chunk's compute.  Lifts the VMEM cap — this
-    is what keeps Pallas serving N >> 16k instead of falling back to jnp.
+    (`memory_space=ANY`); the kernel DMAs each chunk of rows' neighbor
+    rows into a double-buffered VMEM scratch, overlapping the next
+    chunk's copies with the current chunk's compute.  Lifts the VMEM cap.
   * `ell_lap_matvec_local_pallas` — "vmem" over a REPLICATED X but only a
     LOCAL row range of the graph: the variant `shard_map` bodies call
     (sparse/sharding.py).  The global->local translation happens at the
     BlockSpec level via a scalar-prefetch row-block offset, so the kernel
     body is shared with the single-device vmem layout verbatim.
 
-Shared conventions (DESIGN.md §3.2, carried over from pairwise.py):
-  * grid over row tiles; indices/weights/x-row tiles stream through VMEM,
-  * the row gather is a vector gather on the sublane axis (jnp.take);
-    Mosaic lowers it natively on recent toolchains,
-  * inputs may be stored in bf16 (mixed precision); every arithmetic path
-    upcasts AFTER the gather and accumulates in f32, and the output is
-    always f32,
+The gather (all three layouts): the index and weight tiles live in SMEM,
+and each neighbor row is one dynamic-offset row read `x[pl.ds(i, 1), :]`
+addressed by a scalar index — the form Mosaic lowers (a vector gather
+over the sublane axis, `jnp.take`, is refused by the TPU compiler).  The
+row sum runs in f32 with a scalar weight per read:
+
+  * X, weights and output are f32: a row read at an odd row offset of a
+    bf16 array does not lower, so bf16 storage (ops.py) rounds X and the
+    weights through bf16 and hands the kernels the rounded values as f32
+    — the same quantization as the jnp path,
   * embedding dim d is pre-padded to the lane width by ops.py; N is
-    pre-padded to the tile size with zero-weight self-edge rows, which
-    contribute exactly zero (the ELL padding invariant).
+    pre-padded to the tile size with zero-weight rows, which contribute
+    exactly zero (the ELL padding invariant).
 """
 from __future__ import annotations
 
@@ -47,63 +49,82 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+# neighbor reads unrolled per loop trip: enough straight-line work for the
+# scheduler to overlap the scalar index loads with the vector FMAs
+_UNROLL = 8
 
-def _space(name):
-    """Memory-space symbol across jax versions (pltpu.ANY/SMEM on 0.4.x;
-    newer releases move/alias them under pallas core)."""
-    v = getattr(pltpu, name, None)
-    return v if v is not None else getattr(pl, name)
+
+def _lap_rows(load_row, w_ref, x_row_ref, out_ref, r0, nrows):
+    """out[r] = (sum_j w[r, j]) x[r] - sum_j w[r, j] load_row(r, j) for
+    the tile rows r0 .. r0 + nrows; `w_ref` is an SMEM (TR, k) tile."""
+    k = w_ref.shape[1]
+    dp = out_ref.shape[-1]
+
+    def row(r, carry):
+        def nbr(j, acc_deg):
+            acc, deg = acc_deg
+            wj = w_ref[r, j]
+            return acc + wj * load_row(r, j).astype(jnp.float32), deg + wj
+
+        def nbr_group(g, acc_deg):
+            # Mosaic lowers only full or no fori_loop unrolling, so the
+            # group of _UNROLL reads is unrolled by hand
+            for u in range(_UNROLL):
+                acc_deg = nbr(g * _UNROLL + u, acc_deg)
+            return acc_deg
+
+        acc_deg = (jnp.zeros((1, dp), jnp.float32), jnp.float32(0.0))
+        acc_deg = jax.lax.fori_loop(0, k // _UNROLL, nbr_group, acc_deg)
+        for j in range(k - k % _UNROLL, k):
+            acc_deg = nbr(j, acc_deg)
+        acc, deg = acc_deg
+        xi = x_row_ref[pl.ds(r, 1), :].astype(jnp.float32)
+        out_ref[pl.ds(r, 1), :] = deg * xi - acc
+        return carry
+
+    jax.lax.fori_loop(r0, r0 + nrows, row, 0)
 
 
 def _ell_kernel(idx_ref, w_ref, x_row_ref, x_all_ref, out_ref):
-    idx = idx_ref[...]                                  # (TR, k) int32
-    w = w_ref[...].astype(jnp.float32)                  # (TR, k)
-    xi = x_row_ref[...].astype(jnp.float32)             # (TR, dp)
-    x_all = x_all_ref[...]                              # (N, dp) storage dtype
+    _lap_rows(lambda r, j: x_all_ref[pl.ds(idx_ref[r, j], 1), :],
+              w_ref, x_row_ref, out_ref, 0, idx_ref.shape[0])
 
-    tr, k = idx.shape
-    # gather in the storage dtype, upcast the gathered rows only: bf16
-    # storage halves both the resident-X VMEM footprint and the gather
-    # traffic, while every FLOP below runs in f32
-    gathered = jnp.take(x_all, idx.reshape(-1), axis=0,
-                        unique_indices=False, indices_are_sorted=False)
-    gathered = gathered.reshape(tr, k, x_all.shape[-1]).astype(jnp.float32)
-    acc = jax.lax.dot_general(
-        w[:, None, :], gathered, (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
-    )[:, 0, :]                                           # (TR, dp)
-    deg = jnp.sum(w, axis=-1, keepdims=True)
-    out_ref[...] = deg * xi - acc
+
+def _smem_tile(block_rows, k, index_map):
+    return pl.BlockSpec((block_rows, k), index_map,
+                        memory_space=pltpu.SMEM)
 
 
 def ell_lap_matvec_pallas(
     X: jnp.ndarray,          # (N, dp) — dp lane-padded by ops.py
     indices: jnp.ndarray,    # (N, k) int32
-    weights: jnp.ndarray,    # (N, k)
+    weights: jnp.ndarray,    # (N, k) f32
     *,
     block_rows: int = 256,
+    vmem_limit_bytes: int | None = None,
     interpret: bool = False,
 ) -> jnp.ndarray:
     """Pallas implementation of ref.ell_lap_matvec_ref, vmem layout.
 
-    Requires N % block_rows == 0 (ops.py pads with zero-weight self-edge
-    rows) and X's last dim lane-padded."""
+    Requires N % block_rows == 0 (ops.py pads with zero-weight rows) and
+    X's last dim lane-padded."""
     n, dp = X.shape
     assert n % block_rows == 0, (n, block_rows)
     k = indices.shape[1]
-    grid = (n // block_rows,)
 
     return pl.pallas_call(
         _ell_kernel,
-        grid=grid,
+        grid=(n // block_rows,),
         in_specs=[
-            pl.BlockSpec((block_rows, k), lambda i: (i, 0)),
-            pl.BlockSpec((block_rows, k), lambda i: (i, 0)),
+            _smem_tile(block_rows, k, lambda i: (i, 0)),
+            _smem_tile(block_rows, k, lambda i: (i, 0)),
             pl.BlockSpec((block_rows, dp), lambda i: (i, 0)),
-            pl.BlockSpec((n, dp), lambda i: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.VMEM),
         ],
         out_specs=pl.BlockSpec((block_rows, dp), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, dp), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_limit_bytes),
         interpret=interpret,
     )(indices, weights, X, X)
 
@@ -112,63 +133,64 @@ def _ell_hbm_kernel(idx_ref, w_ref, x_row_ref, x_hbm_ref, out_ref, *,
                     chunk: int):
     """Double-buffered HBM gather: while chunk c's neighbor rows are being
     reduced, chunk c+1's rows are already in flight into the other buffer
-    slot.  `idx_ref` lives in SMEM — DMA source addresses are scalars."""
+    slot.  One DMA semaphore per slot: every row copy of a chunk signals
+    it, and the wait loop consumes one row's worth per copy."""
     tr, k = idx_ref.shape
-    dp = out_ref.shape[-1]
     n_chunks = tr // chunk
+    per_chunk = chunk * k
 
     def scoped(buf, sems):
-        # the DMA descriptor for (slot, chunk, row-in-chunk, neighbor) is
-        # reconstructed identically at start() and wait() — the Pallas
-        # async-copy contract
-        def copies(slot, c):
-            return [
-                pltpu.make_async_copy(
-                    x_hbm_ref.at[idx_ref[c * chunk + r, j]],
-                    buf.at[slot, r * k + j],
-                    sems.at[slot, r * k + j],
-                )
-                for r in range(chunk) for j in range(k)
-            ]
+        # the descriptor for (slot, chunk, slot-row t) is rebuilt
+        # identically at start() and wait() — the Pallas async-copy
+        # contract
+        def copy(slot, c, t):
+            src = idx_ref[c * chunk + t // k, t % k]
+            return pltpu.make_async_copy(
+                x_hbm_ref.at[pl.ds(src, 1)], buf.at[slot, pl.ds(t, 1)],
+                sems.at[slot])
 
-        for cp in copies(0, 0):
-            cp.start()
+        def start(slot, c):
+            def body(t, carry):
+                copy(slot, c, t).start()
+                return carry
+            jax.lax.fori_loop(0, per_chunk, body, 0)
+
+        def wait(slot, c):
+            def body(t, carry):
+                copy(slot, c, t).wait()
+                return carry
+            jax.lax.fori_loop(0, per_chunk, body, 0)
+
+        start(0, 0)
 
         def step(c, carry):
             slot = jax.lax.rem(c, 2)
 
             @pl.when(c + 1 < n_chunks)
             def _prefetch():
-                for cp in copies(1 - slot, c + 1):
-                    cp.start()
+                start(1 - slot, c + 1)
 
-            for cp in copies(slot, c):
-                cp.wait()
-
-            g = buf[slot].reshape(chunk, k, dp).astype(jnp.float32)
-            w = w_ref[pl.ds(c * chunk, chunk), :].astype(jnp.float32)
-            xi = x_row_ref[pl.ds(c * chunk, chunk), :].astype(jnp.float32)
-            acc = jax.lax.dot_general(
-                w[:, None, :], g, (((2,), (1,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32,
-            )[:, 0, :]
-            deg = jnp.sum(w, axis=-1, keepdims=True)
-            out_ref[pl.ds(c * chunk, chunk), :] = deg * xi - acc
+            wait(slot, c)
+            r0 = c * chunk
+            _lap_rows(
+                lambda r, j: buf[slot, pl.ds((r - r0) * k + j, 1), :],
+                w_ref, x_row_ref, out_ref, r0, chunk)
             return carry
 
         jax.lax.fori_loop(0, n_chunks, step, 0)
 
     pl.run_scoped(
         scoped,
-        buf=_space("VMEM")((2, chunk * k, dp), x_hbm_ref.dtype),
-        sems=pltpu.SemaphoreType.DMA((2, chunk * k)),
+        buf=pltpu.VMEM((2, per_chunk, x_hbm_ref.shape[-1]),
+                       x_hbm_ref.dtype),
+        sems=pltpu.SemaphoreType.DMA((2,)),
     )
 
 
 def ell_lap_matvec_pallas_hbm(
     X: jnp.ndarray,          # (N, dp) — stays in HBM
     indices: jnp.ndarray,    # (N, k) int32
-    weights: jnp.ndarray,    # (N, k)
+    weights: jnp.ndarray,    # (N, k) f32
     *,
     block_rows: int = 256,
     chunk: int = 8,
@@ -177,7 +199,7 @@ def ell_lap_matvec_pallas_hbm(
     """HBM-resident layout: same contract as `ell_lap_matvec_pallas`, but
     X never enters VMEM whole — per chunk of `chunk` rows, the chunk*k
     neighbor rows are DMA'd into a (2, chunk*k, dp) double buffer.  VMEM
-    use is O(block_rows * (k + dp) + chunk * k * dp), independent of N."""
+    use is O(block_rows * dp + chunk * k * dp), independent of N."""
     n, dp = X.shape
     assert n % block_rows == 0, (n, block_rows)
     assert block_rows % chunk == 0, (block_rows, chunk)
@@ -187,11 +209,10 @@ def ell_lap_matvec_pallas_hbm(
         functools.partial(_ell_hbm_kernel, chunk=chunk),
         grid=(n // block_rows,),
         in_specs=[
-            pl.BlockSpec((block_rows, k), lambda i: (i, 0),
-                         memory_space=_space("SMEM")),
-            pl.BlockSpec((block_rows, k), lambda i: (i, 0)),
+            _smem_tile(block_rows, k, lambda i: (i, 0)),
+            _smem_tile(block_rows, k, lambda i: (i, 0)),
             pl.BlockSpec((block_rows, dp), lambda i: (i, 0)),
-            pl.BlockSpec(memory_space=_space("ANY")),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec((block_rows, dp), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, dp), jnp.float32),
@@ -207,10 +228,11 @@ def _ell_local_kernel(s_ref, idx_ref, w_ref, x_row_ref, x_all_ref, out_ref):
 def ell_lap_matvec_local_pallas(
     X_rep: jnp.ndarray,      # (n_rep, dp) — REPLICATED, lane-padded
     indices: jnp.ndarray,    # (nb, k) int32 — LOCAL graph rows, global ids
-    weights: jnp.ndarray,    # (nb, k)
+    weights: jnp.ndarray,    # (nb, k) f32
     row0,                    # global row offset of this shard (traced OK)
     *,
     block_rows: int = 256,
+    vmem_limit_bytes: int | None = None,
     interpret: bool = False,
 ) -> jnp.ndarray:
     """Local rows of L(A) X inside a shard_map body: the graph arrays are
@@ -223,17 +245,17 @@ def ell_lap_matvec_local_pallas(
     `_ell_kernel` verbatim, and `row0 % block_rows == 0` is required
     (sparse/sharding.py sizes shards so block_rows divides nb)."""
     nb, k = indices.shape
-    n_rep, dp = X_rep.shape
+    dp = X_rep.shape[1]
     assert nb % block_rows == 0, (nb, block_rows)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(nb // block_rows,),
         in_specs=[
-            pl.BlockSpec((block_rows, k), lambda i, s: (i, 0)),
-            pl.BlockSpec((block_rows, k), lambda i, s: (i, 0)),
+            _smem_tile(block_rows, k, lambda i, s: (i, 0)),
+            _smem_tile(block_rows, k, lambda i, s: (i, 0)),
             pl.BlockSpec((block_rows, dp), lambda i, s: (s[0] + i, 0)),
-            pl.BlockSpec((n_rep, dp), lambda i, s: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.VMEM),
         ],
         out_specs=pl.BlockSpec((block_rows, dp), lambda i, s: (i, 0)),
     )
@@ -242,5 +264,7 @@ def ell_lap_matvec_local_pallas(
         _ell_local_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((nb, dp), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_limit_bytes),
         interpret=interpret,
     )(block0, indices, weights, X_rep, X_rep)

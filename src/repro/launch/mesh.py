@@ -1,64 +1,30 @@
-"""Production meshes (assignment spec).
+"""Production meshes (assignment spec) and the shared shard_map helpers.
 
 `make_production_mesh` is a FUNCTION (never a module-level constant) so
 importing this module never touches jax device state.  Only
 launch/dryrun.py forces the 512 host devices.
 
-jax-version compatibility: `jax.sharding.AxisType` (and the `axis_types`
-kwarg on `jax.make_mesh` / `AbstractMesh`) only exist on newer jax; on
-older releases (the container pins 0.4.37) meshes are built without
-explicit axis types, which is equivalent to the Auto default we request.
-`axis_types_kwargs` / `make_abstract_mesh` are the shared fallbacks —
-tests use them too, so the suite collects on both old and new jax.
+Every mesh the repo builds has Auto axes: `jax.make_mesh` without
+`axis_types` builds Explicit axes, under which a contraction over a
+sharded dimension outside `shard_map` (the engine's line-search dot
+products) is a sharding-type error.  `auto_axes` converts a caller's
+mesh.
 """
 from __future__ import annotations
 
 import jax
-from jax.sharding import AbstractMesh, Mesh
+from jax.sharding import AbstractMesh, AxisType, Mesh
 
-try:
-    from jax.sharding import AxisType
-except ImportError:  # jax < 0.5: no explicit axis types (Auto is implied)
-    AxisType = None
-
-# jax >= 0.6 promotes shard_map to the top-level namespace; older releases
-# (the container pins 0.4.37) keep it in jax.experimental.  Shared here so
-# every shard_map user (embed/distributed.py, sparse/sharding.py) sees the
-# same symbol without re-implementing the probe.
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:  # pragma: no cover - exercised on old jax only
-    from jax.experimental.shard_map import shard_map
+shard_map = jax.shard_map
 
 
 def shard_map_norep(f, *, mesh, in_specs, out_specs):
     """`shard_map` with replication checking disabled — `pallas_call` has
     no replication rule, so kernel-bearing bodies (sparse/sharding.py with
-    the local-rows ELL kernel) cannot pass the check.  The kwarg was
-    renamed `check_rep` -> `check_vma` around jax 0.6; probe the signature
-    so both spellings work.  Only kernel-bearing bodies should use this —
-    plain jnp bodies keep the default checking."""
-    import inspect
-
-    try:
-        params = inspect.signature(shard_map).parameters
-    except (TypeError, ValueError):  # pragma: no cover - exotic wrappers
-        params = {}
-    kw = {}
-    if "check_vma" in params:
-        kw["check_vma"] = False
-    elif "check_rep" in params:
-        kw["check_rep"] = False
+    the local-rows ELL kernel) cannot pass the check.  Only kernel-bearing
+    bodies should use this — plain jnp bodies keep the default checking."""
     return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     **kw)
-
-
-def axis_size(ax: str):
-    """jax.lax.axis_size is a recent addition; psum(1) is the portable
-    spelling of "size of this named axis" inside shard_map."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(ax)
-    return jax.lax.psum(1, ax)
+                     check_vma=False)
 
 
 def linear_row_index(row_axes: tuple[str, ...]):
@@ -70,26 +36,26 @@ def linear_row_index(row_axes: tuple[str, ...]):
 
     idx = jnp.asarray(0, jnp.int32)
     for ax in row_axes:
-        idx = idx * axis_size(ax) + jax.lax.axis_index(ax)
+        idx = idx * jax.lax.axis_size(ax) + jax.lax.axis_index(ax)
     return idx
 
 
 def axis_types_kwargs(n_axes: int) -> dict:
-    """`{"axis_types": (Auto,) * n}` where supported, else `{}`."""
-    if AxisType is None:
-        return {}
+    """`{"axis_types": (Auto,) * n}`: the kwarg for an Auto-axes mesh."""
     return {"axis_types": (AxisType.Auto,) * n_axes}
+
+
+def auto_axes(mesh: Mesh) -> Mesh:
+    """`mesh` with every axis Auto (same devices, same names)."""
+    if all(t == AxisType.Auto for t in mesh.axis_types):
+        return mesh
+    return Mesh(mesh.devices, mesh.axis_names,
+                **axis_types_kwargs(len(mesh.axis_names)))
 
 
 def make_abstract_mesh(shape: tuple[int, ...],
                        names: tuple[str, ...]) -> AbstractMesh:
-    """AbstractMesh across the two historical constructor signatures:
-    new jax takes (shape, names, *, axis_types=...); jax <= 0.4.x takes a
-    single ((name, size), ...) tuple."""
-    try:
-        return AbstractMesh(shape, names, **axis_types_kwargs(len(names)))
-    except TypeError:
-        return AbstractMesh(tuple(zip(names, shape)))
+    return AbstractMesh(shape, names, **axis_types_kwargs(len(names)))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

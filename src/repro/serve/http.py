@@ -146,6 +146,8 @@ def main(argv=None) -> int:
     ap.add_argument("--verbose", action="store_true")
     args = ap.parse_args(argv)
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     Y_train = None if args.y_train is None else np.load(args.y_train)
     es = EmbeddingServer.from_artifact(
         args.artifact, Y_train=Y_train, max_batch=args.max_batch,

@@ -8,17 +8,17 @@ refit.  Three mechanisms make the request path cheap and correct:
     a `MicroBatcher`; a batch closes at `max_batch` rows or after
     `max_delay_s`, so single-row requests still amortize the device
     dispatch;
-  * **bucketed pre-jitted transform steps** — a batch of n rows is padded
-    to the next power-of-two bucket (clamped to the max-batch bucket), so
-    jax's compile cache holds at most log2(max_batch)+1 specializations
-    of the rowwise transform step.  Keys mirror `kernels/autotune.py`
-    (`transform:<kind>:n<bucket>:k..:m..:<dtype>:<device>`), and
-    `cache_info()` reports hits/misses per key;
+  * **bucketed batches** — a batch of n rows is padded to the next
+    power-of-two bucket (clamped to the max-batch bucket).  Keys mirror
+    `kernels/autotune.py` (`transform:<kind>:n<bucket>:k..:m..:<dtype>:
+    <device>`), and `cache_info()` reports hits/misses per key;
   * **the rowwise solver** — the server forces
     `TransformSpec(solver='rowwise')` semantics by default: every row's
     trajectory is independent of batch composition AND of the padding
-    rows, so micro-batching and bucketing provably cannot change any
-    response (tests/test_serve.py pins server == direct transform).
+    rows, and every batch is solved in the same fixed row-chunk program
+    shape (`api/transform.py`), so micro-batching and bucketing cannot
+    change any response on any device (tests/test_serve.py pins server
+    == direct transform, `chip_smoke.py` pins it on a TPU).
 
 Per-request deadlines (`timeout_s`) are enforced while queued; graceful
 shutdown (`close()` / context manager) drains the queue.  With

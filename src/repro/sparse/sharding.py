@@ -14,6 +14,10 @@ Multi-device analogue of the single-device sparse pipeline
     dense path's gradient psum (NOT O(N·k));
   * only the energy/degree scalars are additionally psum'd.
 
+Every collective reduces over the row axes only: the other mesh axes have
+size 1 (`validate_sparse_mesh`) and the per-shard values do not vary along
+them, and jax refuses a psum over an axis its input is invariant on.
+
 The CG hot loop (sparse/linalg.pcg) runs unchanged on replicated (N, d)
 arrays; only the operator application is shard_mapped, and it stays
 scatter-free per shard.  Negative sampling keeps the cyclic-shift
@@ -136,15 +140,16 @@ def _directed_lap_local(xi, Xp, idx, w):
     return directed_lap_apply(w, xi, Xp[idx])
 
 
-def _local_lap_fn(nb: int, k: int, kernel_impl: str, kernel_precision: str,
-                  kernel_lane: int):
+def _local_lap_fn(nb: int, k: int, n_rep: int, kernel_impl: str,
+                  kernel_precision: str, kernel_lane: int):
     """(lap, kernel_active): the per-shard directed-Laplacian closure —
     either the jnp gather or the scalar-prefetch-translated Pallas kernel
     (kernels.ops.ell_lap_matvec_local).  Dispatch (autotune included)
     runs HERE, at build time, outside the shard_map trace; the closure
     traced inside the body carries only static config."""
     kw = ops.resolve_local_ell(nb, k, 0, impl=kernel_impl,
-                               storage_dtype=kernel_precision)
+                               storage_dtype=kernel_precision, n_rep=n_rep,
+                               lane=kernel_lane)
     if kw is None:
         return (lambda xi, Xp, idx, w, row0:
                 _directed_lap_local(xi, Xp, idx, w)), False
@@ -191,11 +196,10 @@ def make_sharded_energy_grad(mesh: Mesh, row_axes: tuple[str, ...],
     negative_pair_terms(kind, jnp.zeros(()))  # reject bad kinds at build
     normalized = is_normalized(kind)
     n, n_pad = sg.n, sg.n_pad
-    all_axes = tuple(mesh.axis_names)
     exhaustive = n_negatives is None or n_negatives >= n - 1
     nb_shard = n_pad // _row_groups(mesh, row_axes)
     lap_local, kernel_active = _local_lap_fn(
-        nb_shard, sg.indices.shape[1], kernel_impl, kernel_precision,
+        nb_shard, sg.indices.shape[1], n_pad, kernel_impl, kernel_precision,
         kernel_lane)
     smap = functools.partial(
         shard_map_norep if kernel_active else shard_map, mesh=mesh)
@@ -228,7 +232,7 @@ def make_sharded_energy_grad(mesh: Mesh, row_axes: tuple[str, ...],
 
         # per-shard partials psum'd ONCE: e_plus and s_hat (the partial Z
         # for normalized kinds) share the collective
-        tot = jax.lax.psum(jnp.stack([e_plus, s_hat]), all_axes)
+        tot = jax.lax.psum(jnp.stack([e_plus, s_hat]), row_axes)
         e_plus_g, s_hat_g = tot[0], tot[1]
         if normalized:
             E = e_plus_g + lam * jnp.log(s_hat_g)
@@ -274,7 +278,7 @@ def make_sharded_energy_grad(mesh: Mesh, row_axes: tuple[str, ...],
         G_loc = 4.0 * (la_x - lam_rep * lb_x)
         G = jnp.zeros_like(Xp)
         G = jax.lax.dynamic_update_slice_in_dim(G, G_loc, row0, 0)
-        G = jax.lax.psum(G, all_axes)                          # O(N d) comm
+        G = jax.lax.psum(G, row_axes)                          # O(N d) comm
         return (E, G, z) if normalized else (E, G)
 
     ell_specs = (P(row_axes, None),) * 4
@@ -347,10 +351,9 @@ def make_sharded_sd_operator(mesh: Mesh, row_axes: tuple[str, ...],
     `make_sharded_energy_grad`)."""
     _, inv_diag, mu = make_sd_operator(saff.graph, saff.rev, mu_scale)
     n, n_pad = sg.n, sg.n_pad
-    all_axes = tuple(mesh.axis_names)
     nb_shard = n_pad // _row_groups(mesh, row_axes)
     lap_local, kernel_active = _local_lap_fn(
-        nb_shard, sg.indices.shape[1], kernel_impl, kernel_precision,
+        nb_shard, sg.indices.shape[1], n_pad, kernel_impl, kernel_precision,
         kernel_lane)
 
     @jax.named_scope("sharded-sd-matvec")
@@ -363,7 +366,7 @@ def make_sharded_sd_operator(mesh: Mesh, row_axes: tuple[str, ...],
                          + lap_local(vi, Vp, ridx, rw, row0))
         out = jnp.zeros_like(Vp)
         out = jax.lax.dynamic_update_slice_in_dim(out, out_loc, row0, 0)
-        return jax.lax.psum(out, all_axes)
+        return jax.lax.psum(out, row_axes)
 
     smap = (shard_map_norep if kernel_active else shard_map)(
         body, mesh=mesh,

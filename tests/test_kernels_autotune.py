@@ -55,20 +55,47 @@ def test_first_search_wins_and_same_key_hits_cache():
 
 
 def test_all_candidates_failing_falls_back_to_first():
+    """There is no fallback: when no candidate compiles the search raises
+    with every candidate's error, and nothing is cached."""
     cands = [KernelConfig(block_rows=8), KernelConfig(block_rows=16)]
 
     def runner(cfg, bucket_n):
         def thunk():
-            raise RuntimeError("nothing compiles")
+            raise RuntimeError(f"nothing compiles at {cfg.block_rows}")
         return thunk
 
-    cfg, hit = autotune.get_config("ell", n=32, k=2, d=2,
-                                   candidates=cands, runner=runner)
-    assert cfg == cands[0] and not hit
-    # the failure is cached — paid once
-    _, hit2 = autotune.get_config("ell", n=32, k=2, d=2,
-                                  candidates=cands, runner=runner)
-    assert hit2
+    with pytest.raises(RuntimeError, match="no candidate") as err:
+        autotune.get_config("ell", n=32, k=2, d=2,
+                            candidates=cands, runner=runner)
+    assert "nothing compiles at 8" in str(err.value)
+    assert "nothing compiles at 16" in str(err.value)
+    key = autotune.cache_key("ell", n=32, k=2, d=2)
+    assert [f["config"]["block_rows"] for f in autotune.failures(key)] \
+        == [8, 16]
+    assert key not in autotune.cached_entries()
+
+
+def test_failed_candidates_listed_in_dispatch():
+    """A candidate that fails is skipped, and the dispatch record names it
+    with its error next to the config that won."""
+    cands = [KernelConfig(block_rows=8), KernelConfig(block_rows=16)]
+
+    def runner(cfg, bucket_n):
+        def thunk():
+            if cfg.block_rows == 8:
+                raise ValueError("refused by the compiler")
+            return jnp.zeros(())
+        return thunk
+
+    cfg, _ = autotune.get_config("ell", n=32, k=2, d=2, candidates=cands,
+                                 runner=runner)
+    assert cfg.block_rows == 16
+    (fail,) = autotune.failures(autotune.cache_key("ell", n=32, k=2, d=2))
+    assert fail["config"]["block_rows"] == 8
+    assert fail["error"] == "ValueError: refused by the compiler"
+    X, idx, w = _rand_graph(14, 32, 4, 2)
+    ops.ell_lap_matvec(X, idx, w, impl="pallas-interpret", lane=8)
+    assert ops.last_dispatch("ell_lap_matvec")["failed"] == []
 
 
 def test_shape_bucket_pow2_and_caps():
@@ -140,23 +167,36 @@ def test_disk_cache_merge_preserves_foreign_entries(tmp_path, monkeypatch):
 
 
 def test_candidates_always_include_legacy_fixed_256():
-    """The kernel-bench acceptance gate (autotuned <= fixed 256) holds by
-    construction: 256 is in every candidate list at n >= 256."""
-    for interp in (True, False):
-        ell = autotune.ell_candidates(n=1024, sublane=8, layouts=["vmem"],
-                                      interpret=interp)
-        assert KernelConfig(block_rows=256, layout="vmem") in ell
-        pw = autotune.pairwise_candidates(n=1024, sublane=8,
-                                          interpret=interp)
-        assert KernelConfig(block_rows=256, block_cols=256,
-                            layout="tiled") in pw
+    """The legacy fixed 256 leads every candidate list at n >= 256, so it
+    is the tile whenever the compiler takes it; smaller tiles follow as
+    fallbacks, largest first."""
+    ell = autotune.ell_candidates(n=1024, sublane=8, layouts=["vmem"])
+    assert ell[0] == KernelConfig(block_rows=256, layout="vmem")
+    rows = [c.block_rows for c in ell]
+    assert rows == sorted(rows, reverse=True)
+    pw = autotune.pairwise_candidates(n=1024, sublane=8)
+    assert pw[0] == KernelConfig(block_rows=256, block_cols=256,
+                                 layout="tiled")
+    # the SMEM cap clips the lead tile, it does not reorder the list
+    capped = autotune.ell_candidates(n=1024, sublane=8, layouts=["vmem"],
+                                     max_rows=192)
+    assert [c.block_rows for c in capped] == [192, 128, 64]
 
 
 def test_hbm_candidates_chunk_divides_block_rows():
-    for cfg in autotune.ell_candidates(n=4096, sublane=8, layouts=["hbm"],
-                                       interpret=False):
-        assert cfg.layout == "hbm" and cfg.chunk > 0
-        assert cfg.block_rows % cfg.chunk == 0
+    for max_chunk in (None, 3, 8):
+        for cfg in autotune.ell_candidates(n=4096, sublane=8,
+                                           layouts=["hbm"],
+                                           max_rows=120,
+                                           max_chunk=max_chunk):
+            assert cfg.layout == "hbm" and cfg.chunk > 0
+            assert cfg.block_rows % cfg.chunk == 0
+            assert cfg.chunk <= (max_chunk or 8)
+    # the one divisor helper every tile shrink goes through
+    assert autotune.fit_divisor(120, 8) == 8
+    assert autotune.fit_divisor(120, 7) == 6
+    assert autotune.fit_divisor(5000, 192, 8) == 40
+    assert autotune.fit_divisor(16, 256, 8) == 16
 
 
 def test_sublane_and_legal_tile():

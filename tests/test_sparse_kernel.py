@@ -185,9 +185,9 @@ def test_local_rows_kernel_traced_row0():
 
 def test_resolve_local_ell_dispatch():
     # auto on CPU routes to the jnp per-shard gather, transparently
-    assert ops.resolve_local_ell(16, 4, 2) is None
+    assert ops.resolve_local_ell(16, 4, 2, n_rep=32) is None
     assert ops.last_dispatch("ell_lap_matvec_local")["reason"] == "no-tpu"
     # forced interpret: block_rows must tile the shard exactly
-    kw = ops.resolve_local_ell(24, 4, 2, impl="pallas-interpret")
+    kw = ops.resolve_local_ell(24, 4, 2, n_rep=48, impl="pallas-interpret")
     assert kw is not None and 24 % kw["block_rows"] == 0
     assert ops.last_dispatch("ell_lap_matvec_local")["path"] == "pallas"
