@@ -14,8 +14,9 @@ build.  Each backend rejects the other family's input with a pointed
 error instead of silently ignoring it.
 
 Telemetry: each backend activates `telemetry.tracer` around *both* the
-objective build (so graph-build / spectral-init spans land in the trace)
-and the fit loop, then hands the `Telemetry` on to `fit_loop` which wires
+objective build (so graph-build / spectral-init spans land in the trace;
+on the dense path they block on their outputs only while traced) and the
+fit loop, then hands the `Telemetry` on to `fit_loop` which wires
 its `RunRecorder` into the iteration stream.
 """
 from __future__ import annotations
@@ -30,7 +31,7 @@ from repro.embed.engine import fit_loop
 from repro.embed.trainer import (build_dense_mesh_objective,
                                  build_sparse_objective,
                                  build_tree_objective, make_loop_config)
-from repro.obs import activate, span
+from repro.obs import activate, block_if_traced, span
 
 from .registries import attach_backend_impl, strategy_entry
 
@@ -54,11 +55,11 @@ def _dense_problem(spec, Y, X0, aff):
         if Y is None:
             raise ValueError("fit needs Y (or a precomputed aff=)")
         with span("graph-build", phase=True, dense=True):
-            aff = make_affinities(jnp.asarray(Y), spec.perplexity,
-                                  model=spec.kind)
+            aff = block_if_traced(make_affinities(
+                jnp.asarray(Y), spec.perplexity, model=spec.kind))
     if X0 is None:
         with span("spectral-init", phase=True):
-            X0 = laplacian_eigenmaps(aff.Wp, spec.dim) * 0.1
+            X0 = block_if_traced(laplacian_eigenmaps(aff.Wp, spec.dim) * 0.1)
     return aff, jnp.asarray(X0)
 
 

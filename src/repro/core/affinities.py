@@ -70,31 +70,32 @@ def calibrated_conditionals(
     every fit recompiled the bisection program (caught by the
     compile-count guard in tests/test_analysis.py); jitted here it
     compiles once per (shape, dtype) and perplexity changes are free."""
-    n = D2.shape[0]
-    target = jnp.log(jnp.asarray(perplexity, dtype=D2.dtype))
-    eye = jnp.eye(n, dtype=bool)
+    with jax.named_scope("affinities"):
+        n = D2.shape[0]
+        target = jnp.log(jnp.asarray(perplexity, dtype=D2.dtype))
+        eye = jnp.eye(n, dtype=bool)
 
-    def solve_row(d2_row, self_row):
-        def body(_, carry):
-            lo, hi, beta = carry
-            h, _ = _row_entropy_probs(d2_row, beta, self_row)
-            # entropy decreases in beta: too much entropy -> raise beta
-            too_high = h > target
-            lo = jnp.where(too_high, beta, lo)
-            hi = jnp.where(too_high, hi, beta)
-            beta = jnp.where(
-                jnp.isinf(hi), beta * 2.0, 0.5 * (lo + hi)
-            )
-            return lo, hi, beta
+        def solve_row(d2_row, self_row):
+            def body(_, carry):
+                lo, hi, beta = carry
+                h, _ = _row_entropy_probs(d2_row, beta, self_row)
+                # entropy decreases in beta: too much entropy -> raise beta
+                too_high = h > target
+                lo = jnp.where(too_high, beta, lo)
+                hi = jnp.where(too_high, hi, beta)
+                beta = jnp.where(
+                    jnp.isinf(hi), beta * 2.0, 0.5 * (lo + hi)
+                )
+                return lo, hi, beta
 
-        lo0 = jnp.asarray(0.0, D2.dtype)
-        hi0 = jnp.asarray(jnp.inf, D2.dtype)
-        beta0 = jnp.asarray(1.0, D2.dtype)
-        _, _, beta = jax.lax.fori_loop(0, n_iter, body, (lo0, hi0, beta0))
-        _, p = _row_entropy_probs(d2_row, beta, self_row)
-        return p
+            lo0 = jnp.asarray(0.0, D2.dtype)
+            hi0 = jnp.asarray(jnp.inf, D2.dtype)
+            beta0 = jnp.asarray(1.0, D2.dtype)
+            _, _, beta = jax.lax.fori_loop(0, n_iter, body, (lo0, hi0, beta0))
+            _, p = _row_entropy_probs(d2_row, beta, self_row)
+            return p
 
-    return jax.vmap(solve_row)(D2, eye)
+        return jax.vmap(solve_row)(D2, eye)
 
 
 def sne_affinities(Y: Array, perplexity: float = 30.0) -> Array:

@@ -47,7 +47,9 @@ def _step(strategy, kind, ls_cfg: LSConfig, X, E, G, state,
           alpha_prev, Wp, Wm, lam, impl=()):
     impl = dict(impl)   # hashable (k, v) pairs -> kernels.ops kwargs
     aff = Affinities(Wp, Wm)
-    P, state = strategy.direction(state, X, G, aff, kind, lam)
+    # device scopes (docs/observability.md): op metadata only
+    with jax.named_scope("direction-solve"):
+        P, state = strategy.direction(state, X, G, aff, kind, lam)
     if ls_cfg.init_step == "adaptive":
         alpha0 = alpha_prev
     elif ls_cfg.init_step == "adaptive_grow":
@@ -59,12 +61,15 @@ def _step(strategy, kind, ls_cfg: LSConfig, X, E, G, state,
         scale = jnp.sqrt(jnp.mean(xc * xc)) + 1e-3
         p_rms = jnp.sqrt(jnp.mean(P * P)) + 1e-30
         alpha0 = jnp.minimum(alpha0, ls_cfg.max_rel_move * scale / p_rms)
-    ls = backtracking(
-        lambda Xn: energy(Xn, aff, kind, lam, **impl), X, E, G, P, alpha0,
-        ls_cfg
-    )
+
+    def energy_of(Xn):
+        with jax.named_scope("objective"):
+            return energy(Xn, aff, kind, lam, **impl)
+
+    ls = backtracking(energy_of, X, E, G, P, alpha0, ls_cfg)
     X_new = X + ls.alpha * P
-    E_new, G_new = energy_and_grad(X_new, aff, kind, lam, **impl)
+    with jax.named_scope("objective"):
+        E_new, G_new = energy_and_grad(X_new, aff, kind, lam, **impl)
     return X_new, E_new, G_new, state, ls.alpha, ls.n_evals + 1
 
 

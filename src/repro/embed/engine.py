@@ -195,8 +195,11 @@ def fit_loop(
     still works but is deprecated — prefer the 4-arg form or the
     `on_iteration(it, X, diagnostics)` hook.  `telemetry` is a
     `repro.obs.Telemetry`: its recorder gets one typed record per
-    iteration (JSONL when configured) and the engine's phase spans
-    (setup / compile / solve-iter / checkpoint) land on its tracer.
+    iteration (JSONL when configured) and the engine's spans land on its
+    tracer: `setup`, `compile`, per iteration `solve-iter` (inside it
+    `step` + `fetch` on the fused path, `direction` + `line-search` +
+    `grad` on the host path) then `iter-host` (records, callbacks,
+    convergence test), and `checkpoint` (docs/observability.md).
     """
     cb_wants_diag = (callback is not None
                      and _callback_wants_diagnostics(callback))
@@ -341,12 +344,15 @@ def _fit_loop(objective, X0, cfg, callback, cb_wants_diag, on_iteration,
     for it in range(start_it + 1, cfg.max_iters + 1):
         with span("solve-iter", it=it):
             if fused_step is not None:
-                X, E_new, G, state, alpha_dev, ne = jax.block_until_ready(
-                    fused_step(X, E, G, state, alpha_dev))
+                with span("step"):
+                    X, E_new, G, state, alpha_dev, ne = \
+                        jax.block_until_ready(
+                            fused_step(X, E, G, state, alpha_dev))
                 # one batched transfer for all per-iteration scalars
                 # (RPR001): energy, |G|, accepted step, n_evals
-                vals = jax.device_get(
-                    (E_new, jnp.linalg.norm(G), alpha_dev, ne))
+                with span("fetch"):
+                    vals = jax.device_get(
+                        (E_new, jnp.linalg.norm(G), alpha_dev, ne))
                 e_rec, g_host, alpha_host = (float(v) for v in vals[:3])
                 n_ev = int(vals[3])
             else:
@@ -355,63 +361,71 @@ def _fit_loop(objective, X0, cfg, callback, cb_wants_diag, on_iteration,
                     # one PRNG key per iteration: the line search descends
                     # a deterministic surrogate (common random numbers)
                     key = jax.random.fold_in(key0, it)
-                    E, G = objective.energy_and_grad(X, key)
-                    # E is e0 for the backtrack below; batch it with
-                    # |G| in one transfer (RPR001)
-                    e_host, g_host = (float(v) for v in
-                                      jax.device_get((E, jnp.linalg.norm(G))))
+                    with span("grad"):
+                        E, G = objective.energy_and_grad(X, key)
+                        # E is e0 for the backtrack below; batch it with
+                        # |G| in one transfer (RPR001)
+                        e_host, g_host = (
+                            float(v) for v in
+                            jax.device_get((E, jnp.linalg.norm(G))))
                     n_ev += 1
                 else:
                     # deterministic: E is unchanged since its transfer
                     # last iteration (or pre-loop) — reuse the host copy
                     e_host = energies[-1]
-                P, state = solve(state, X, G)
-                alpha0 = initial_step(X, P, alpha_host, cfg.ls)
-                alpha_host, e_new, n_bt = host_backtrack(
-                    lambda Xn: float(objective.energy(Xn, key)),
-                    X, e_host, G, P, alpha0, cfg.ls)
+                # the host waits for P in initial_step's transfer
+                with span("direction"):
+                    P, state = solve(state, X, G)
+                    alpha0 = initial_step(X, P, alpha_host, cfg.ls)
+                with span("line-search"):
+                    alpha_host, e_new, n_bt = host_backtrack(
+                        lambda Xn: float(objective.energy(Xn, key)),
+                        X, e_host, G, P, alpha0, cfg.ls)
                 n_ev += n_bt
                 X = X + alpha_host * P
                 if stochastic:
                     e_rec = e_new  # this iteration's surrogate, accepted X
                 else:
-                    E, G = objective.energy_and_grad(X, key)
-                    e_rec, g_host = (float(v) for v in
-                                     jax.device_get((E, jnp.linalg.norm(G))))
+                    with span("grad"):
+                        E, G = objective.energy_and_grad(X, key)
+                        e_rec, g_host = (
+                            float(v) for v in
+                            jax.device_get((E, jnp.linalg.norm(G))))
                     n_ev += 1
-        now = time.perf_counter() - t_loop
-        energies.append(e_rec)
-        gnorms.append(g_host)
-        steps.append(alpha_host)
-        times.append(now)
-        fevals.append(fevals[-1] + n_ev)
-        diag = None
-        if want_diag:
-            extras = dict(obj_diag()) if obj_diag is not None else {}
-            if record_memory:
-                extras.update(device_memory_stats())
-            diag = {"it": it, "energy": e_rec, "grad_norm": gnorms[-1],
-                    "alpha": alpha_host, "n_evals": n_ev, "t": now,
-                    "iter_s": now - times[-2], **extras}
-            diags.append(diag)
-            if recorder is not None:
-                recorder.record(IterationRecord(
-                    it=it, energy=e_rec, grad_norm=gnorms[-1],
-                    alpha=alpha_host, n_evals=n_ev, t=now,
-                    iter_s=now - times[-2], extras=extras))
-        if callback is not None:
-            if cb_wants_diag:
-                callback(it, X, e_rec, diag)
+        with span("iter-host", it=it):
+            now = time.perf_counter() - t_loop
+            energies.append(e_rec)
+            gnorms.append(g_host)
+            steps.append(alpha_host)
+            times.append(now)
+            fevals.append(fevals[-1] + n_ev)
+            diag = None
+            if want_diag:
+                extras = dict(obj_diag()) if obj_diag is not None else {}
+                if record_memory:
+                    extras.update(device_memory_stats())
+                diag = {"it": it, "energy": e_rec, "grad_norm": gnorms[-1],
+                        "alpha": alpha_host, "n_evals": n_ev, "t": now,
+                        "iter_s": now - times[-2], **extras}
+                diags.append(diag)
+                if recorder is not None:
+                    recorder.record(IterationRecord(
+                        it=it, energy=e_rec, grad_norm=gnorms[-1],
+                        alpha=alpha_host, n_evals=n_ev, t=now,
+                        iter_s=now - times[-2], extras=extras))
+            if callback is not None:
+                if cb_wants_diag:
+                    callback(it, X, e_rec, diag)
+                else:
+                    callback(it, X, e_rec)
+            if on_iteration is not None:
+                on_iteration(it, X, diag)
+            if conv == "ema":
+                ema_new = cfg.ema_decay * ema + (1.0 - cfg.ema_decay) * e_rec
+                rel = abs(ema - ema_new) / max(abs(ema_new), 1e-30)
+                ema = ema_new
             else:
-                callback(it, X, e_rec)
-        if on_iteration is not None:
-            on_iteration(it, X, diag)
-        if conv == "ema":
-            ema_new = cfg.ema_decay * ema + (1.0 - cfg.ema_decay) * e_rec
-            rel = abs(ema - ema_new) / max(abs(ema_new), 1e-30)
-            ema = ema_new
-        else:
-            rel = abs(energies[-2] - e_rec) / max(abs(e_rec), 1e-30)
+                rel = abs(energies[-2] - e_rec) / max(abs(e_rec), 1e-30)
         if ckpt is not None and it % cfg.checkpoint_every == 0:
             save(it)
         if rel < cfg.tol:

@@ -45,7 +45,7 @@ from repro.core.laplacian import degree
 from repro.core.linesearch import LSConfig
 from repro.core.objectives import attractive_weights
 from repro.core.strategies import _jitter
-from repro.obs import span
+from repro.obs import block_if_traced, span
 from repro.sparse import (energy_and_grad_tree, make_grid_plan,
                           make_sd_operator, make_sharded_energy_grad,
                           make_sharded_sd_operator, pcg,
@@ -319,8 +319,11 @@ def build_dense_mesh_objective(cfg, mesh: Mesh,
     with span("graph-build", phase=True, n=Y.shape[0], dense=True):
         aff = jax.block_until_ready(
             make_affinities(jnp.asarray(Y), cfg.perplexity, model=cfg.kind))
-    X = jnp.asarray(X0) if X0 is not None \
-        else laplacian_eigenmaps(aff.Wp, cfg.dim) * 0.1
+    if X0 is not None:
+        X = jnp.asarray(X0)
+    else:
+        with span("spectral-init", phase=True, n=Y.shape[0]):
+            X = block_if_traced(laplacian_eigenmaps(aff.Wp, cfg.dim) * 0.1)
     lam = jnp.asarray(cfg.lam, X.dtype)
 
     # W- == 1 off-diagonal for every supported affinity builder: use the
@@ -407,8 +410,9 @@ def _make_direction_solve(strategy: str, matvec, inv_diag, cfg,
         def solve(G, P0):
             # surface the PCG counters the solver computes anyway — two
             # extra scalar outputs, no extra work in the jitted program
-            r = pcg(matvec, -G, P0, inv_diag=inv_diag,
-                    tol=cfg.cg_tol, maxiter=cfg.cg_maxiter)
+            with jax.named_scope("direction-solve"):
+                r = pcg(matvec, -G, P0, inv_diag=inv_diag,
+                        tol=cfg.cg_tol, maxiter=cfg.cg_maxiter)
             return r.x, {"pcg_iters": r.n_iters,
                          "pcg_residual": r.rel_residual}
         return solve
@@ -487,26 +491,30 @@ def build_sparse_objective(cfg, mesh: Mesh | None = None,
         matvec, inv_diag, _ = make_sd_operator(saff.graph, saff.rev,
                                                cfg.mu_scale, **kernel_args)
 
+        # device scope `objective` on every evaluation (op metadata only)
         if normalized:
             @jax.jit
             def eg(X, key, z):
-                return energy_and_grad_sparse(
-                    X, saff, cfg.kind, lam,
-                    n_negatives=cfg.n_negatives, key=key, z_prev=z,
-                    z_decay=cfg.z_ema_decay, return_state=True)
+                with jax.named_scope("objective"):
+                    return energy_and_grad_sparse(
+                        X, saff, cfg.kind, lam,
+                        n_negatives=cfg.n_negatives, key=key, z_prev=z,
+                        z_decay=cfg.z_ema_decay, return_state=True)
         else:
             @jax.jit
             def eg(X, key):
-                return energy_and_grad_sparse(
-                    X, saff, cfg.kind, lam,
-                    n_negatives=cfg.n_negatives, key=key)
+                with jax.named_scope("objective"):
+                    return energy_and_grad_sparse(
+                        X, saff, cfg.kind, lam,
+                        n_negatives=cfg.n_negatives, key=key)
 
         @jax.jit
         def e_only(X, key):
             # line-search trials need no gradient: ~half the work
-            return energy_and_grad_sparse(
-                X, saff, cfg.kind, lam, n_negatives=cfg.n_negatives,
-                key=key, with_grad=False)[0]
+            with jax.named_scope("objective"):
+                return energy_and_grad_sparse(
+                    X, saff, cfg.kind, lam, n_negatives=cfg.n_negatives,
+                    key=key, with_grad=False)[0]
 
         place = None
 

@@ -26,13 +26,16 @@ time, outside any jit) wrapping jitted implementations:
 
 Every decision is recorded — never silent:
 
-  * a `repro.obs` span (`kernel/pairwise_terms`, `kernel/ell_lap_matvec`)
-    carries `path`, `reason`, `layout`, and the chosen tile config as
-    span args (trace-time, once per compiled shape);
-  * an active telemetry recorder gets the same dict merged into its
-    `kernel_dispatch` meta (surfaced by `repro.obs.report`);
+  * an active telemetry recorder gets `path`, `reason`, `layout` and the
+    chosen tile config merged into its `kernel_dispatch` meta (surfaced
+    by `repro.obs.report`);
   * `last_dispatch()` returns the most recent decision per kernel for
     tests and benchmarks.
+
+On the device, each `pallas_call` is named after its jitted wrapper
+(`_ell_pallas`, `_pairwise_pallas`, `_bh_pallas`): the HLO instruction
+and the op's scope path carry that name in a profiler capture
+(docs/observability.md).
 
 Tile legality: requested/autotuned tile sizes are clamped to the row
 count and then rounded UP to the hardware sublane multiple (8 rows for
@@ -50,7 +53,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.obs import current_tracer, span
+from repro.obs import current_tracer
 
 from . import autotune
 from .farfield import bh_interaction_pallas
@@ -289,8 +292,7 @@ def ell_lap_matvec(
     if path == "jnp":
         info = {"path": "jnp", "reason": reason, "storage": storage}
         _record("ell_lap_matvec", info)
-        with span("kernel/ell_lap_matvec", n=n, k=k, **info):
-            return _ell_jnp(X, indices, weights, storage)
+        return _ell_jnp(X, indices, weights, storage)
 
     max_rows = smem_rows(k)
     max_chunk = hbm_max_chunk(k, max(lane, d))
@@ -328,10 +330,9 @@ def ell_lap_matvec(
             "autotuned": autotuned, "cache_hit": cache_hit,
             "failed": failed}
     _record("ell_lap_matvec", info)
-    with span("kernel/ell_lap_matvec", n=n, k=k, **info):
-        return _ell_pallas(X, indices, weights, block_rows=br, layout=lay,
-                           chunk=ch, interpret=interp, lane=lane,
-                           storage=storage, vlimit=vlimit)
+    return _ell_pallas(X, indices, weights, block_rows=br, layout=lay,
+                       chunk=ch, interpret=interp, lane=lane,
+                       storage=storage, vlimit=vlimit)
 
 
 # -- fused pairwise terms --------------------------------------------------------
@@ -384,8 +385,7 @@ def pairwise_terms(
         reason = "forced-off" if impl == "jnp" else "no-tpu"
         info = {"path": "jnp", "reason": reason, "storage": storage}
         _record("pairwise_terms", info)
-        with span("kernel/pairwise_terms", n=n, kind=kind, **info):
-            return _pairwise_jnp(X, Wa, Wb, kind, storage)
+        return _pairwise_jnp(X, Wa, Wb, kind, storage)
 
     reason = "tpu-default" if impl == "auto" else "forced-on"
     if interpret is None:
@@ -420,10 +420,9 @@ def pairwise_terms(
             "interpret": interpret, "autotuned": autotuned,
             "cache_hit": cache_hit, "failed": failed}
     _record("pairwise_terms", info)
-    with span("kernel/pairwise_terms", n=n, kind=kind, **info):
-        return _pairwise_pallas(X, Wa, Wb, kind=kind, block_rows=br,
-                                block_cols=bc, interpret=interpret,
-                                lane=lane, storage=storage)
+    return _pairwise_pallas(X, Wa, Wb, kind=kind, block_rows=br,
+                            block_cols=bc, interpret=interpret,
+                            lane=lane, storage=storage)
 
 
 # -- Barnes-Hut cell interaction -------------------------------------------------
@@ -492,9 +491,7 @@ def bh_interaction(
     if reason is not None:
         info = {"path": "jnp", "reason": reason, "storage": storage}
         _record("bh_interaction", info)
-        with span("kernel/bh_interaction", n=n, w=width, m=m, kind=kind,
-                  **info):
-            return _bh_jnp(X, idx, w, table, kind, storage)
+        return _bh_jnp(X, idx, w, table, kind, storage)
 
     reason = "tpu-default" if impl == "auto" else "forced-on"
     if interpret is None:
@@ -530,10 +527,9 @@ def bh_interaction(
             "autotuned": autotuned, "cache_hit": cache_hit,
             "failed": failed}
     _record("bh_interaction", info)
-    with span("kernel/bh_interaction", n=n, w=width, m=m, kind=kind, **info):
-        return _bh_pallas(X, idx, w, table, kind=kind, block_rows=br,
-                          interpret=interpret, lane=lane, storage=storage,
-                          vlimit=vlimit)
+    return _bh_pallas(X, idx, w, table, kind=kind, block_rows=br,
+                      interpret=interpret, lane=lane, storage=storage,
+                      vlimit=vlimit)
 
 
 # -- sharded local-rows ELL matvec -----------------------------------------------

@@ -12,9 +12,11 @@ more than energy and wall-clock.  This package is the substrate:
   * `SpanTracer` + `span()` — a contextvar-scoped span-timer API with
     Chrome-trace-event (Perfetto-loadable) export and an optional
     `jax.profiler.TraceAnnotation` hookup; instrumentation points in
-    `embed/engine.py`, `sparse/graph.py`, `sparse/sharding.py` and
-    `kernels/ops.py` are no-ops (one contextvar read) unless a tracer is
-    active, so the hot paths stay provably cheap when telemetry is off;
+    `embed/engine.py`, `api/backends.py`, `sparse/graph.py` and
+    `sparse/sharding.py` are no-ops (one contextvar read) unless a tracer
+    is active, so the hot paths stay provably cheap when telemetry is off
+    (device work is attributed by `jax.named_scope` in the traced code,
+    docs/observability.md);
   * `Telemetry` — the user-facing switch: `Embedding.fit(telemetry=...)`
     accepts `True`, an output directory, or a `Telemetry` instance;
   * `python -m repro.obs.report run.jsonl [other.jsonl]` renders one run
@@ -25,7 +27,8 @@ so every layer of the stack can depend on `repro.obs` without cycles.
 """
 from .record import (IterationRecord, RequestRecord, RunRecorder,
                      device_memory_stats, load_jsonl, load_requests)
-from .spans import SpanTracer, activate, current_tracer, span
+from .spans import (SpanTracer, activate, block_if_traced,
+                    current_tracer, span)
 from .telemetry import Telemetry, resolve_telemetry
 
 __all__ = [
@@ -35,6 +38,7 @@ __all__ = [
     "SpanTracer",
     "Telemetry",
     "activate",
+    "block_if_traced",
     "current_tracer",
     "device_memory_stats",
     "load_jsonl",

@@ -7,19 +7,24 @@ a shared no-op context manager — one dict-free contextvar read, so
 instrumentation points cost nothing in uninstrumented runs (the <5%
 telemetry overhead budget is asserted in the bench gate).
 
-Spans measure HOST wall-clock.  For a span wrapping a jitted callable
-that fires inside another trace, that is trace/compile time (recorded
-once per compile); for eager call sites it is dispatch-to-completion when
-the caller blocks, dispatch-only otherwise — `fit_loop` blocks on its
-per-iteration results, so its `solve-iter` spans are true step times.
+Spans measure HOST wall-clock: dispatch-to-completion where the code
+blocks inside the span, dispatch-only otherwise.  `fit_loop` blocks on
+its per-iteration results, so its `solve-iter`/`step`/`fetch` spans are
+true step times; `block_if_traced` makes a pre-loop span (dense
+`graph-build`, `spectral-init`) block only while a tracer is active.
+Device-side attribution is `jax.named_scope`, not spans: a span around a
+jitted callee that fires inside another trace would time tracing.
 
 Export is the Chrome trace-event JSON format (`{"traceEvents": [...]}`,
 complete "X" events with microsecond `ts`/`dur`), loadable in Perfetto
-(ui.perfetto.dev) or `chrome://tracing`.  With `jax_annotations=True`
-every span additionally enters a `jax.profiler.TraceAnnotation`, so the
-same names show up inside a `jax.profiler.trace` capture next to the XLA
-events — the hookup is best-effort and degrades to host spans when the
-profiler is unavailable.
+(ui.perfetto.dev) or `chrome://tracing`.  `ts` is on the profiler's
+clock (Unix-epoch microseconds, `time.time_ns`), so the exported file
+lines up with a `jax.profiler` capture without an offset; `dur` comes
+from `perf_counter`.  With `jax_annotations=True` every span
+additionally enters a `jax.profiler.TraceAnnotation`, so the same names
+show up inside a `jax.profiler.trace` capture next to the XLA events —
+the hookup is best-effort and degrades to host spans when the profiler
+is unavailable.
 """
 from __future__ import annotations
 
@@ -46,7 +51,7 @@ _NOOP = _NoopSpan()
 
 
 class _Span:
-    __slots__ = ("tracer", "name", "phase", "args", "t0", "_ann")
+    __slots__ = ("tracer", "name", "phase", "args", "t0", "ts_ns", "_ann")
 
     def __init__(self, tracer: "SpanTracer", name: str, phase: bool,
                  args: dict[str, Any]):
@@ -65,6 +70,7 @@ class _Span:
             except Exception:
                 self._ann = None
         self.tracer._depth += 1
+        self.ts_ns = time.time_ns()
         self.t0 = time.perf_counter()
         return self
 
@@ -76,7 +82,8 @@ class _Span:
                 self._ann.__exit__(*exc)
             except Exception:
                 pass
-        self.tracer._close(self.name, self.t0, t1, self.args, self.phase)
+        self.tracer._close(self.name, self.ts_ns, t1 - self.t0, self.args,
+                           self.phase)
         return False
 
 
@@ -93,19 +100,18 @@ class SpanTracer:
         self.jax_annotations = jax_annotations
         self.recorder = recorder
         self.events: list[dict[str, Any]] = []
-        self._t0 = time.perf_counter()
         self._depth = 0
 
     def span(self, name: str, *, phase: bool = False, **args: Any) -> _Span:
         return _Span(self, name, phase, args)
 
-    def _close(self, name: str, t0: float, t1: float,
+    def _close(self, name: str, ts_ns: int, dur_s: float,
                args: dict[str, Any], phase: bool) -> None:
         ev = {
             "name": name,
             "ph": "X",
-            "ts": (t0 - self._t0) * 1e6,       # microseconds
-            "dur": (t1 - t0) * 1e6,
+            "ts": ts_ns / 1e3,       # epoch microseconds: the profiler's clock
+            "dur": dur_s * 1e6,
             "pid": 0,
             "tid": 0,
         }
@@ -113,7 +119,7 @@ class SpanTracer:
             ev["args"] = args
         self.events.append(ev)
         if phase and self.recorder is not None:
-            self.recorder.record_phase(name, t1 - t0)
+            self.recorder.record_phase(name, dur_s)
 
     # -- export -------------------------------------------------------------
     def to_chrome_trace(self) -> dict[str, Any]:
@@ -154,6 +160,17 @@ def activate(tracer: SpanTracer | None) -> _Activation:
     `activate(None)` is a supported no-op scope (backends pass their
     telemetry's tracer straight through, active or not)."""
     return _Activation(tracer)
+
+
+def block_if_traced(tree):
+    """`tree` after its arrays are ready while a tracer is active, so the
+    span around its dispatch times the device work too; unchanged (no
+    wait) when none is."""
+    if _ACTIVE.get() is None:
+        return tree
+    import jax
+
+    return jax.block_until_ready(tree)
 
 
 def span(name: str, *, phase: bool = False, **args: Any):

@@ -336,26 +336,27 @@ def calibrated_weights_ell(d2: Array, valid: Array, perplexity: float,
     k-atom maximum log(k); bisection then drives beta -> 0 and the row
     degenerates to uniform over its candidates — callers should keep
     k >~ 3 * perplexity (t-SNE convention)."""
-    target = jnp.log(jnp.asarray(perplexity, dtype=d2.dtype))
+    with jax.named_scope("affinities"):
+        target = jnp.log(jnp.asarray(perplexity, dtype=d2.dtype))
 
-    def solve_row(d2_row, valid_row):
-        def body(_, carry):
-            lo, hi, beta = carry
-            h, _ = _row_entropy_probs_ell(d2_row, beta, valid_row)
-            too_high = h > target
-            lo = jnp.where(too_high, beta, lo)
-            hi = jnp.where(too_high, hi, beta)
-            beta = jnp.where(jnp.isinf(hi), beta * 2.0, 0.5 * (lo + hi))
-            return lo, hi, beta
+        def solve_row(d2_row, valid_row):
+            def body(_, carry):
+                lo, hi, beta = carry
+                h, _ = _row_entropy_probs_ell(d2_row, beta, valid_row)
+                too_high = h > target
+                lo = jnp.where(too_high, beta, lo)
+                hi = jnp.where(too_high, hi, beta)
+                beta = jnp.where(jnp.isinf(hi), beta * 2.0, 0.5 * (lo + hi))
+                return lo, hi, beta
 
-        lo0 = jnp.asarray(0.0, d2.dtype)
-        hi0 = jnp.asarray(jnp.inf, d2.dtype)
-        beta0 = jnp.asarray(1.0, d2.dtype)
-        _, _, beta = jax.lax.fori_loop(0, n_iter, body, (lo0, hi0, beta0))
-        _, p = _row_entropy_probs_ell(d2_row, beta, valid_row)
-        return p
+            lo0 = jnp.asarray(0.0, d2.dtype)
+            hi0 = jnp.asarray(jnp.inf, d2.dtype)
+            beta0 = jnp.asarray(1.0, d2.dtype)
+            _, _, beta = jax.lax.fori_loop(0, n_iter, body, (lo0, hi0, beta0))
+            _, p = _row_entropy_probs_ell(d2_row, beta, valid_row)
+            return p
 
-    return jax.vmap(solve_row)(d2, valid)
+        return jax.vmap(solve_row)(d2, valid)
 
 
 def sparse_affinities(Y: Array, k: int, perplexity: float = 30.0,

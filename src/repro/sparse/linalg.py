@@ -73,10 +73,14 @@ def sym_lap_matvec(g: NeighborGraph, X: Array,
     scatter-add is orders of magnitude slower than the gather.  Without
     `rev` the transpose half falls back to scatter-add — fine for graphs
     that change every iteration (sampled negatives) where building the
-    transpose would itself cost a scatter."""
-    la_x = ops.ell_lap_matvec(X, g.indices, g.weights, **impl)
+    transpose would itself cost a scatter.  The two halves run under the
+    device scopes `laplacian/forward` and `laplacian/reverse`
+    (docs/observability.md)."""
+    with jax.named_scope("laplacian/forward"):
+        la_x = ops.ell_lap_matvec(X, g.indices, g.weights, **impl)
     if rev is not None:
-        lat_x = ops.ell_lap_matvec(X, rev.indices, rev.weights, **impl)
+        with jax.named_scope("laplacian/reverse"):
+            lat_x = ops.ell_lap_matvec(X, rev.indices, rev.weights, **impl)
     else:
         lat_x = in_degree(g)[:, None] * X - ell_t_matvec(g, X)
     return 0.5 * (la_x + lat_x)
@@ -130,31 +134,32 @@ def sparse_laplacian_eigenmaps(g: NeighborGraph,
     lambda_{d+1} / lambda_{d+2} gap.  Matches the dense routine's gauge:
     drop the trivial top eigenvector, map back through D^{-1/2}, center,
     unit std per dimension."""
-    n = g.n
-    dg = jnp.maximum(sym_degree(g) if rev is None
-                     else 0.5 * (out_degree(g) + out_degree(rev)), 1e-12)
-    dinv = 1.0 / jnp.sqrt(dg)
+    with jax.named_scope("spectral-init"):
+        n = g.n
+        dg = jnp.maximum(sym_degree(g) if rev is None
+                         else 0.5 * (out_degree(g) + out_degree(rev)), 1e-12)
+        dinv = 1.0 / jnp.sqrt(dg)
 
-    def Mv(V):
-        return dinv[:, None] * sym_matvec(g, dinv[:, None] * V, rev=rev)
+        def Mv(V):
+            return dinv[:, None] * sym_matvec(g, dinv[:, None] * V, rev=rev)
 
-    V = jax.random.normal(jax.random.PRNGKey(seed),
-                          (n, min(d + 1 + oversample, n)),
-                          dtype=g.weights.dtype)
-    V, _ = jnp.linalg.qr(V)
+        V = jax.random.normal(jax.random.PRNGKey(seed),
+                              (n, min(d + 1 + oversample, n)),
+                              dtype=g.weights.dtype)
+        V, _ = jnp.linalg.qr(V)
 
-    def sweep(_, V):
-        V, _ = jnp.linalg.qr(Mv(V) + V)
-        return V
+        def sweep(_, V):
+            V, _ = jnp.linalg.qr(Mv(V) + V)
+            return V
 
-    V = jax.lax.fori_loop(0, n_iters, sweep, V)
-    # Rayleigh-Ritz: order the converged subspace by eigenvalue of M
-    T = V.T @ Mv(V)
-    _, S = jnp.linalg.eigh(0.5 * (T + T.T))    # ascending
-    U = V @ S[:, ::-1]                          # descending: col 0 trivial
-    X = dinv[:, None] * U[:, 1:d + 1]
-    X = X - jnp.mean(X, axis=0, keepdims=True)
-    return X / jnp.maximum(jnp.std(X, axis=0, keepdims=True), 1e-12)
+        V = jax.lax.fori_loop(0, n_iters, sweep, V)
+        # Rayleigh-Ritz: order the converged subspace by eigenvalue of M
+        T = V.T @ Mv(V)
+        _, S = jnp.linalg.eigh(0.5 * (T + T.T))    # ascending
+        U = V @ S[:, ::-1]                          # descending: col 0 trivial
+        X = dinv[:, None] * U[:, 1:d + 1]
+        X = X - jnp.mean(X, axis=0, keepdims=True)
+        return X / jnp.maximum(jnp.std(X, axis=0, keepdims=True), 1e-12)
 
 
 # -- preconditioned CG ----------------------------------------------------------

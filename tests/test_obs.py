@@ -236,11 +236,17 @@ def test_on_iteration_hook(Y):
     assert res.diagnostics is not None                  # hook implies diag
 
 
-def test_telemetry_off_trajectory_unchanged(Y):
-    spec = _sparse_spec(iters=4)
-    e_off = Embedding(spec).fit(Y).result_.energies
-    e_on = Embedding(spec).fit(Y, telemetry=True).result_.energies
-    np.testing.assert_array_equal(np.asarray(e_off), np.asarray(e_on))
+@pytest.mark.parametrize("backend", ["dense", "sparse", "tree"])
+def test_telemetry_off_trajectory_unchanged(Y, backend):
+    # traced with the profiler annotations on: the engine's spans and the
+    # programs' device scopes change nothing the fit computes
+    spec = _sparse_spec(iters=4).replace(backend=backend)
+    off = Embedding(spec).fit(Y).result_
+    on = Embedding(spec).fit(
+        Y, telemetry=Telemetry(jax_annotations=True)).result_
+    np.testing.assert_array_equal(np.asarray(off.energies),
+                                  np.asarray(on.energies))
+    np.testing.assert_array_equal(np.asarray(off.X), np.asarray(on.X))
 
 
 # -- resume contiguity -----------------------------------------------------------

@@ -11,6 +11,8 @@ The topology is described inside a module fixture, never at import:
 only one process at a time may load the TPU library, and every test
 worker imports this file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -46,14 +48,33 @@ def _ell_args(n, k, sharding):
             _sds((n, k), F32, sharding))
 
 
-def _compile_ell(n, k, layout, sharding):
+def _ell_static(n, k, layout):
     br = min(ops.legal_tile(256, n, 8), ops.smem_rows(k))
     chunk = (autotune.fit_divisor(br, min(8, ops.hbm_max_chunk(k, LANE)))
              if layout == "hbm" else 0)
-    return ops._ell_pallas.lower(
-        *_ell_args(n, k, sharding), block_rows=br, layout=layout,
-        chunk=chunk, interpret=False, lane=LANE, storage="float32",
-        vlimit=ops.vmem_limit()).compile()
+    return dict(block_rows=br, layout=layout, chunk=chunk, interpret=False,
+                lane=LANE, storage="float32", vlimit=ops.vmem_limit())
+
+
+def _compile_ell(n, k, layout, sharding):
+    return ops._ell_pallas.lower(*_ell_args(n, k, sharding),
+                                 **_ell_static(n, k, layout)).compile()
+
+
+def _under(scope, fn, **static):
+    """`fn` jitted inside a caller that runs it under the device scope
+    `scope`, as the program's callers do (docs/observability.md)."""
+    def caller(*args):
+        with jax.named_scope(scope):
+            return fn(*args, **static)
+    return jax.jit(caller)
+
+
+def _kernel_instruction(compiled, name, scope):
+    """The compiled kernel's HLO instruction named `name`, its op
+    metadata under the device scope `scope`."""
+    return re.search(rf"%{name}\.\d+ = [^\n]*custom-call\([^\n]*"
+                     rf'op_name="[^"]*/{scope}/[^"]*"', compiled.as_text())
 
 
 def test_ell_vmem_layout_mnist20k(one_chip):
@@ -108,3 +129,25 @@ def test_bh_interaction_tree_mnist20k(one_chip, width, table_rows):
         interpret=False, lane=LANE, storage="float32",
         vlimit=ops.vmem_limit()).compile()
     assert compiled is not None
+
+
+# a kernel keeps its wrapper's name as its HLO instruction under the
+# callers' scopes: the benchmark's roofline readers match that name in a
+# chip trace, and the scope metrics read the scope
+@pytest.mark.parametrize("layout", ["vmem", "hbm"])
+def test_ell_kernel_keeps_its_name_under_its_scope(one_chip, layout):
+    compiled = _under("laplacian/reverse", ops._ell_pallas,
+                      **_ell_static(2048, 32, layout)).lower(
+        *_ell_args(2048, 32, one_chip)).compile()
+    assert _kernel_instruction(compiled, "_ell_pallas", "laplacian/reverse")
+
+
+def test_pairwise_kernel_keeps_its_name_under_its_scope(one_chip):
+    n = N_COIL
+    compiled = _under(
+        "objective", ops._pairwise_pallas, kind="ee", block_rows=256,
+        block_cols=256, interpret=False, lane=LANE,
+        storage="float32").lower(
+        _sds((n, 2), F32, one_chip), _sds((n, n), F32, one_chip),
+        _sds((n, n), F32, one_chip)).compile()
+    assert _kernel_instruction(compiled, "_pairwise_pallas", "objective")
