@@ -193,6 +193,7 @@ class SparseSD:
     cg_maxiter: int = 100
 
     def init(self, X0, aff, kind: str, lam) -> State:
+        from repro.kernels.ops import ell_live_lengths
         from repro.sparse.graph import NeighborGraph, from_dense, reverse_graph
         from repro.sparse.linalg import sym_degree
 
@@ -226,6 +227,9 @@ class SparseSD:
         return {
             "indices": g.indices, "weights": g.weights,
             "rev_indices": rev.indices, "rev_weights": rev.weights,
+            # the ELL kernels' per-row trip counts, once per system
+            "lengths": (ell_live_lengths(g.weights),
+                        ell_live_lengths(rev.weights)),
             "shift": resid + mu, "inv_diag": 1.0 / (4.0 * dsym + resid + mu),
             "prev_P": jnp.zeros_like(X0),
         }
@@ -239,7 +243,9 @@ class SparseSD:
         shift = state["shift"]
 
         def matvec(V):
-            return 4.0 * sym_lap_matvec(g, V, rev=rev) + shift[:, None] * V
+            return (4.0 * sym_lap_matvec(g, V, rev=rev,
+                                         lengths=state["lengths"])
+                    + shift[:, None] * V)
 
         res = pcg(matvec, -G, state["prev_P"], inv_diag=state["inv_diag"],
                   tol=self.cg_tol, maxiter=self.cg_maxiter)

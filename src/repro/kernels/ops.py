@@ -24,6 +24,10 @@ time, outside any jit) wrapping jitted implementations:
      are held as f32.  The jnp path rounds through bf16 too, so every
      path sees the same quantization.
 
+The ELL kernels cut each row at its live length (`ell_live_lengths`:
+1 + the index of its last nonzero weight), so trailing zero-weight
+padding costs no time; the dispatch record says so (`row_cut`).
+
 Every decision is recorded — never silent:
 
   * an active telemetry recorder gets `path`, `reason`, `layout` and the
@@ -72,7 +76,8 @@ _DEFAULT_VMEM_X_BUDGET = 12 * 1024 * 1024  # bytes the resident-X layout may
 # default scoped limit would not hold a 12 MiB table plus tiles)
 _VMEM_TILE_HEADROOM = 16 * 1024 * 1024
 # SMEM (1 MiB on v5e) holds the double-buffered (rows, k) int32 index and
-# f32 weight tiles of the gather kernels; lanes pad to 128
+# f32 weight tiles of the gather kernels and the ELL kernel's (1, rows)
+# int32 live-length tile; lanes pad to 128
 _SMEM_TILE_BUDGET = 768 * 1024
 # the HBM layout's (2, chunk * k, dp) f32 gather double buffer
 _HBM_BUFFER_BUDGET = 4 * 1024 * 1024
@@ -106,11 +111,22 @@ def vmem_limit() -> int:
     return vmem_x_budget() + _VMEM_TILE_HEADROOM
 
 
+def smem_tile_bytes(rows: int, k: int) -> int:
+    """SMEM the gather kernels' double-buffered row tiles take at ELL
+    width `k`: the (rows, k) index and weight tiles and the (1, rows)
+    live-length tile, each 4-byte word, lanes padded to 128."""
+    return 2 * 4 * (2 * rows * _round_up(max(k, 1), 128)
+                    + _round_up(rows, 128))
+
+
 def smem_rows(k: int) -> int:
-    """Largest row tile (a multiple of 8) whose index and weight tiles,
-    double-buffered, fit the SMEM budget at ELL width `k`."""
-    per_row = 2 * 2 * _round_up(max(k, 1), 128) * 4
-    return max(_ROW_SUB, _SMEM_TILE_BUDGET // per_row // _ROW_SUB * _ROW_SUB)
+    """Largest row tile (a multiple of 8) whose index, weight and length
+    tiles fit the SMEM budget at ELL width `k`."""
+    rows = _SMEM_TILE_BUDGET // (4 * 4 * _round_up(max(k, 1), 128))
+    rows = max(_ROW_SUB, rows // _ROW_SUB * _ROW_SUB)
+    while rows > _ROW_SUB and smem_tile_bytes(rows, k) > _SMEM_TILE_BUDGET:
+        rows -= _ROW_SUB
+    return rows
 
 
 def hbm_max_chunk(k: int, dp: int) -> int:
@@ -222,10 +238,31 @@ def _ell_jnp(X, indices, weights, storage):
     return ell_lap_matvec_ref(X, indices, weights)
 
 
+@jax.jit
+def ell_live_lengths(weights: jnp.ndarray) -> jnp.ndarray:
+    """(N,) int32 live length of each ELL row: 1 + the index of its last
+    nonzero weight, 0 for a row without one.  The slots past it add
+    exactly nothing to the row's Laplacian product, so the Pallas kernels
+    skip them; zero weights before it are still visited.  A weight is
+    zero when its bits are those of +0 or -0: a subnormal weight, which
+    f32 arithmetic may flush to zero, still counts as live."""
+    bits = jax.lax.bitcast_convert_type(weights.astype(jnp.float32),
+                                        jnp.int32)
+    slot = jnp.arange(1, weights.shape[1] + 1, dtype=jnp.int32)
+    return jnp.max(jnp.where(bits & 0x7FFFFFFF != 0, slot, 0), axis=1)
+
+
+def _live_lengths(weights, lengths, rows):
+    """The given or derived live lengths, zero-padded to `rows`."""
+    if lengths is None:
+        lengths = ell_live_lengths(weights)
+    return jnp.pad(lengths.astype(jnp.int32), (0, rows - lengths.shape[0]))
+
+
 @functools.partial(jax.jit, static_argnames=(
     "block_rows", "layout", "chunk", "interpret", "lane", "storage",
     "vlimit"))
-def _ell_pallas(X, indices, weights, *, block_rows, layout, chunk,
+def _ell_pallas(X, indices, weights, lengths, *, block_rows, layout, chunk,
                 interpret, lane, storage, vlimit):
     n, d = X.shape
     n_pad = _round_up(n, block_rows)
@@ -233,13 +270,14 @@ def _ell_pallas(X, indices, weights, *, block_rows, layout, chunk,
     Xp = _pad_to(_rows32(X, storage), n_pad, dp)
     idx_p = jnp.pad(indices.astype(jnp.int32), ((0, n_pad - n), (0, 0)))
     w_p = _pad_to(_rows32(weights, storage), n_pad, weights.shape[1])
+    len_p = _live_lengths(weights, lengths, n_pad)
     if layout == "hbm":
         out = ell_lap_matvec_pallas_hbm(
-            Xp, idx_p, w_p, block_rows=block_rows, chunk=chunk,
+            Xp, idx_p, w_p, len_p, block_rows=block_rows, chunk=chunk,
             interpret=interpret)
     else:
         out = ell_lap_matvec_pallas(
-            Xp, idx_p, w_p, block_rows=block_rows,
+            Xp, idx_p, w_p, len_p, block_rows=block_rows,
             vmem_limit_bytes=vlimit, interpret=interpret)
     return out[:n, :d]
 
@@ -278,10 +316,16 @@ def ell_lap_matvec(
     interpret: bool | None = None,
     lane: int = 128,
     storage_dtype=None,
+    lengths: jnp.ndarray | None = None,
 ) -> jnp.ndarray:
     """Directed ELL Laplacian product L(A) X; see kernels/ref.py for the
     contract and the module docstring for the dispatch ladder.  Leave
-    `block_rows`/`layout`/`chunk` unset to let the autotuner pick them."""
+    `block_rows`/`layout`/`chunk` unset to let the autotuner pick them.
+
+    The Pallas path cuts each row at its live length: `lengths` as
+    `ell_live_lengths(weights)` gives them, handed in by callers that
+    apply one graph many times (sparse/linalg.make_sd_operator), derived
+    here from `weights` when absent.  The jnp path ignores them."""
     impl = _resolve_impl(impl, use_pallas)
     storage = _resolve_storage(storage_dtype)
     n, d = X.shape
@@ -311,7 +355,7 @@ def ell_lap_matvec(
             return _candidate(
                 _ell_pallas, np.ones((bucket_n, d), np.float32),
                 np.zeros((bucket_n, k), np.int32),
-                np.ones((bucket_n, k), np.float32),
+                np.ones((bucket_n, k), np.float32), None,
                 block_rows=cfg.block_rows, layout=cfg.layout,
                 chunk=cfg.chunk, interpret=interp, lane=lane,
                 storage=storage, vlimit=vlimit)
@@ -328,10 +372,11 @@ def ell_lap_matvec(
             "storage": storage, "block_rows": br,
             "chunk": ch if lay == "hbm" else 0, "interpret": interp,
             "autotuned": autotuned, "cache_hit": cache_hit,
-            "failed": failed}
+            "failed": failed, "row_cut": "live-length",
+            "lengths": "derived" if lengths is None else "given"}
     _record("ell_lap_matvec", info)
-    return _ell_pallas(X, indices, weights, block_rows=br, layout=lay,
-                       chunk=ch, interpret=interp, lane=lane,
+    return _ell_pallas(X, indices, weights, lengths, block_rows=br,
+                       layout=lay, chunk=ch, interpret=interp, lane=lane,
                        storage=storage, vlimit=vlimit)
 
 
@@ -577,7 +622,7 @@ def resolve_local_ell(nb: int, k: int, d: int, *, n_rep: int,
         return _candidate(
             fn, np.ones((rows, max(lane, d)), np.float32),
             np.zeros((rows, k), np.int32), np.ones((rows, k), np.float32),
-            np.int32(0))
+            np.full(rows, k, np.int32), np.int32(0))
 
     key = dict(n=nb, k=k, d=d, dtype=storage, interpret=interpret)
     cfg, cache_hit = autotune.get_config(
@@ -589,22 +634,26 @@ def resolve_local_ell(nb: int, k: int, d: int, *, n_rep: int,
             "block_rows": br, "interpret": interpret, "autotuned": True,
             "cache_hit": cache_hit,
             "failed": autotune.failures(autotune.cache_key("ell_local",
-                                                           **key))}
+                                                           **key)),
+            "row_cut": "live-length"}
     _record("ell_lap_matvec_local", info)
     return {"block_rows": br, "interpret": interpret, "storage": storage}
 
 
 def ell_lap_matvec_local(X_rep, indices, weights, row0, *, block_rows,
-                         interpret, storage, lane: int = 128):
+                         interpret, storage, lane: int = 128, lengths=None):
     """Local rows of L(A) X inside a shard_map body, via the
     scalar-prefetch translated kernel.  Static kwargs come from
     `resolve_local_ell` (called at build time); this function is safe to
-    trace inside shard_map (no dispatch, no autotune)."""
+    trace inside shard_map (no dispatch, no autotune).  Rows are cut at
+    their live lengths, given or derived from `weights` as in
+    `ell_lap_matvec`."""
     d = X_rep.shape[1]
     dp = max(lane, d)
     Xk = jnp.pad(_rows32(X_rep, storage), ((0, 0), (0, dp - d)))
     out = ell_lap_matvec_local_pallas(
-        Xk, indices.astype(jnp.int32), _rows32(weights, storage), row0,
+        Xk, indices.astype(jnp.int32), _rows32(weights, storage),
+        _live_lengths(weights, lengths, weights.shape[0]), row0,
         block_rows=block_rows, interpret=interpret,
         vmem_limit_bytes=vmem_limit())
     return out[:, :d]

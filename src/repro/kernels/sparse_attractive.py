@@ -39,6 +39,16 @@ row sum runs in f32 with a scalar weight per read:
   * embedding dim d is pre-padded to the lane width by ops.py; N is
     pre-padded to the tile size with zero-weight rows, which contribute
     exactly zero (the ELL padding invariant).
+
+Each row visits only its live slots: `lengths[r]` is 1 + the index of
+row r's last nonzero weight (ops.ell_live_lengths), and the whole groups
+of eight slots past it are skipped (the last group visited may hold up
+to seven trailing zero-weight slots, and the k % 8 tail slots are always
+visited; a row of full width runs the full-width loop).  A skipped slot would add exactly `0 * x` to the row
+sum and 0 to the degree, so for finite X the output is bit for bit that
+of the full-width loop.  Zero weights inside the live range are still
+visited.  The lengths ride in as a (rows // block_rows, 1, block_rows)
+int32 SMEM tile beside the index and weight tiles.
 """
 from __future__ import annotations
 
@@ -54,9 +64,12 @@ from jax.experimental.pallas import tpu as pltpu
 _UNROLL = 8
 
 
-def _lap_rows(load_row, w_ref, x_row_ref, out_ref, r0, nrows):
+def _lap_rows(load_row, len_ref, w_ref, x_row_ref, out_ref, r0, nrows):
     """out[r] = (sum_j w[r, j]) x[r] - sum_j w[r, j] load_row(r, j) for
-    the tile rows r0 .. r0 + nrows; `w_ref` is an SMEM (TR, k) tile."""
+    the tile rows r0 .. r0 + nrows; `w_ref` is an SMEM (TR, k) tile and
+    `len_ref` the SMEM (1, TR) tile of the rows' live lengths: row r
+    visits its slots j < len_ref[0, r], rounded up to a whole group of
+    _UNROLL, and the k % _UNROLL tail slots only."""
     k = w_ref.shape[1]
     dp = out_ref.shape[-1]
 
@@ -73,8 +86,14 @@ def _lap_rows(load_row, w_ref, x_row_ref, out_ref, r0, nrows):
                 acc_deg = nbr(g * _UNROLL + u, acc_deg)
             return acc_deg
 
+        # whole groups up to the live length, at most k // _UNROLL of
+        # them, then the k % _UNROLL tail slots as before: a short row
+        # also reads up to _UNROLL - 1 + k % _UNROLL trailing zero-weight
+        # slots, each adding exactly 0, but never a slot past k
+        live = len_ref[0, r]
+        groups = jnp.minimum((live + _UNROLL - 1) // _UNROLL, k // _UNROLL)
         acc_deg = (jnp.zeros((1, dp), jnp.float32), jnp.float32(0.0))
-        acc_deg = jax.lax.fori_loop(0, k // _UNROLL, nbr_group, acc_deg)
+        acc_deg = jax.lax.fori_loop(0, groups, nbr_group, acc_deg)
         for j in range(k - k % _UNROLL, k):
             acc_deg = nbr(j, acc_deg)
         acc, deg = acc_deg
@@ -85,9 +104,9 @@ def _lap_rows(load_row, w_ref, x_row_ref, out_ref, r0, nrows):
     jax.lax.fori_loop(r0, r0 + nrows, row, 0)
 
 
-def _ell_kernel(idx_ref, w_ref, x_row_ref, x_all_ref, out_ref):
+def _ell_kernel(len_ref, idx_ref, w_ref, x_row_ref, x_all_ref, out_ref):
     _lap_rows(lambda r, j: x_all_ref[pl.ds(idx_ref[r, j], 1), :],
-              w_ref, x_row_ref, out_ref, 0, idx_ref.shape[0])
+              len_ref, w_ref, x_row_ref, out_ref, 0, idx_ref.shape[0])
 
 
 def _smem_tile(block_rows, k, index_map):
@@ -95,10 +114,23 @@ def _smem_tile(block_rows, k, index_map):
                         memory_space=pltpu.SMEM)
 
 
+def _len_tile(block_rows, index_map):
+    """The SMEM (1, block_rows) tile of the live lengths: a 3-D array
+    whose last two dims are the whole block, so the tile takes one
+    128-lane padded row of SMEM, not one per table row."""
+    return pl.BlockSpec((None, 1, block_rows), index_map,
+                        memory_space=pltpu.SMEM)
+
+
+def _len_blocks(lengths, block_rows):
+    return lengths.astype(jnp.int32).reshape(-1, 1, block_rows)
+
+
 def ell_lap_matvec_pallas(
     X: jnp.ndarray,          # (N, dp) — dp lane-padded by ops.py
     indices: jnp.ndarray,    # (N, k) int32
     weights: jnp.ndarray,    # (N, k) f32
+    lengths: jnp.ndarray,    # (N,) int32 live lengths, each in [0, k]
     *,
     block_rows: int = 256,
     vmem_limit_bytes: int | None = None,
@@ -116,6 +148,7 @@ def ell_lap_matvec_pallas(
         _ell_kernel,
         grid=(n // block_rows,),
         in_specs=[
+            _len_tile(block_rows, lambda i: (i, 0, 0)),
             _smem_tile(block_rows, k, lambda i: (i, 0)),
             _smem_tile(block_rows, k, lambda i: (i, 0)),
             pl.BlockSpec((block_rows, dp), lambda i: (i, 0)),
@@ -126,15 +159,17 @@ def ell_lap_matvec_pallas(
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=vmem_limit_bytes),
         interpret=interpret,
-    )(indices, weights, X, X)
+    )(_len_blocks(lengths, block_rows), indices, weights, X, X)
 
 
-def _ell_hbm_kernel(idx_ref, w_ref, x_row_ref, x_hbm_ref, out_ref, *,
-                    chunk: int):
+def _ell_hbm_kernel(len_ref, idx_ref, w_ref, x_row_ref, x_hbm_ref, out_ref,
+                    *, chunk: int):
     """Double-buffered HBM gather: while chunk c's neighbor rows are being
     reduced, chunk c+1's rows are already in flight into the other buffer
     slot.  One DMA semaphore per slot: every row copy of a chunk signals
-    it, and the wait loop consumes one row's worth per copy."""
+    it, and the wait loop consumes one row's worth per copy.  The copies
+    cover whole rows, dead slots included; only the reduction stops at
+    each row's live length."""
     tr, k = idx_ref.shape
     n_chunks = tr // chunk
     per_chunk = chunk * k
@@ -174,7 +209,7 @@ def _ell_hbm_kernel(idx_ref, w_ref, x_row_ref, x_hbm_ref, out_ref, *,
             r0 = c * chunk
             _lap_rows(
                 lambda r, j: buf[slot, pl.ds((r - r0) * k + j, 1), :],
-                w_ref, x_row_ref, out_ref, r0, chunk)
+                len_ref, w_ref, x_row_ref, out_ref, r0, chunk)
             return carry
 
         jax.lax.fori_loop(0, n_chunks, step, 0)
@@ -191,6 +226,7 @@ def ell_lap_matvec_pallas_hbm(
     X: jnp.ndarray,          # (N, dp) — stays in HBM
     indices: jnp.ndarray,    # (N, k) int32
     weights: jnp.ndarray,    # (N, k) f32
+    lengths: jnp.ndarray,    # (N,) int32 live lengths, each in [0, k]
     *,
     block_rows: int = 256,
     chunk: int = 8,
@@ -209,6 +245,7 @@ def ell_lap_matvec_pallas_hbm(
         functools.partial(_ell_hbm_kernel, chunk=chunk),
         grid=(n // block_rows,),
         in_specs=[
+            _len_tile(block_rows, lambda i: (i, 0, 0)),
             _smem_tile(block_rows, k, lambda i: (i, 0)),
             _smem_tile(block_rows, k, lambda i: (i, 0)),
             pl.BlockSpec((block_rows, dp), lambda i: (i, 0)),
@@ -217,18 +254,20 @@ def ell_lap_matvec_pallas_hbm(
         out_specs=pl.BlockSpec((block_rows, dp), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, dp), jnp.float32),
         interpret=interpret,
-    )(indices, weights, X, X)
+    )(_len_blocks(lengths, block_rows), indices, weights, X, X)
 
 
-def _ell_local_kernel(s_ref, idx_ref, w_ref, x_row_ref, x_all_ref, out_ref):
+def _ell_local_kernel(s_ref, len_ref, idx_ref, w_ref, x_row_ref, x_all_ref,
+                      out_ref):
     del s_ref  # consumed by the x_row index map only
-    _ell_kernel(idx_ref, w_ref, x_row_ref, x_all_ref, out_ref)
+    _ell_kernel(len_ref, idx_ref, w_ref, x_row_ref, x_all_ref, out_ref)
 
 
 def ell_lap_matvec_local_pallas(
     X_rep: jnp.ndarray,      # (n_rep, dp) — REPLICATED, lane-padded
     indices: jnp.ndarray,    # (nb, k) int32 — LOCAL graph rows, global ids
     weights: jnp.ndarray,    # (nb, k) f32
+    lengths: jnp.ndarray,    # (nb,) int32 live lengths, each in [0, k]
     row0,                    # global row offset of this shard (traced OK)
     *,
     block_rows: int = 256,
@@ -252,6 +291,7 @@ def ell_lap_matvec_local_pallas(
         num_scalar_prefetch=1,
         grid=(nb // block_rows,),
         in_specs=[
+            _len_tile(block_rows, lambda i, s: (i, 0, 0)),
             _smem_tile(block_rows, k, lambda i, s: (i, 0)),
             _smem_tile(block_rows, k, lambda i, s: (i, 0)),
             pl.BlockSpec((block_rows, dp), lambda i, s: (s[0] + i, 0)),
@@ -267,4 +307,5 @@ def ell_lap_matvec_local_pallas(
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=vmem_limit_bytes),
         interpret=interpret,
-    )(block0, indices, weights, X_rep, X_rep)
+    )(block0, _len_blocks(lengths, block_rows), indices, weights, X_rep,
+      X_rep)

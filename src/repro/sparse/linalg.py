@@ -63,7 +63,9 @@ def ell_t_matvec(g: NeighborGraph, X: Array) -> Array:
 
 
 def sym_lap_matvec(g: NeighborGraph, X: Array,
-                   rev: NeighborGraph | None = None, **impl) -> Array:
+                   rev: NeighborGraph | None = None,
+                   lengths: tuple[Array, Array] | None = None,
+                   **impl) -> Array:
     """L((A + A^T)/2) @ X in O(N k d), as (L(A)X + L(A^T)X) / 2.
 
     When `rev` (the precomputed transpose ELL, graph.reverse_graph) is
@@ -73,14 +75,19 @@ def sym_lap_matvec(g: NeighborGraph, X: Array,
     scatter-add is orders of magnitude slower than the gather.  Without
     `rev` the transpose half falls back to scatter-add — fine for graphs
     that change every iteration (sampled negatives) where building the
-    transpose would itself cost a scatter.  The two halves run under the
+    transpose would itself cost a scatter.  `lengths`, the live lengths
+    of `g` and `rev` (kernels.ops.ell_live_lengths), spare the kernels
+    deriving them on every product.  The two halves run under the
     device scopes `laplacian/forward` and `laplacian/reverse`
     (docs/observability.md)."""
+    g_len, rev_len = lengths if lengths is not None else (None, None)
     with jax.named_scope("laplacian/forward"):
-        la_x = ops.ell_lap_matvec(X, g.indices, g.weights, **impl)
+        la_x = ops.ell_lap_matvec(X, g.indices, g.weights, lengths=g_len,
+                                  **impl)
     if rev is not None:
         with jax.named_scope("laplacian/reverse"):
-            lat_x = ops.ell_lap_matvec(X, rev.indices, rev.weights, **impl)
+            lat_x = ops.ell_lap_matvec(X, rev.indices, rev.weights,
+                                       lengths=rev_len, **impl)
     else:
         lat_x = in_degree(g)[:, None] * X - ell_t_matvec(g, X)
     return 0.5 * (la_x + lat_x)
@@ -94,13 +101,19 @@ def make_sd_operator(g: NeighborGraph, rev: NeighborGraph | None,
     core.strategies.SparseSD generalizes this with the full-degree
     residual shift for dense-kappa conversions.  `impl` kwargs (e.g.
     ``impl="pallas"``, ``storage_dtype="bfloat16"``) are forwarded to the
-    kernel dispatcher for every matvec — this is the CG hot path."""
+    kernel dispatcher for every matvec — this is the CG hot path.  The
+    rows' live lengths are computed here once, like the degrees, so the
+    kernels skip each row's trailing zero-weight slots without a
+    reduction per product."""
     bd = 4.0 * sym_degree(g)
     mu = jnp.maximum(1e-10 * jnp.min(bd), mu_scale * jnp.mean(bd))
     inv_diag = 1.0 / (bd + mu)
+    lengths = (ops.ell_live_lengths(g.weights),
+               None if rev is None else ops.ell_live_lengths(rev.weights))
 
     def matvec(V):
-        return 4.0 * sym_lap_matvec(g, V, rev=rev, **impl) + mu * V
+        return 4.0 * sym_lap_matvec(g, V, rev=rev, lengths=lengths,
+                                    **impl) + mu * V
 
     return matvec, inv_diag, mu
 

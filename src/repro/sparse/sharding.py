@@ -151,11 +151,11 @@ def _local_lap_fn(nb: int, k: int, n_rep: int, kernel_impl: str,
                                storage_dtype=kernel_precision, n_rep=n_rep,
                                lane=kernel_lane)
     if kw is None:
-        return (lambda xi, Xp, idx, w, row0:
+        return (lambda xi, Xp, idx, w, row0, lengths=None:
                 _directed_lap_local(xi, Xp, idx, w)), False
 
-    def lap(xi, Xp, idx, w, row0):
-        return ops.ell_lap_matvec_local(Xp, idx, w, row0,
+    def lap(xi, Xp, idx, w, row0, lengths=None):
+        return ops.ell_lap_matvec_local(Xp, idx, w, row0, lengths=lengths,
                                         lane=kernel_lane, **kw)
 
     return lap, True
@@ -348,35 +348,38 @@ def make_sharded_sd_operator(mesh: Mesh, row_axes: tuple[str, ...],
     for both halves, one O(N d) psum to re-replicate.  This is the CG
     hot path — `kernel_impl`/`kernel_precision` put both halves on the
     local-rows Pallas kernel (dispatch resolved at build time, see
-    `make_sharded_energy_grad`)."""
+    `make_sharded_energy_grad`).  The rows' live lengths, which the
+    kernel cuts each row at, are computed once here."""
     _, inv_diag, mu = make_sd_operator(saff.graph, saff.rev, mu_scale)
     n, n_pad = sg.n, sg.n_pad
     nb_shard = n_pad // _row_groups(mesh, row_axes)
     lap_local, kernel_active = _local_lap_fn(
         nb_shard, sg.indices.shape[1], n_pad, kernel_impl, kernel_precision,
         kernel_lane)
+    lengths = (ops.ell_live_lengths(sg.weights),
+               ops.ell_live_lengths(sg.rev_weights))
 
     @jax.named_scope("sharded-sd-matvec")
-    def body(Vp, idx, w, ridx, rw):
+    def body(Vp, idx, w, ridx, rw, w_len, rw_len):
         nb = idx.shape[0]
         row0 = linear_row_index(row_axes) * nb
         vi = jax.lax.dynamic_slice_in_dim(Vp, row0, nb, 0)
         # 4 * 0.5 * (L(A) V + L(A^T) V)
-        out_loc = 2.0 * (lap_local(vi, Vp, idx, w, row0)
-                         + lap_local(vi, Vp, ridx, rw, row0))
+        out_loc = 2.0 * (lap_local(vi, Vp, idx, w, row0, w_len)
+                         + lap_local(vi, Vp, ridx, rw, row0, rw_len))
         out = jnp.zeros_like(Vp)
         out = jax.lax.dynamic_update_slice_in_dim(out, out_loc, row0, 0)
         return jax.lax.psum(out, row_axes)
 
     smap = (shard_map_norep if kernel_active else shard_map)(
         body, mesh=mesh,
-        in_specs=(P(),) + (P(row_axes, None),) * 4,
+        in_specs=(P(),) + (P(row_axes, None),) * 4 + (P(row_axes),) * 2,
         out_specs=P(),
     )
 
     def matvec(V):
         Vp = jnp.pad(V, ((0, n_pad - n), (0, 0)))
-        return (smap(Vp, sg.indices, sg.weights,
-                     sg.rev_indices, sg.rev_weights)[:n] + mu * V)
+        return (smap(Vp, sg.indices, sg.weights, sg.rev_indices,
+                     sg.rev_weights, *lengths)[:n] + mu * V)
 
     return matvec, inv_diag, mu

@@ -43,9 +43,13 @@ def _sds(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _ell_args(n, k, sharding):
+def _ell_args(n, k, sharding, lengths=False):
+    """X, indices, weights and the live lengths: given as an (n,) int32
+    array, as make_sd_operator hands them in, or None, so the kernel's
+    wrapper derives them from the weights."""
     return (_sds((n, 2), F32, sharding), _sds((n, k), I32, sharding),
-            _sds((n, k), F32, sharding))
+            _sds((n, k), F32, sharding),
+            _sds((n,), I32, sharding) if lengths else None)
 
 
 def _ell_static(n, k, layout):
@@ -56,8 +60,8 @@ def _ell_static(n, k, layout):
                 lane=LANE, storage="float32", vlimit=ops.vmem_limit())
 
 
-def _compile_ell(n, k, layout, sharding):
-    return ops._ell_pallas.lower(*_ell_args(n, k, sharding),
+def _compile_ell(n, k, layout, sharding, lengths=False):
+    return ops._ell_pallas.lower(*_ell_args(n, k, sharding, lengths),
                                  **_ell_static(n, k, layout)).compile()
 
 
@@ -86,6 +90,16 @@ def test_ell_vmem_layout_mnist20k(one_chip):
 
 
 @pytest.mark.parametrize("k", [K_MNIST, 675])
+def test_ell_vmem_layout_mnist20k_given_lengths(one_chip, k):
+    # the SD operator's products: ragged live lengths handed in, each row
+    # looping to its own length; k = 675 is the reverse graph's width,
+    # whose row tile the length tile shrinks to fit SMEM
+    assert ops.smem_tile_bytes(ops.smem_rows(k), k) <= ops._SMEM_TILE_BUDGET
+    assert _compile_ell(N_MNIST, k, "vmem", one_chip,
+                        lengths=True) is not None
+
+
+@pytest.mark.parametrize("k", [K_MNIST, 675])
 def test_ell_hbm_layout_mnist20k(one_chip, k):
     # k = 675: the reverse graph's width (its maximum in-degree) on the
     # seed-0 mnist_like data at perplexity 50 — the SMEM tiles and the DMA
@@ -96,11 +110,12 @@ def test_ell_hbm_layout_mnist20k(one_chip, k):
 def test_ell_local_rows_four_way_shard(one_chip):
     nb = -(-N_MNIST // 4)                 # one shard of a (4, 1) mesh
     br = autotune.fit_divisor(nb, ops.smem_rows(K_MNIST), 8)
-    fn = jax.jit(lambda X, i, w, r0: ell_lap_matvec_local_pallas(
-        X, i, w, r0, block_rows=br, vmem_limit_bytes=ops.vmem_limit()))
+    fn = jax.jit(lambda X, i, w, ln, r0: ell_lap_matvec_local_pallas(
+        X, i, w, ln, r0, block_rows=br, vmem_limit_bytes=ops.vmem_limit()))
     compiled = fn.lower(_sds((4 * nb, LANE), F32, one_chip),
                         _sds((nb, K_MNIST), I32, one_chip),
                         _sds((nb, K_MNIST), F32, one_chip),
+                        _sds((nb,), I32, one_chip),
                         _sds((), I32, one_chip)).compile()
     assert compiled is not None
 
