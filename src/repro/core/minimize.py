@@ -70,7 +70,13 @@ def _step(strategy, kind, ls_cfg: LSConfig, X, E, G, state,
     X_new = X + ls.alpha * P
     with jax.named_scope("objective"):
         E_new, G_new = energy_and_grad(X_new, aff, kind, lam, **impl)
-    return X_new, E_new, G_new, state, ls.alpha, ls.n_evals + 1
+    n_evals = ls.n_evals + 1
+    # everything the engine's host loop reads, packed so that it costs one
+    # device->host transfer: [E, |G|, alpha, n_evals] (n_evals is exact)
+    scalars = jnp.stack([E_new, jnp.linalg.norm(G_new),
+                         ls.alpha.astype(E_new.dtype),
+                         n_evals.astype(E_new.dtype)])
+    return X_new, E_new, G_new, state, ls.alpha, n_evals, scalars
 
 
 @dataclasses.dataclass

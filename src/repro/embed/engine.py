@@ -35,11 +35,14 @@ and may additionally provide
                             called when someone is listening (telemetry,
                             on_iteration, or a diagnostics-aware callback)
     make_fused_step()       a single jitted (X, E, G, state, alpha) ->
-                            (X, E, G, state, alpha, n_evals) program that
-                            replaces the whole direction/line-search/update
-                            sequence — this is how `core/minimize.py` keeps
-                            its one-XLA-program-per-iteration timing (and
-                            its bit-identical results) through the refactor
+                            (X, E, G, state, alpha, n_evals, scalars)
+                            program that replaces the whole direction/
+                            line-search/update sequence; `scalars` packs
+                            [E, |G|, alpha, n_evals] in E's dtype, so the
+                            host reads an iteration in one transfer — this
+                            is how `core/minimize.py` keeps its
+                            one-XLA-program-per-iteration timing (and its
+                            bit-identical iterates) through the refactor
     place(X)                device placement for X-like arrays (e.g.
                             replicate on a mesh); used on checkpoint restore
     carry_state()           objective-side state to checkpoint (a pytree,
@@ -110,6 +113,8 @@ class EngineResult:
     setup_time: float         # direction-solver init (e.g. Cholesky)
     resumed_from: int | None
     state: Any = None         # final direction-solver state
+    loop_transfers: int = 0   # arrays the loop copied device->host: one
+                              # per iteration on the fused path
     diagnostics: list[dict] | None = None   # per-iteration table (only
                                             # collected when someone asked:
                                             # telemetry / on_iteration /
@@ -199,7 +204,9 @@ def fit_loop(
     tracer: `setup`, `compile`, per iteration `solve-iter` (inside it
     `step` + `fetch` on the fused path, `direction` + `line-search` +
     `grad` on the host path) then `iter-host` (records, callbacks,
-    convergence test), and `checkpoint` (docs/observability.md).
+    convergence test), and `checkpoint` (docs/observability.md).  The
+    recorder's meta gets `loop_transfers`, the count of arrays the loop
+    copied device->host (`EngineResult.loop_transfers`).
     """
     cb_wants_diag = (callback is not None
                      and _callback_wants_diagnostics(callback))
@@ -339,22 +346,25 @@ def _fit_loop(objective, X0, cfg, callback, cb_wants_diag, on_iteration,
 
     converged = False
     diags: list[dict] = []
+    transfers = 0
+    # the host path's own transfers besides its gradient fetches:
+    # initial_step's two scalars and host_backtrack's <G, P>
+    ls_transfers = 1 + (2 if cfg.ls.max_rel_move is not None else 0)
     t_loop = time.perf_counter()
     it = start_it
     for it in range(start_it + 1, cfg.max_iters + 1):
         with span("solve-iter", it=it):
             if fused_step is not None:
                 with span("step"):
-                    X, E_new, G, state, alpha_dev, ne = \
-                        jax.block_until_ready(
-                            fused_step(X, E, G, state, alpha_dev))
-                # one batched transfer for all per-iteration scalars
-                # (RPR001): energy, |G|, accepted step, n_evals
+                    X, E_new, G, state, alpha_dev, _, scalars = \
+                        fused_step(X, E, G, state, alpha_dev)
+                # the iteration's one wait and one transfer (RPR001):
+                # energy, |G|, accepted step, n_evals
                 with span("fetch"):
-                    vals = jax.device_get(
-                        (E_new, jnp.linalg.norm(G), alpha_dev, ne))
-                e_rec, g_host, alpha_host = (float(v) for v in vals[:3])
-                n_ev = int(vals[3])
+                    e_rec, g_host, alpha_host, n_ev = \
+                        jax.device_get(scalars).tolist()
+                n_ev = int(n_ev)
+                transfers += 1
             else:
                 n_ev = 0
                 if stochastic:
@@ -369,6 +379,7 @@ def _fit_loop(objective, X0, cfg, callback, cb_wants_diag, on_iteration,
                             float(v) for v in
                             jax.device_get((E, jnp.linalg.norm(G))))
                     n_ev += 1
+                    transfers += 2
                 else:
                     # deterministic: E is unchanged since its transfer
                     # last iteration (or pre-loop) — reuse the host copy
@@ -382,6 +393,7 @@ def _fit_loop(objective, X0, cfg, callback, cb_wants_diag, on_iteration,
                         lambda Xn: float(objective.energy(Xn, key)),
                         X, e_host, G, P, alpha0, cfg.ls)
                 n_ev += n_bt
+                transfers += ls_transfers + n_bt
                 X = X + alpha_host * P
                 if stochastic:
                     e_rec = e_new  # this iteration's surrogate, accepted X
@@ -392,6 +404,7 @@ def _fit_loop(objective, X0, cfg, callback, cb_wants_diag, on_iteration,
                             float(v) for v in
                             jax.device_get((E, jnp.linalg.norm(G))))
                     n_ev += 1
+                    transfers += 2
         with span("iter-host", it=it):
             now = time.perf_counter() - t_loop
             energies.append(e_rec)
@@ -436,6 +449,8 @@ def _fit_loop(objective, X0, cfg, callback, cb_wants_diag, on_iteration,
         if cfg.max_seconds is not None and now > cfg.max_seconds:
             break
     save(it)
+    if recorder is not None:
+        recorder.set_meta(loop_transfers=transfers)
 
     return EngineResult(
         X=X,
@@ -450,4 +465,5 @@ def _fit_loop(objective, X0, cfg, callback, cb_wants_diag, on_iteration,
         resumed_from=resumed_from,
         state=state,
         diagnostics=diags if want_diag else None,
+        loop_transfers=transfers,
     )

@@ -297,11 +297,12 @@ def test_engine_hot_step_makes_no_implicit_transfers(data):
     alpha = jnp.ones((), X0.dtype)
     out = jax.block_until_ready(step(X0, E, G, state, alpha))  # warm
     with no_implicit_transfers():
-        X, E2, G2, state2, alpha2, ne = jax.block_until_ready(
-            step(*out[:4], out[4]))
-        # the sanctioned extraction: one explicit transfer, then host math
-        e_host, a_host = (float(v) for v in jax.device_get((E2, alpha2)))
-    assert np.isfinite(e_host) and a_host > 0.0
+        X, E2, G2, state2, alpha2, ne, scalars = step(*out[:4], out[4])
+        # the sanctioned extraction: one explicit transfer of the packed
+        # [E, |G|, alpha, n_evals], then host math
+        e_host, g_host, a_host, n_ev = jax.device_get(scalars).tolist()
+    assert np.isfinite(e_host) and g_host > 0.0 and a_host > 0.0
+    assert n_ev == int(ne) >= 2
 
 
 def test_transfer_guard_catches_implicit_h2d():
