@@ -1,10 +1,8 @@
 """Shared benchmark machinery: datasets, the method lineup, timing helpers.
 
-Methods run through the public `repro.api.Embedding` estimator (the dense
-backend is bit-identical to the legacy `core.minimize` driver, so
-benchmark trajectories are unchanged by the port).  `method_by_name`
-still hands out raw strategy objects for drivers that need them
-(fig3's homotopy path).
+Methods run through the public `repro.api.Embedding` estimator.
+`method_by_name` hands out raw strategy objects for drivers that need
+them (fig3's homotopy path, `repro.embed.homotopy_path`).
 
 Scale note: the container is a single CPU core; Ns default to reduced
 versions of the paper's datasets (COIL-20: N=720 exact; MNIST: N=2000 vs

@@ -6,7 +6,8 @@ import argparse
 import json
 
 
-from repro.core import homotopy_path, LSConfig
+from repro.core import LSConfig
+from repro.embed import homotopy_path
 
 from .common import coil_problem, csv_row, method_by_name
 

@@ -9,8 +9,8 @@ import argparse
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import FP, SD, LSConfig, homotopy_path, laplacian_eigenmaps, \
-    make_affinities
+from repro.core import FP, SD, LSConfig, laplacian_eigenmaps, make_affinities
+from repro.embed import homotopy_path
 from repro.data import coil_like
 
 

@@ -15,10 +15,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.api import Embedding, EmbedSpec
 from repro.configs import RunConfig, get_smoke_config
 from repro.configs.base import ShapeConfig
-from repro.core import (SD, LSConfig, laplacian_eigenmaps, make_affinities,
-                        minimize)
+from repro.core import LSConfig, laplacian_eigenmaps, make_affinities
 from repro.data import batch_for
 from repro.models import build_model, init_train_state, make_train_step
 from repro.optim.adamw import AdamWConfig
@@ -51,9 +51,11 @@ def main():
 
     aff = make_affinities(Y, perplexity=25.0, model=a.kind)
     X0 = laplacian_eigenmaps(aff.Wp, 2) * 0.1
-    res = minimize(X0, aff, a.kind, 1.0 if a.kind in ("ssne", "tsne")
-                   else 100.0, SD(), max_iters=150, tol=1e-8,
-                   ls_cfg=LSConfig(init_step="adaptive_grow"))
+    spec = EmbedSpec(kind=a.kind, strategy="sd", backend="dense",
+                     lam=1.0 if a.kind in ("ssne", "tsne") else 100.0,
+                     max_iters=150, tol=1e-8,
+                     ls=LSConfig(init_step="adaptive_grow"))
+    res = Embedding(spec).fit(None, X0=X0, aff=aff).result_
     print(f"{a.kind}+SD: E {res.energies[0]:.4f} -> {res.energies[-1]:.4f} "
           f"in {res.n_iters} iters")
     os.makedirs("results", exist_ok=True)

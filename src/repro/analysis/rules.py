@@ -30,8 +30,8 @@ is about HOW this repo uses jax, not about Python:
           (kernels upcast AFTER the gather; accumulating in bf16 loses
           the mixed-precision parity the kernel gate pins at 1e-5).
   RPR006  `DeprecationWarning` without `stacklevel=2` — the warning
-          must point at CALLER code or the shim migration story
-          (minimize/EmbedConfig/DistributedEmbedding) is undebuggable.
+          must point at CALLER code, or a user cannot find which of
+          their call sites to migrate.
   RPR007  `span(...)` not used as a context manager — a bare call
           creates the span object and drops it: nothing is timed, and
           the trace silently loses the phase.

@@ -2,10 +2,8 @@
 
 Each backend is a function `fit(spec, Y, *, X0, aff, saff, mesh,
 mesh_spec, callback, telemetry) -> EngineResult` composing an `Objective`
-(core/minimize.py or embed/trainer.py builders) with the unified engine
-(`embed.engine.fit_loop`).  The dense backend is the exact glue
-`core.minimize.minimize` has always run — `repro.api` trajectories are
-bit-identical to the legacy driver (pinned in tests/test_api.py).
+(core/minimize.py `DenseObjective` or the embed/trainer.py builders) with
+the unified engine (`embed.engine.fit_loop`).
 
 Precomputed inputs: `aff=` (dense `core.Affinities`) is dense-backend-
 only; `saff=` (sparse `SparseAffinities`) is the neighbor-graph analogue

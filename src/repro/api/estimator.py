@@ -27,7 +27,6 @@ the resumed trajectory is the uninterrupted one, bit-for-bit.
 from __future__ import annotations
 
 import dataclasses
-import warnings
 from typing import Callable
 
 import jax
@@ -38,7 +37,7 @@ from repro.obs import resolve_telemetry
 
 from . import registries
 from .spec import EmbedSpec, TransformSpec
-from .transform import UNSET, transform_points
+from .transform import transform_points
 
 Array = jnp.ndarray
 
@@ -162,20 +161,15 @@ class Embedding:
         return self.fit(Y, telemetry=telemetry)
 
     # -- serving ------------------------------------------------------------
-    def transform(self, Y_new: Array, spec: TransformSpec | None = None,
-                  *, max_iters: int | None = None,
-                  n_negatives: int | None = UNSET,
-                  tol: float | None = None) -> Array:
+    def transform(self, Y_new: Array,
+                  spec: TransformSpec | None = None) -> Array:
         """Embed unseen points against the frozen training embedding.
 
         Never re-fits: the training coordinates enter as constants, so
         `embedding_` is bit-identical before and after.  Configuration is
         a frozen `TransformSpec` (`spec=`); its zero/None fields defer to
-        the fitted `EmbedSpec` (docs/serving.md).  The legacy keyword
-        form (`max_iters=`, `n_negatives=`, `tol=`) still works but is
-        deprecated — it builds the spec internally, exactly like the
-        `EmbedConfig` -> `EmbedSpec` migration.  Requires the fit to have
-        seen raw `Y` (not only precomputed affinities)."""
+        the fitted `EmbedSpec` (docs/serving.md).  Requires the fit to
+        have seen raw `Y` (not only precomputed affinities)."""
         if getattr(self, "embedding_", None) is None:
             raise ValueError("transform() requires a fitted estimator")
         if getattr(self, "_Y_train", None) is None:
@@ -187,23 +181,8 @@ class Embedding:
             raise ValueError(
                 "transform() needs the raw training Y; this estimator was "
                 "fit from precomputed affinities only")
-        legacy = (max_iters is not None or n_negatives is not UNSET
-                  or tol is not None)
-        if spec is not None:
-            if legacy:
-                raise ValueError(
-                    "pass either a TransformSpec or the legacy "
-                    "max_iters/n_negatives/tol kwargs, not both")
-        elif legacy:
-            warnings.warn(
-                "Embedding.transform(max_iters=..., n_negatives=..., "
-                "tol=...) is deprecated; pass a repro.api.TransformSpec "
-                "instead (transform(Y, TransformSpec(...)))",
-                DeprecationWarning, stacklevel=2)
         X_new, res = transform_points(
-            self.spec, self._Y_train, self.embedding_, Y_new,
-            tspec=spec, max_iters=max_iters, n_negatives=n_negatives,
-            tol=tol)
+            self.spec, self._Y_train, self.embedding_, Y_new, tspec=spec)
         self.last_transform_result_ = res
         return X_new
 

@@ -55,8 +55,8 @@ class StrategyEntry:
     """One registered search-direction strategy.
 
     `dense_factory(spec, **opts)` builds the `core/strategies` object used
-    by the dense backend (and by the legacy `core.minimize` path — parity
-    between the two is pinned bit-for-bit in tests/test_api.py).  The
+    by the dense backend (the name -> object mapping is pinned bit-for-bit
+    against a direct `fit_loop` in tests/test_api.py).  The
     sparse/mesh realizations live in the backends themselves
     (embed/trainer.py), keyed by the canonical name.
     """
@@ -89,8 +89,8 @@ def available_strategies() -> list[str]:
 
 def canonical_strategy(name: str) -> str:
     """Canonical registry name (resolving aliases), or ValueError listing
-    the valid names — the early-validation error `EmbedSpec`/`EmbedConfig`
-    surface at construction."""
+    the valid names — the early-validation error `EmbedSpec` surfaces at
+    construction."""
     low = name.lower()
     low = _STRATEGY_ALIASES.get(low, low)
     if low not in STRATEGIES:

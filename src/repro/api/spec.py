@@ -1,11 +1,10 @@
 """`EmbedSpec`: the one declarative description of an embedding problem.
 
-Replaces the ad-hoc `EmbedConfig` kwarg pile: every knob of every backend
-lives here, and the three names that select *what runs* — `kind`
-(model family), `strategy` (search direction) and `backend` (storage/
-device path) — are validated against their registries at CONSTRUCTION, so
-a typo fails immediately with the list of valid names instead of deep
-inside a run.
+Every knob of every backend lives here, and the three names that select
+*what runs* — `kind` (model family), `strategy` (search direction) and
+`backend` (storage/device path) — are validated against their registries
+at CONSTRUCTION, so a typo fails immediately with the list of valid names
+instead of deep inside a run.
 
 The spec is frozen: `replace()` (dataclasses semantics) derives variants,
 which is how `Embedding.resume` extends budgets without mutating the
@@ -108,7 +107,7 @@ class EmbedSpec:
 
     def kernel_args(self) -> dict:
         """The `kernels.ops` dispatch kwargs this spec selects — empty at
-        the defaults, so legacy call paths stay byte-identical."""
+        the defaults, where the dispatchers pick for themselves."""
         out: dict = {}
         if self.kernel_impl != "auto":
             out["impl"] = self.kernel_impl
@@ -143,13 +142,12 @@ TRANSFORM_SOLVERS = ("engine", "rowwise")
 class TransformSpec:
     """Declarative out-of-sample transform request, mirroring `EmbedSpec`.
 
-    Replaces the `Embedding.transform(**kwargs)` pile the same way
-    `EmbedSpec` replaced `EmbedConfig`: every serving knob lives here,
-    validated at CONSTRUCTION with the registry-style error messages, and
-    the frozen value doubles as the server's per-request configuration
-    (`repro.serve.EmbeddingServer`).  Zero/None sentinels defer to the
-    fitted `EmbedSpec` (`max_iters=0` -> `transform_iters`, `k_cross=0`
-    -> the training ELL width, `tol=None` -> `spec.tol`).
+    Every serving knob lives here (it is the only way to configure a
+    transform), validated at CONSTRUCTION with the registry-style error
+    messages, and the frozen value doubles as the server's per-request
+    configuration (`repro.serve.EmbeddingServer`).  Zero/None sentinels
+    defer to the fitted `EmbedSpec` (`max_iters=0` -> `transform_iters`,
+    `k_cross=0` -> the training ELL width, `tol=None` -> `spec.tol`).
     """
 
     max_iters: int = 0            # 0 => EmbedSpec.transform_iters

@@ -292,10 +292,6 @@ def _anchor_affinities(Y_new, Y_train, k: int, perplexity: float,
 #: (one sublane tile of f32 rows on a TPU)
 ROW_CHUNK = 8
 
-#: distinguishes "use spec.transform_negatives" (unset) from an explicit
-#: ``n_negatives=None`` (exhaustive, deterministic repulsion)
-UNSET = object()
-
 
 def resolve_transform_spec(spec, tspec):
     """Fill a `TransformSpec`'s deferred (zero/None) fields from the
@@ -325,27 +321,15 @@ def _resolve_k(spec, tspec, n_train: int, perplexity: float) -> int:
 
 
 def transform_points(spec, Y_train: Array, X_train: Array, Y_new: Array,
-                     *, tspec=None, max_iters: int | None = None,
-                     n_negatives: int | None = UNSET,
-                     tol: float | None = None):
+                     *, tspec=None):
     """Embed `Y_new` against the frozen (Y_train, X_train) map.
 
-    Configuration comes from a `TransformSpec` (`tspec`); the legacy
-    `max_iters`/`n_negatives`/`tol` kwargs are still honored when no spec
-    is given (`Embedding.transform` owns their deprecation).  Returns
-    `(X_new, result)` where `result` is an `EngineResult` (engine solver),
-    a `RowwiseResult` (rowwise solver), or None for an empty batch.
-    X_train is only ever READ — the training embedding stays bit-identical
-    through any number of transforms.
+    Configuration comes from a `TransformSpec` (`tspec`; None means
+    `TransformSpec()`).  Returns `(X_new, result)` where `result` is an
+    `EngineResult` (engine solver), a `RowwiseResult` (rowwise solver), or
+    None for an empty batch.  X_train is only ever READ — the training
+    embedding stays bit-identical through any number of transforms.
     """
-    from .spec import TransformSpec
-    if tspec is None:
-        tspec = TransformSpec(
-            max_iters=0 if max_iters is None else int(max_iters),
-            exhaustive=(n_negatives is not UNSET and n_negatives is None),
-            n_negatives=(0 if n_negatives in (UNSET, None)
-                         else int(n_negatives)),
-            tol=tol)
     tspec = resolve_transform_spec(spec, tspec)
 
     Y_train = jnp.asarray(Y_train)

@@ -9,9 +9,7 @@ from .affinities import (
     sq_distances,
 )
 from .baselines import LBFGS, NonlinearCG
-from .homotopy import HomotopyResult, homotopy_path
 from .linesearch import LSConfig
-from .minimize import MinimizeResult, minimize
 from .objectives import (
     NORMALIZED,
     attractive_edge_terms,
@@ -32,8 +30,7 @@ __all__ = [
     "Affinities", "make_affinities", "sne_affinities",
     "sne_affinities_from_d2", "sq_distances",
     "LBFGS", "NonlinearCG",
-    "HomotopyResult", "homotopy_path",
-    "LSConfig", "MinimizeResult", "minimize",
+    "LSConfig",
     "NORMALIZED", "attractive_edge_terms", "attractive_weights",
     "direct_energy", "energy", "energy_and_grad", "energy_and_grad_sparse",
     "grad", "gradient_weights", "is_normalized", "negative_pair_terms",

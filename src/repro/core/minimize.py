@@ -1,43 +1,27 @@
-"""The dense single-device optimizer driver — now a thin wrapper over the
-unified fit engine (embed/engine.py).
+"""The dense single-device objective of the unified fit engine
+(embed/engine.py).
 
-The whole iteration (direction -> backtracking line search -> update) stays
+The whole iteration (direction -> backtracking line search -> update) is
 ONE jitted XLA program per (strategy, kind, line-search config, shapes):
 `DenseObjective.make_fused_step` hands `_step` to the engine, whose Python
 loop only does trace bookkeeping and convergence checks — so wall-clock
-comparisons across strategies remain apples-to-apples (as in the paper's
-figures, which plot E vs runtime and vs iterations), and results are
-bit-identical to the pre-engine driver.
+comparisons across strategies stay apples-to-apples (as in the paper's
+figures, which plot E vs runtime and vs iterations).
 """
 from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Callable
+from typing import Any
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from .affinities import Affinities
 from .linesearch import LSConfig, backtracking
 from .objectives import energy, energy_and_grad
 
 Array = jnp.ndarray
-
-
-@dataclasses.dataclass
-class MinimizeResult:
-    X: Array
-    energies: np.ndarray      # E_k, k = 0..n_iters (includes E_0)
-    grad_norms: np.ndarray
-    step_sizes: np.ndarray
-    times: np.ndarray         # cumulative wall-clock seconds at each iterate
-    n_fevals: np.ndarray      # cumulative energy evaluations
-    n_iters: int
-    converged: bool
-    setup_time: float         # strategy init (e.g. Cholesky factorization)
-    strategy_state: Any = None
 
 
 @functools.partial(
@@ -125,72 +109,3 @@ class DenseObjective:
                          self.aff.Wm, self.lam, impl=self.impl)
 
         return step
-
-
-def minimize(
-    X0: Array,
-    aff: Affinities,
-    kind: str,
-    lam,
-    strategy,
-    max_iters: int = 500,
-    tol: float = 1e-7,
-    ls_cfg: LSConfig = LSConfig(),
-    callback: Callable[[int, Array, float], None] | None = None,
-    max_seconds: float | None = None,
-) -> MinimizeResult:
-    """DEPRECATED: use `repro.api.Embedding` (the dense backend runs this
-    exact glue — trajectories are bit-identical).  Kept as a shim for
-    legacy call sites."""
-    import warnings
-
-    warnings.warn(
-        "core.minimize.minimize is deprecated; use repro.api.Embedding "
-        "with backend='dense' (bit-identical trajectories)",
-        DeprecationWarning, stacklevel=2)
-    return _minimize(X0, aff, kind, lam, strategy, max_iters=max_iters,
-                     tol=tol, ls_cfg=ls_cfg, callback=callback,
-                     max_seconds=max_seconds)
-
-
-def _minimize(
-    X0: Array,
-    aff: Affinities,
-    kind: str,
-    lam,
-    strategy,
-    max_iters: int = 500,
-    tol: float = 1e-7,
-    ls_cfg: LSConfig = LSConfig(),
-    callback: Callable[[int, Array, float], None] | None = None,
-    max_seconds: float | None = None,
-) -> MinimizeResult:
-    """Minimize E(X; lam) with the given search-direction strategy.
-
-    Stops on relative energy decrease < tol, on max_iters, or (for the
-    paper's fixed-budget comparisons) on max_seconds of wall-clock.
-    """
-    # deferred: repro.embed.engine <- repro.embed.__init__ <- trainer <-
-    # repro.core would be circular at module-import time
-    from repro.embed.engine import LoopConfig, fit_loop
-
-    lam = jnp.asarray(lam, dtype=X0.dtype)
-    obj = DenseObjective(aff, kind, lam, strategy, ls_cfg, X0)
-    res = fit_loop(
-        obj, X0,
-        LoopConfig(max_iters=max_iters, tol=tol, ls=ls_cfg,
-                   convergence="raw", max_seconds=max_seconds),
-        callback=callback,
-    )
-    return MinimizeResult(
-        X=res.X,
-        energies=res.energies,
-        grad_norms=res.grad_norms,
-        step_sizes=res.step_sizes,
-        times=res.times,
-        n_fevals=res.n_fevals,
-        n_iters=res.n_iters,
-        converged=res.converged,
-        setup_time=res.setup_time,
-        strategy_state=res.state,
-    )

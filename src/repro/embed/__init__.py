@@ -8,11 +8,11 @@ from .distributed import (
     shard_rows,
 )
 from .engine import EngineResult, LoopConfig, Objective, fit_loop
-from .trainer import DistributedEmbedding, EmbedConfig, FitResult
+from .homotopy import HomotopyResult, homotopy_path
 
 __all__ = [
     "EmbedMeshSpec", "make_block_jacobi_setup", "make_block_jacobi_solve",
     "make_distributed_energy_grad", "replicate", "shard_pairwise",
-    "shard_rows", "DistributedEmbedding", "EmbedConfig", "FitResult",
-    "EngineResult", "LoopConfig", "Objective", "fit_loop",
+    "shard_rows", "EngineResult", "LoopConfig", "Objective", "fit_loop",
+    "HomotopyResult", "homotopy_path",
 ]

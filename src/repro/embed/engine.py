@@ -1,10 +1,6 @@
 """The unified fit engine: ONE optimization driver for every backend.
 
-Before this layer existed the repo had three divergent copies of the same
-loop — `core/minimize.py` (jitted fused step + Python bookkeeping),
-`embed/trainer.py::fit` (dense mesh path, host-side backtracking) and
-`embed/trainer.py::_fit_sparse` (sparse path, EMA convergence) — so every
-new capability had to be written three times.  `fit_loop` now owns, once:
+`fit_loop` owns, once for every backend:
 
   * the backtracking line search (core/linesearch semantics, including the
     adaptive-grow trial step and the max-rel-move trust cap),
@@ -39,10 +35,9 @@ and may additionally provide
                             program that replaces the whole direction/
                             line-search/update sequence; `scalars` packs
                             [E, |G|, alpha, n_evals] in E's dtype, so the
-                            host reads an iteration in one transfer — this
-                            is how `core/minimize.py` keeps its
-                            one-XLA-program-per-iteration timing (and its
-                            bit-identical iterates) through the refactor
+                            host reads an iteration in one transfer — how
+                            the dense backend (core/minimize.py) runs one
+                            XLA program per iteration
     place(X)                device placement for X-like arrays (e.g.
                             replicate on a mesh); used on checkpoint restore
     carry_state()           objective-side state to checkpoint (a pytree,
@@ -53,16 +48,17 @@ and may additionally provide
                             the first post-resume iteration sees exactly
                             the state the uninterrupted run would have
 
-Current backends: dense single-device (core/minimize.py), dense 2-D-sharded
-block-Jacobi and sparse single-device (embed/trainer.py), row-sharded
-sparse (sparse/sharding.py via embed/trainer.py).
+Current backends (docs/engine.md): dense single-device
+(core/minimize.py `DenseObjective`, fused step), and from
+embed/trainer.py the dense 2-D-sharded block-Jacobi, the sparse
+single-device, the row-sharded sparse (sparse/sharding.py) and the
+Barnes-Hut tree (sparse/farfield.py) objectives.
 """
 from __future__ import annotations
 
 import dataclasses
 import inspect
 import time
-import warnings
 from typing import Any, Callable, Protocol, runtime_checkable
 
 import jax
@@ -164,9 +160,9 @@ def _place(objective, X):
 
 
 def _callback_wants_diagnostics(callback) -> bool:
-    """True when `callback` accepts a 4th positional argument (or *args):
-    the new form is `callback(it, X, e, diagnostics)`.  Unintrospectable
-    callables are treated as legacy 3-arg."""
+    """True when `callback` accepts a 4th positional argument (or *args),
+    i.e. is the `callback(it, X, e, diagnostics)` form.  Unintrospectable
+    callables are treated as the 3-arg form."""
     try:
         sig = inspect.signature(callback)
     except (TypeError, ValueError):
@@ -194,11 +190,15 @@ def fit_loop(
     Stops on relative (raw or EMA) energy decrease < tol, on max_iters, or
     on max_seconds of wall-clock (the paper's fixed-budget comparisons).
 
+    `callback` takes one of two forms, both supported.  The 4-arg
     `callback(it, X, e, diagnostics)` receives the per-iteration
     diagnostics dict (engine fields + whatever `objective.diagnostics()`
-    lifts out of the jitted step); the legacy 3-arg `callback(it, X, e)`
-    still works but is deprecated — prefer the 4-arg form or the
-    `on_iteration(it, X, diagnostics)` hook.  `telemetry` is a
+    lifts out of the jitted step), as does the
+    `on_iteration(it, X, diagnostics)` hook.  The 3-arg
+    `callback(it, X, e)` receives no diagnostics: when it is the only
+    listener the engine never calls `objective.diagnostics()`, so the
+    callback adds no device->host read per iteration (the benchmark's
+    timed windows rely on this).  `telemetry` is a
     `repro.obs.Telemetry`: its recorder gets one typed record per
     iteration (JSONL when configured) and the engine's spans land on its
     tracer: `setup`, `compile`, per iteration `solve-iter` (inside it
@@ -210,11 +210,6 @@ def fit_loop(
     """
     cb_wants_diag = (callback is not None
                      and _callback_wants_diagnostics(callback))
-    if callback is not None and not cb_wants_diag:
-        warnings.warn(
-            "the 3-arg fit_loop callback(it, X, e) is deprecated; accept "
-            "a 4th diagnostics-dict argument, or use on_iteration=",
-            DeprecationWarning, stacklevel=2)
     if telemetry is not None:
         with telemetry.activate():
             return _fit_loop(objective, X0, cfg, callback, cb_wants_diag,

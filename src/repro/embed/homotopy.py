@@ -5,6 +5,8 @@ follow the minimum path X(lambda) to the target lambda, warm-starting each
 stage from the previous solution.  Slower than direct minimization but finds
 deeper minima (Carreira-Perpinan 2010).  Works with every strategy; the SD
 Cholesky factor does not depend on lambda and is reused across all stages.
+Each stage is one dense fit through the engine (`DenseObjective` +
+`fit_loop`, raw relative-decrease convergence).
 """
 from __future__ import annotations
 
@@ -13,9 +15,11 @@ import dataclasses
 import jax.numpy as jnp
 import numpy as np
 
-from .affinities import Affinities
-from .linesearch import LSConfig
-from .minimize import MinimizeResult, _minimize
+from repro.core.affinities import Affinities
+from repro.core.linesearch import LSConfig
+from repro.core.minimize import DenseObjective
+
+from .engine import EngineResult, LoopConfig, fit_loop
 
 Array = jnp.ndarray
 
@@ -28,7 +32,7 @@ class HomotopyResult:
     iters_per_lambda: np.ndarray
     fevals_per_lambda: np.ndarray
     time_per_lambda: np.ndarray
-    results: list[MinimizeResult]
+    results: list[EngineResult]
 
 
 def homotopy_path(
@@ -48,13 +52,14 @@ def homotopy_path(
     lambdas = np.logspace(
         np.log10(lam_start), np.log10(lam_final), n_stages
     )
+    loop_cfg = LoopConfig(max_iters=max_iters, tol=tol, ls=ls_cfg,
+                          convergence="raw")
     X = X0
-    results: list[MinimizeResult] = []
+    results: list[EngineResult] = []
     for lam in lambdas:
-        res = _minimize(
-            X, aff, kind, jnp.asarray(lam, X0.dtype), strategy,
-            max_iters=max_iters, tol=tol, ls_cfg=ls_cfg,
-        )
+        obj = DenseObjective(aff, kind, jnp.asarray(lam, X0.dtype), strategy,
+                             ls_cfg, X)
+        res = fit_loop(obj, X, loop_cfg)
         X = res.X
         results.append(res)
     return HomotopyResult(

@@ -1,9 +1,9 @@
-"""Mesh-aware `Objective` backends for the unified fit engine, plus the
-legacy `DistributedEmbedding`/`EmbedConfig` entry points (now thin
-deprecation shims over `repro.api.Embedding`).
+"""Mesh-aware and matrix-free `Objective` backends for the unified fit
+engine.
 
 The optimization loop lives in embed/engine.py (`fit_loop`); this module
-contributes the backend builders the public API composes:
+contributes the backend builders the public API (`repro.api.backends`)
+composes, each from a `repro.api.EmbedSpec` (`cfg` below):
 
   * `build_dense_mesh_objective` — the N x N affinities 2-D sharded; the
     spectral direction is solved block-Jacobi (DESIGN.md §3.4).  On a
@@ -20,7 +20,7 @@ contributes the backend builders the public API composes:
     shapes the sparse path can't use (a >1-sized column axis) are rejected
     with a clear error.
 
-Both builders take a `strategy` name (the `repro.api` strategy registry):
+The builders take a `strategy` name (the `repro.api` strategy registry):
 the spectral direction (``sd``, the default) plus its diagonal
 degenerations ``fp`` (B = 4 D+ + mu I — the paper's fixed-point iteration,
 realized here from the same degree vector that Jacobi-preconditions the
@@ -29,10 +29,6 @@ sparse CG) and ``gd`` (B = I).  Strategies that need dense Hessian terms
 before a builder ever runs.
 """
 from __future__ import annotations
-
-import dataclasses
-import warnings
-from typing import Callable
 
 import jax
 import jax.numpy as jnp
@@ -62,104 +58,18 @@ from .distributed import (
     shard_pairwise,
     shard_rows,
 )
-from .engine import EngineResult, LoopConfig
+from .engine import LoopConfig
 
 Array = jnp.ndarray
 
 
-@dataclasses.dataclass
-class EmbedConfig:
-    """DEPRECATED: use `repro.api.EmbedSpec` (declarative spec with
-    strategy/backend registries).  Kept as a validating shim: unknown
-    `kind`/`strategy` fail at construction with the registry's valid
-    names, and `DistributedEmbedding` converts to an `EmbedSpec`."""
-
-    kind: str = "ee"
-    lam: float = 100.0
-    perplexity: float = 20.0
-    dim: int = 2
-    max_iters: int = 200
-    tol: float = 1e-7
-    mu_scale: float = 1e-5
-    strategy: str = "sd"
-    ls: LSConfig = dataclasses.field(
-        default_factory=lambda: LSConfig(init_step="adaptive_grow")
-    )
-    checkpoint_dir: str | None = None
-    checkpoint_every: int = 50
-    seed: int = 0
-    # sparse neighbor-graph pipeline (docs/sparse.md)
-    sparse: bool = False
-    n_neighbors: int = 0         # ELL width k; 0 => auto (3 * perplexity).
-                                 # k < perplexity is rejected: the k-candidate
-                                 # entropy can't reach log(perplexity) and the
-                                 # calibration would degenerate to uniform.
-    n_negatives: int = 5         # uniform negative samples per point
-    z_ema_decay: float = 0.9     # streaming partition-function EMA for the
-                                 # normalized kinds' sparse ratio estimator
-                                 # (0 disables smoothing; ignored when the
-                                 # negatives are exhaustive)
-    knn_method: str = "auto"     # 'exact' | 'approx' | 'auto'
-    cg_tol: float = 1e-3
-    cg_maxiter: int = 100
-
-    def __post_init__(self):
-        # early validation through the api registries (deferred import:
-        # repro.api.backends imports this module)
-        from repro.api.registries import canonical_strategy
-        from repro.api.spec import validate_kind
-
-        validate_kind(self.kind)
-        self.strategy = canonical_strategy(self.strategy)
-        warnings.warn(
-            "EmbedConfig is deprecated; use repro.api.EmbedSpec "
-            "(strategy/backend registries, one spec for every backend)",
-            DeprecationWarning, stacklevel=2)
-
-    def to_spec(self, n_devices: int = 1):
-        """The equivalent `repro.api.EmbedSpec` (sparse flag -> backend)."""
-        from repro.api.spec import EmbedSpec
-
-        if self.sparse:
-            backend = "sparse-sharded" if n_devices > 1 else "sparse"
-        else:
-            backend = "dense-mesh"
-        return EmbedSpec(
-            kind=self.kind, strategy=self.strategy, backend=backend,
-            lam=self.lam, perplexity=self.perplexity, dim=self.dim,
-            max_iters=self.max_iters, tol=self.tol, mu_scale=self.mu_scale,
-            ls=self.ls, checkpoint_dir=self.checkpoint_dir,
-            checkpoint_every=self.checkpoint_every, seed=self.seed,
-            n_neighbors=self.n_neighbors, n_negatives=self.n_negatives,
-            z_ema_decay=self.z_ema_decay, knn_method=self.knn_method,
-            cg_tol=self.cg_tol, cg_maxiter=self.cg_maxiter)
-
-
-@dataclasses.dataclass
-class FitResult:
-    X: Array
-    energies: np.ndarray
-    times: np.ndarray
-    n_iters: int
-    resumed_from: int | None
-    diagnostics: list[dict] | None = None   # per-iteration table when the
-                                            # run was fit with telemetry /
-                                            # a diagnostics consumer
-
-
-def to_fit_result(res: EngineResult) -> FitResult:
-    return FitResult(X=res.X, energies=res.energies, times=res.times,
-                     n_iters=res.n_iters, resumed_from=res.resumed_from,
-                     diagnostics=res.diagnostics)
-
-
-def make_loop_config(cfg, ls: LSConfig) -> LoopConfig:
-    """LoopConfig from any spec-shaped config (EmbedSpec or EmbedConfig)."""
+def make_loop_config(spec, ls: LSConfig) -> LoopConfig:
+    """The engine's LoopConfig for a `repro.api.EmbedSpec`."""
     return LoopConfig(
-        max_iters=cfg.max_iters, tol=cfg.tol, ls=ls,
-        checkpoint_dir=cfg.checkpoint_dir,
-        checkpoint_every=cfg.checkpoint_every, seed=cfg.seed,
-        max_seconds=getattr(cfg, "max_seconds", None),
+        max_iters=spec.max_iters, tol=spec.tol, ls=ls,
+        checkpoint_dir=spec.checkpoint_dir,
+        checkpoint_every=spec.checkpoint_every, seed=spec.seed,
+        max_seconds=spec.max_seconds,
     )
 
 
@@ -460,18 +370,15 @@ def build_sparse_objective(cfg, mesh: Mesh | None = None,
         with span("spectral-init", phase=True, n=n):
             X = jax.block_until_ready(_sparse_spectral_init(cfg, saff, n))
 
-    # kernel-dispatch knobs (EmbedSpec; legacy EmbedConfig has neither,
-    # so getattr keeps the deprecation shims byte-identical)
-    kernel_impl = getattr(cfg, "kernel_impl", "auto")
-    kernel_precision = getattr(cfg, "kernel_precision", "float32")
-    kernel_args = cfg.kernel_args() if hasattr(cfg, "kernel_args") else {}
+    kernel_args = cfg.kernel_args()
 
     if sharded:
         sg = shard_sparse_affinities(mesh, mspec.row_axes, saff)
         eg_l, e_l = make_sharded_energy_grad(
             mesh, mspec.row_axes, sg, cfg.kind,
             n_negatives=cfg.n_negatives, z_decay=cfg.z_ema_decay,
-            kernel_impl=kernel_impl, kernel_precision=kernel_precision)
+            kernel_impl=cfg.kernel_impl,
+            kernel_precision=cfg.kernel_precision)
         if normalized:
             eg = lambda X, key, z: eg_l(X, lam, key, z)
         else:
@@ -479,7 +386,8 @@ def build_sparse_objective(cfg, mesh: Mesh | None = None,
         e_only = lambda X, key: e_l(X, lam, key)
         matvec, inv_diag, _ = make_sharded_sd_operator(
             mesh, mspec.row_axes, sg, saff, cfg.mu_scale,
-            kernel_impl=kernel_impl, kernel_precision=kernel_precision)
+            kernel_impl=cfg.kernel_impl,
+            kernel_precision=cfg.kernel_precision)
         place = lambda X: replicate(mesh, X)
         X = place(X)
     else:
@@ -547,10 +455,9 @@ def build_tree_objective(cfg, Y: Array | None = None,
         with span("spectral-init", phase=True, n=n):
             X = jax.block_until_ready(_sparse_spectral_init(cfg, saff, n))
 
-    plan = make_grid_plan(
-        n, theta=cfg.theta, depth=getattr(cfg, "tree_depth", 0),
-        cap=getattr(cfg, "tree_cap", 0))
-    kernel_args = cfg.kernel_args() if hasattr(cfg, "kernel_args") else {}
+    plan = make_grid_plan(n, theta=cfg.theta, depth=cfg.tree_depth,
+                          cap=cfg.tree_cap)
+    kernel_args = cfg.kernel_args()
 
     def eg(X, key):
         return energy_and_grad_tree(X, saff, lam, cfg.kind, plan,
@@ -565,30 +472,3 @@ def build_tree_objective(cfg, Y: Array | None = None,
     solve = _make_direction_solve(strategy, matvec, inv_diag, cfg, "tree")
     obj = _TreeObjective(eg, e_only, solve, X, plan)
     return obj, X
-
-
-class DistributedEmbedding:
-    """DEPRECATED: use `repro.api.Embedding` (pass the mesh to its
-    constructor).  Thin shim: converts the `EmbedConfig` to an `EmbedSpec`
-    and delegates `fit` to the estimator, so legacy call sites keep their
-    exact behavior (same builders, same engine, same results)."""
-
-    def __init__(self, cfg: EmbedConfig, mesh: Mesh,
-                 spec: EmbedMeshSpec | None = None):
-        warnings.warn(
-            "DistributedEmbedding is deprecated; use repro.api.Embedding "
-            "(EmbedSpec + mesh) instead",
-            DeprecationWarning, stacklevel=2)
-        self.cfg = cfg
-        self.mesh = mesh
-        self.spec = spec if spec is not None else default_mesh_spec(mesh)
-
-    def fit(self, Y: Array, X0: Array | None = None,
-            callback: Callable[[int, Array, float], None] | None = None
-            ) -> FitResult:
-        from repro.api import Embedding
-
-        est = Embedding(self.cfg.to_spec(self.mesh.devices.size),
-                        mesh=self.mesh, mesh_spec=self.spec)
-        est.fit(Y, X0=X0, callback=callback)
-        return to_fit_result(est.result_)

@@ -5,9 +5,8 @@ calls; each is a PLAIN Python dispatcher (decisions happen at call/trace
 time, outside any jit) wrapping jitted implementations:
 
   1. **Path**: Pallas vs the jnp oracle, decided by the `impl` knob
-     ('auto' | 'pallas' | 'pallas-interpret' | 'jnp'; the legacy
-     `use_pallas` bool still works) — 'auto' means Pallas on TPU, jnp
-     elsewhere.
+     ('auto' | 'pallas' | 'pallas-interpret' | 'jnp') — 'auto' means
+     Pallas on TPU, jnp elsewhere.
   2. **Layout + tiles**: when the caller leaves `block_rows` unset,
      autotune.py takes the first candidate of a fixed preference list
      that compiles at the request's shape bucket and caches it; the ELL
@@ -172,13 +171,10 @@ def _pad_to(x: jnp.ndarray, rows: int, cols: int) -> jnp.ndarray:
     return jnp.pad(x, ((0, pr), (0, pc)))
 
 
-def _resolve_impl(impl, use_pallas):
-    """Merge the new `impl` knob with the legacy `use_pallas` bool."""
+def _resolve_impl(impl):
+    """Validate the `impl` knob; None means 'auto'."""
     if impl is None:
-        if use_pallas is None:
-            impl = "auto"
-        else:
-            impl = "pallas" if use_pallas else "jnp"
+        impl = "auto"
     if impl not in IMPLS:
         raise ValueError(f"unknown impl {impl!r}; have {IMPLS}")
     return impl
@@ -309,7 +305,6 @@ def ell_lap_matvec(
     weights: jnp.ndarray,    # (N, k)
     *,
     impl: str | None = None,
-    use_pallas: bool | None = None,
     block_rows: int | None = None,
     layout: str | None = None,
     chunk: int | None = None,
@@ -326,7 +321,7 @@ def ell_lap_matvec(
     `ell_live_lengths(weights)` gives them, handed in by callers that
     apply one graph many times (sparse/linalg.make_sd_operator), derived
     here from `weights` when absent.  The jnp path ignores them."""
-    impl = _resolve_impl(impl, use_pallas)
+    impl = _resolve_impl(impl)
     storage = _resolve_storage(storage_dtype)
     n, d = X.shape
     k = indices.shape[1]
@@ -411,7 +406,6 @@ def pairwise_terms(
     kind: str,
     *,
     impl: str | None = None,
-    use_pallas: bool | None = None,
     block_rows: int | None = None,
     block_cols: int | None = None,
     interpret: bool | None = None,
@@ -422,7 +416,7 @@ def pairwise_terms(
     module docstring for the dispatch ladder."""
     if kind not in KINDS:
         raise ValueError(f"unknown kind {kind!r}")
-    impl = _resolve_impl(impl, use_pallas)
+    impl = _resolve_impl(impl)
     storage = _resolve_storage(storage_dtype)
     n, d = X.shape
 
@@ -506,7 +500,6 @@ def bh_interaction(
     kind: str,
     *,
     impl: str | None = None,
-    use_pallas: bool | None = None,
     block_rows: int | None = None,
     interpret: bool | None = None,
     lane: int = 128,
@@ -521,7 +514,7 @@ def bh_interaction(
     raw X, which the jnp gather handles fine)."""
     if kind not in KINDS:
         raise ValueError(f"unknown kind {kind!r}")
-    impl = _resolve_impl(impl, use_pallas)
+    impl = _resolve_impl(impl)
     storage = _resolve_storage(storage_dtype)
     n, d = X.shape
     width = idx.shape[1]
@@ -596,7 +589,7 @@ def resolve_local_ell(nb: int, k: int, d: int, *, n_rep: int,
     (the local grid must tile the shard exactly, and the BlockSpec row
     translation needs row0 % block_rows == 0 — sharding.py pads nb to a
     sublane multiple)."""
-    impl = _resolve_impl(impl, None)
+    impl = _resolve_impl(impl)
     storage = _resolve_storage(storage_dtype)
     reason = None
     if impl == "jnp" or (impl == "auto" and not _on_tpu()):

@@ -13,6 +13,9 @@ import jax.numpy as jnp
 import pytest
 
 from repro.analysis import guards as _guards
+from repro.core.linesearch import LSConfig
+from repro.core.minimize import DenseObjective
+from repro.embed.engine import LoopConfig, fit_loop
 
 
 @pytest.fixture
@@ -51,6 +54,18 @@ def three_loops(n_per: int = 40, loops: int = 3, dim: int = 16, seed: int = 0):
         circ = jnp.stack([jnp.cos(ts), jnp.sin(ts)], -1) @ proj
         pts.append(circ + c)
     return jnp.concatenate(pts)
+
+
+def dense_fit(X0, aff, kind, lam, strategy, max_iters=500, tol=1e-7,
+              ls_cfg=LSConfig(), max_seconds=None):
+    """One dense fit with a strategy OBJECT (core/strategies): the
+    `DenseObjective` through the engine with the raw relative-decrease
+    test — what `repro.api`'s dense backend runs for a registered name."""
+    obj = DenseObjective(aff, kind, jnp.asarray(lam, dtype=X0.dtype),
+                         strategy, ls_cfg, X0)
+    return fit_loop(obj, X0,
+                    LoopConfig(max_iters=max_iters, tol=tol, ls=ls_cfg,
+                               convergence="raw", max_seconds=max_seconds))
 
 
 @pytest.fixture(scope="session")

@@ -203,11 +203,12 @@ def test_compile_pin_dense_fused_step(data, dense_spec, fitted_dense):
 
 
 def test_compile_pin_sparse_epoch(data):
-    from repro.embed import EmbedConfig
+    from repro.api import EmbedSpec
     from repro.embed.trainer import build_sparse_objective
-    cfg = EmbedConfig(kind="ee", lam=50.0, perplexity=8.0, max_iters=5,
-                      sparse=True, n_neighbors=12, n_negatives=8, tol=0.0)
-    obj, X0 = build_sparse_objective(cfg, Y=jnp.asarray(data))
+    spec = EmbedSpec(kind="ee", lam=50.0, perplexity=8.0, max_iters=5,
+                     backend="sparse", n_neighbors=12, n_negatives=8,
+                     tol=0.0)
+    obj, X0 = build_sparse_objective(spec, Y=jnp.asarray(data))
     key0 = jax.random.PRNGKey(1)
     # warm the exact per-iteration sequence (incl. the eager fold_in)
     jax.block_until_ready(obj.energy_and_grad(X0, jax.random.fold_in(key0, 1)))

@@ -1,10 +1,9 @@
 """The unified `repro.api` estimator surface: registry validation,
-strategy parity against the legacy drivers, backend auto-resolution,
-out-of-sample transform semantics, the versioned artifact format
-(save/load), and the deprecation shims."""
+strategy parity against a direct engine fit, backend auto-resolution,
+out-of-sample transform semantics and the versioned artifact format
+(save/load)."""
 import dataclasses
 import os
-import warnings
 
 import jax
 import jax.numpy as jnp
@@ -16,7 +15,7 @@ from repro.api import Embedding, EmbedSpec, TransformSpec, \
 from repro.core import LSConfig, laplacian_eigenmaps, make_affinities
 from repro.core.strategies import DiagH, FP, GD, SD, SDMinus
 from repro.data import mnist_like
-from tests.conftest import three_loops
+from tests.conftest import dense_fit, three_loops
 
 
 @pytest.fixture(scope="module")
@@ -69,8 +68,8 @@ def test_spec_rejects_unknown_kernel_knobs():
 
 
 def test_spec_kernel_args_empty_at_defaults():
-    """Default kernel knobs forward NOTHING, keeping legacy call paths
-    byte-identical (the bit-for-bit parity tests below depend on it)."""
+    """Default kernel knobs forward NOTHING, so the dispatchers pick for
+    themselves (the bit-for-bit parity tests below depend on it)."""
     assert EmbedSpec().kernel_args() == {}
     assert EmbedSpec(kernel_impl="jnp").kernel_args() == {"impl": "jnp"}
     assert EmbedSpec(kernel_precision="bfloat16").kernel_args() == \
@@ -101,15 +100,11 @@ def test_dense_fit_through_interpret_kernel(problem):
     assert ops.last_dispatch("pairwise_terms")["storage"] == "bfloat16"
 
 
-def test_embedconfig_rejects_unknown_names():
-    from repro.embed import EmbedConfig
-
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        with pytest.raises(ValueError, match="model families"):
-            EmbedConfig(kind="nope")
-        with pytest.raises(ValueError, match="registered strategies"):
-            EmbedConfig(strategy="newton")
+def test_spec_rejection_messages_name_the_registries():
+    with pytest.raises(ValueError, match="model families"):
+        EmbedSpec(kind="nope")
+    with pytest.raises(ValueError, match="registered strategies"):
+        EmbedSpec(strategy="newton")
 
 
 def test_auto_backend_resolution():
@@ -133,24 +128,22 @@ def test_auto_backend_resolution():
 # -- strategy-registry parity (satellite) ---------------------------------------
 
 
-@pytest.mark.parametrize("name,legacy", [
+@pytest.mark.parametrize("name,strategy", [
     ("gd", GD()),
     ("fp", FP()),
     ("diag", DiagH()),
     ("sd", SD()),
     ("sd-", SDMinus()),
 ])
-def test_dense_strategy_parity_bit_for_bit(problem, name, legacy):
+def test_dense_strategy_parity_bit_for_bit(problem, name, strategy):
     """Every registered partial-Hessian strategy through repro.api matches
-    the legacy core.minimize trajectory bit-for-bit."""
+    a direct engine fit of the `core/strategies` object bit-for-bit: the
+    registry maps each name onto exactly that object."""
     _, aff, X0 = problem
     ls = LSConfig(init_step="adaptive_grow" if name.startswith("sd")
                   else "one")
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        from repro.core import minimize
-        ref = minimize(X0, aff, "ee", 50.0, legacy, max_iters=10,
-                       tol=1e-6, ls_cfg=ls)
+    ref = dense_fit(X0, aff, "ee", 50.0, strategy, max_iters=10, tol=1e-6,
+                    ls_cfg=ls)
     emb = Embedding(EmbedSpec(kind="ee", lam=50.0, strategy=name,
                               backend="dense", max_iters=10, tol=1e-6,
                               ls=ls))
@@ -168,24 +161,6 @@ def sparse_spec():
     return EmbedSpec(kind="ee", lam=50.0, strategy="sd", backend="sparse",
                      perplexity=8.0, max_iters=8, tol=0.0,
                      n_neighbors=24, n_negatives=8)
-
-
-def test_sparse_backend_matches_legacy_trainer(problem, sparse_spec):
-    """repro.api's sparse backend IS the legacy EmbedConfig(sparse=True)
-    path — identical trajectories (same builders, engine, seeds)."""
-    Y, _, _ = problem
-    api = Embedding(sparse_spec).fit(Y)
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        from repro.embed import DistributedEmbedding, EmbedConfig
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
-        cfg = EmbedConfig(kind="ee", lam=50.0, perplexity=8.0, max_iters=8,
-                          tol=0.0, sparse=True, n_neighbors=24,
-                          n_negatives=8)
-        legacy = DistributedEmbedding(cfg, mesh).fit(Y)
-    np.testing.assert_array_equal(np.asarray(legacy.X),
-                                  np.asarray(api.embedding_))
-    np.testing.assert_array_equal(legacy.energies, api.result_.energies)
 
 
 @pytest.mark.parametrize("strategy", ["fp", "gd"])
@@ -343,32 +318,6 @@ def test_auto_backend_with_precomputed_aff_stays_dense(problem):
     assert emb.backend_ == "dense"
 
 
-# -- deprecation shims (satellite) ----------------------------------------------
-
-
-def test_minimize_shim_warns(problem):
-    _, aff, X0 = problem
-    from repro.core import SD as CoreSD, minimize
-    with pytest.warns(DeprecationWarning, match="repro.api.Embedding"):
-        minimize(X0, aff, "ee", 50.0, CoreSD(), max_iters=1, tol=0.0)
-
-
-def test_embedconfig_shim_warns():
-    from repro.embed import EmbedConfig
-    with pytest.warns(DeprecationWarning, match="EmbedSpec"):
-        EmbedConfig(kind="ee")
-
-
-def test_distributed_embedding_shim_warns():
-    from repro.embed import DistributedEmbedding, EmbedConfig
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        cfg = EmbedConfig(kind="ee")
-    with pytest.warns(DeprecationWarning, match="repro.api.Embedding"):
-        DistributedEmbedding(cfg, mesh)
-
-
 # -- TransformSpec (satellite: frozen request-shaping config) -------------------
 
 
@@ -405,24 +354,6 @@ def test_transform_spec_resolves_deferred_fields_from_embedspec():
     # explicit values win over the spec's defaults
     r2 = resolve_transform_spec(spec, TransformSpec(max_iters=5, tol=0.0))
     assert (r2.max_iters, r2.tol) == (5, 0.0)
-
-
-def test_transform_legacy_kwargs_warn_but_match_spec_path():
-    Y, _ = mnist_like(n=120)
-    emb = Embedding(EmbedSpec(kind="ee", lam=10.0, strategy="sd",
-                              backend="dense", perplexity=8.0,
-                              max_iters=8, tol=0.0))
-    emb.fit(jnp.asarray(Y[:100]))
-    with pytest.warns(DeprecationWarning, match="TransformSpec"):
-        a = emb.transform(jnp.asarray(Y[100:]), max_iters=6,
-                          n_negatives=None)
-    b = emb.transform(jnp.asarray(Y[100:]),
-                      spec=TransformSpec(max_iters=6, exhaustive=True))
-    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    # mixing the spec with legacy kwargs is an error, not a silent merge
-    with pytest.raises(ValueError, match="not both"):
-        emb.transform(jnp.asarray(Y[100:]), spec=TransformSpec(),
-                      max_iters=3)
 
 
 def test_rowwise_solver_is_batch_composition_invariant():

@@ -8,7 +8,7 @@ import textwrap
 import jax
 import numpy as np
 
-from repro.embed import DistributedEmbedding, EmbedConfig
+from repro.api import Embedding, EmbedSpec
 from tests.conftest import three_loops
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -76,14 +76,13 @@ def test_multi_device_distributed_matches_dense():
 def test_trainer_fit_single_device(tmp_path):
     mesh = jax.make_mesh((1, 1), ("data", "model"))
     Y = three_loops(n_per=16, loops=2, dim=8)
-    cfg = EmbedConfig(kind="ee", lam=50.0, perplexity=8.0, max_iters=20,
-                      checkpoint_dir=str(tmp_path / "ck"), checkpoint_every=5)
-    emb = DistributedEmbedding(cfg, mesh)
-    res = emb.fit(Y)
+    spec = EmbedSpec(kind="ee", lam=50.0, perplexity=8.0, max_iters=20,
+                     backend="dense-mesh",
+                     checkpoint_dir=str(tmp_path / "ck"), checkpoint_every=5)
+    res = Embedding(spec, mesh=mesh).fit(Y).result_
     assert res.energies[-1] < res.energies[0]
     assert np.all(np.isfinite(res.energies))
 
     # restart resumes from the saved checkpoint
-    emb2 = DistributedEmbedding(cfg, mesh)
-    res2 = emb2.fit(Y)
+    res2 = Embedding(spec, mesh=mesh).fit(Y).result_
     assert res2.resumed_from is not None

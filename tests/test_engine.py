@@ -1,6 +1,7 @@
-"""Unified fit engine (embed/engine.py): bit-identity of the refactored
-core.minimize and of the fused loop's packed fetch, the loop's transfer
-count, and checkpoint/resume reproducibility through the engine."""
+"""Unified fit engine (embed/engine.py): bit-identity of the dense fused
+path against the pre-engine driver loop and of the fused loop's packed
+fetch, the loop's transfer count, and checkpoint/resume reproducibility
+through the engine."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -8,11 +9,10 @@ import pytest
 
 from repro.api import Embedding, EmbedSpec
 from repro.core import (FP, GD, SD, LSConfig, energy_and_grad,
-                        laplacian_eigenmaps, make_affinities, minimize)
+                        laplacian_eigenmaps, make_affinities)
 from repro.core.minimize import DenseObjective, _step
-from repro.embed import DistributedEmbedding, EmbedConfig
 from repro.embed.engine import LoopConfig, fit_loop
-from tests.conftest import three_loops
+from tests.conftest import dense_fit, three_loops
 
 
 @pytest.fixture(scope="module")
@@ -24,7 +24,7 @@ def problem():
 
 
 def _seed_minimize(X0, aff, kind, lam, strategy, max_iters, tol, ls_cfg):
-    """The pre-engine core.minimize driver loop, pinned verbatim (minus
+    """The pre-engine dense driver loop, pinned verbatim (minus
     timing): the engine's fused-step path must reproduce it bit-for-bit."""
     lam = jnp.asarray(lam, dtype=X0.dtype)
     state = jax.block_until_ready(strategy.init(X0, aff, kind, lam))
@@ -62,8 +62,8 @@ def test_minimize_bit_identical_to_seed_driver(problem, strategy, ls_cfg):
     _, aff, X0 = problem
     X, energies, gnorms, steps, fevals, n_iters, converged = _seed_minimize(
         X0, aff, "ee", 50.0, strategy, 20, 1e-6, ls_cfg)
-    res = minimize(X0, aff, "ee", 50.0, strategy, max_iters=20, tol=1e-6,
-                   ls_cfg=ls_cfg)
+    res = dense_fit(X0, aff, "ee", 50.0, strategy, max_iters=20, tol=1e-6,
+                    ls_cfg=ls_cfg)
     np.testing.assert_array_equal(np.asarray(X), np.asarray(res.X))
     assert energies == list(res.energies)
     assert gnorms == list(res.grad_norms)
@@ -88,19 +88,18 @@ def test_resume_replays_uninterrupted_trace(tmp_path, sparse, kind, lam):
     per-iteration fold_in keys make the surrogate exactly reproducible."""
     Y = three_loops(n_per=16, loops=2, dim=8)
     mesh = jax.make_mesh((1, 1), ("data", "model"))
-    base = dict(kind=kind, lam=lam, perplexity=8.0, tol=0.0, sparse=sparse,
-                n_neighbors=24 if sparse else 0, n_negatives=8)
+    base = EmbedSpec(kind=kind, lam=lam, perplexity=8.0, tol=0.0,
+                     backend="sparse" if sparse else "dense-mesh",
+                     n_neighbors=24 if sparse else 0, n_negatives=8)
 
-    full = DistributedEmbedding(
-        EmbedConfig(max_iters=12, **base), mesh).fit(Y)
+    def fit(**changes):
+        return Embedding(base.replace(**changes), mesh=mesh).fit(Y).result_
+
+    full = fit(max_iters=12)
 
     ckdir = str(tmp_path / "ck")
-    DistributedEmbedding(
-        EmbedConfig(max_iters=6, checkpoint_dir=ckdir,
-                    checkpoint_every=100, **base), mesh).fit(Y)
-    res = DistributedEmbedding(
-        EmbedConfig(max_iters=12, checkpoint_dir=ckdir,
-                    checkpoint_every=100, **base), mesh).fit(Y)
+    fit(max_iters=6, checkpoint_dir=ckdir, checkpoint_every=100)
+    res = fit(max_iters=12, checkpoint_dir=ckdir, checkpoint_every=100)
 
     assert res.resumed_from == 6
     assert res.n_iters == 6
@@ -114,9 +113,9 @@ def test_resume_replays_uninterrupted_trace(tmp_path, sparse, kind, lam):
 
 
 def test_engine_max_seconds_and_traces(problem):
-    """EngineResult trace invariants surface through minimize()."""
+    """EngineResult trace invariants of a dense fit."""
     _, aff, X0 = problem
-    res = minimize(X0, aff, "ee", 50.0, SD(), max_iters=15, tol=0.0)
+    res = dense_fit(X0, aff, "ee", 50.0, SD(), max_iters=15, tol=0.0)
     assert len(res.energies) == res.n_iters + 1
     assert len(res.step_sizes) == res.n_iters
     assert res.n_fevals[-1] >= res.n_iters

@@ -30,7 +30,7 @@ def _rand_problem(seed: int, n: int, d: int, dtype=jnp.float32):
 
 def _check(X, Wa, Wb, kind, br, bc, lane=8, tol=5e-5):
     r = pairwise_terms_ref(X, Wa, Wb, kind)
-    p = ops.pairwise_terms(X, Wa, Wb, kind, use_pallas=True, interpret=True,
+    p = ops.pairwise_terms(X, Wa, Wb, kind, impl="pallas", interpret=True,
                            block_rows=br, block_cols=bc, lane=lane)
     np.testing.assert_allclose(np.asarray(p.la_x), np.asarray(r.la_x),
                                rtol=tol, atol=tol * float(jnp.max(jnp.abs(r.la_x)) + 1))
@@ -58,7 +58,7 @@ def test_kernel_bf16_inputs(kind):
     X, Wa, Wb = _rand_problem(1, 64, 2)
     Xb = X.astype(jnp.bfloat16)
     r = pairwise_terms_ref(X, Wa, Wb, kind)
-    p = ops.pairwise_terms(Xb, Wa, Wb, kind, use_pallas=True, interpret=True,
+    p = ops.pairwise_terms(Xb, Wa, Wb, kind, impl="pallas", interpret=True,
                            block_rows=32, block_cols=32, lane=8)
     rel = float(jnp.linalg.norm(p.la_x - r.la_x) /
                 (jnp.linalg.norm(r.la_x) + 1e-30))

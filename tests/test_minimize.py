@@ -1,14 +1,16 @@
-"""Driver-level behaviour: line search, convergence accounting, homotopy."""
+"""Dense-fit behaviour through the engine: line search, convergence
+accounting, homotopy."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core import (
-    SD, GD, LSConfig, energy, energy_and_grad, homotopy_path,
-    laplacian_eigenmaps, make_affinities, minimize,
+    SD, GD, LSConfig, energy, energy_and_grad, laplacian_eigenmaps,
+    make_affinities,
 )
 from repro.core.linesearch import backtracking
-from tests.conftest import three_loops
+from repro.embed import homotopy_path
+from tests.conftest import dense_fit, three_loops
 
 
 @pytest.fixture(scope="module")
@@ -34,7 +36,7 @@ def test_backtracking_satisfies_armijo(problem):
 
 def test_minimize_traces_consistent(problem):
     aff, X0 = problem
-    res = minimize(X0, aff, "ee", 50.0, SD(), max_iters=15, tol=0.0)
+    res = dense_fit(X0, aff, "ee", 50.0, SD(), max_iters=15, tol=0.0)
     assert len(res.energies) == res.n_iters + 1
     assert len(res.times) == res.n_iters + 1
     assert res.n_fevals[-1] >= res.n_iters  # at least one eval per iteration
@@ -44,16 +46,16 @@ def test_minimize_traces_consistent(problem):
 
 def test_minimize_tol_stops_early(problem):
     aff, X0 = problem
-    res = minimize(X0, aff, "ee", 50.0, SD(), max_iters=500, tol=1e-6,
-                   ls_cfg=LSConfig(init_step="adaptive_grow"))
+    res = dense_fit(X0, aff, "ee", 50.0, SD(), max_iters=500, tol=1e-6,
+                    ls_cfg=LSConfig(init_step="adaptive_grow"))
     assert res.converged
     assert res.n_iters < 500
 
 
 def test_max_seconds_budget(problem):
     aff, X0 = problem
-    res = minimize(X0, aff, "ee", 50.0, GD(), max_iters=100_000, tol=0.0,
-                   max_seconds=1.0)
+    res = dense_fit(X0, aff, "ee", 50.0, GD(), max_iters=100_000, tol=0.0,
+                    max_seconds=1.0)
     assert res.times[-1] < 20.0  # generous: one step + compile
 
 

@@ -196,18 +196,43 @@ def test_no_telemetry_means_no_diagnostics(Y):
     assert emb.telemetry_ is None
 
 
-# -- engine callback compat ------------------------------------------------------
+# -- engine callback forms -------------------------------------------------------
 
 
-def test_legacy_three_arg_callback_warns_but_works(Y):
+def test_three_arg_callback_sees_every_iteration_without_diagnostics(Y):
+    """The 3-arg form is the no-diagnostics contract: it sees every
+    iteration, raises no warning, and the engine collects no diagnostics
+    for it (so no per-iteration device->host read is added)."""
     seen = []
 
-    def legacy(it, X, e):
+    def plain(it, X, e):
         seen.append((it, float(e)))
 
-    with pytest.warns(DeprecationWarning, match="diagnostics"):
-        Embedding(_sparse_spec(iters=3)).fit(Y, callback=legacy)
+    with warnings.catch_warnings():
+        warnings.simplefilter("error", DeprecationWarning)
+        emb = Embedding(_sparse_spec(iters=3)).fit(Y, callback=plain)
     assert [it for it, _ in seen] == [1, 2, 3]
+    assert emb.result_.diagnostics is None
+
+
+def test_three_arg_callback_never_calls_objective_diagnostics(Y):
+    """The engine asks the objective for diagnostics only when someone
+    listens for them; a 3-arg callback does not."""
+    from repro.embed.engine import fit_loop
+    from repro.embed.trainer import build_sparse_objective, make_loop_config
+
+    spec = _sparse_spec(iters=3)
+    obj, X0 = build_sparse_objective(spec, Y=Y)
+
+    def no_diagnostics():
+        raise AssertionError("diagnostics() called for a 3-arg callback")
+
+    obj.diagnostics = no_diagnostics
+    seen = []
+    res = fit_loop(obj, X0, make_loop_config(spec, spec.resolved_ls()),
+                   lambda it, X, e: seen.append(it))
+    assert seen == [1, 2, 3]
+    assert res.diagnostics is None
 
 
 def test_four_arg_callback_gets_diagnostics(Y):

@@ -24,7 +24,7 @@ _SUBPROCESS_PROG = textwrap.dedent("""
     import jax, jax.numpy as jnp, numpy as np
     from repro.launch.mesh import axis_types_kwargs
     from repro.core import energy_and_grad_sparse
-    from repro.embed import DistributedEmbedding, EmbedConfig
+    from repro.api import Embedding, EmbedSpec
     from repro.sparse import (make_sd_operator, make_sharded_energy_grad,
                               make_sharded_sd_operator,
                               shard_sparse_affinities, sparse_affinities)
@@ -106,18 +106,20 @@ _SUBPROCESS_PROG = textwrap.dedent("""
         return jnp.concatenate(pts)
 
     Y2 = three_loops(25, 2, 8)                       # n=50
-    cfg = EmbedConfig(kind="ee", lam=50.0, perplexity=8.0, max_iters=10,
-                      sparse=True, n_neighbors=24, n_negatives=8, tol=0.0)
+    spec = EmbedSpec(kind="ee", lam=50.0, perplexity=8.0, max_iters=10,
+                     backend="sparse", n_neighbors=24, n_negatives=8,
+                     tol=0.0)
+    sharded = spec.replace(backend="sparse-sharded")
     mesh1 = jax.make_mesh((1, 1), ("data", "model"), **axis_types_kwargs(2))
     iterates = []
-    r1 = DistributedEmbedding(cfg, mesh1).fit(
-        Y2, callback=lambda it, X, e: iterates.append(np.asarray(X)))
+    r1 = Embedding(spec, mesh=mesh1).fit(
+        Y2, callback=lambda it, X, e: iterates.append(np.asarray(X))).result_
 
     saff2 = sparse_affinities(Y2, k=24, perplexity=8.0, model="ee")
     sg2 = shard_sparse_affinities(mesh, ("data",), saff2)
     eg8, _ = make_sharded_energy_grad(mesh, ("data",), sg2, "ee",
                                       n_negatives=8)
-    key0 = jax.random.PRNGKey(cfg.seed + 1)
+    key0 = jax.random.PRNGKey(spec.seed + 1)
     for it, Xt in enumerate(iterates, start=1):
         key = jax.random.fold_in(key0, it)
         E1, G1 = energy_and_grad_sparse(jnp.asarray(Xt), saff2, "ee", 50.0,
@@ -127,26 +129,26 @@ _SUBPROCESS_PROG = textwrap.dedent("""
         relG = float(jnp.linalg.norm(G1 - G8) / jnp.linalg.norm(G1))
         assert relE <= 1e-5 and relG <= 1e-5, (it, relE, relG)
 
-    # -- end-to-end: the trainer routes multi-device sparse through the
-    # sharded backend and tracks the single-device run ---------------------
-    r8 = DistributedEmbedding(cfg, mesh).fit(Y2)
+    # -- end-to-end: the sharded backend on 8 devices tracks the
+    # single-device run ----------------------------------------------------
+    r8 = Embedding(sharded, mesh=mesh).fit(Y2).result_
     assert r8.energies[-1] < r8.energies[0]
     assert r8.X.shape == (Y2.shape[0], 2)
     # identical seeds: trajectories agree up to accumulated fp noise
     np.testing.assert_allclose(r8.energies, r1.energies, rtol=5e-3)
 
     # -- acceptance: normalized-model trainer parity, 8 devices vs 1 -------
-    cfg_t = EmbedConfig(kind="tsne", lam=1.0, perplexity=8.0, max_iters=8,
-                        sparse=True, n_neighbors=24, n_negatives=8, tol=0.0)
-    rt1 = DistributedEmbedding(cfg_t, mesh1).fit(Y2)
-    rt8 = DistributedEmbedding(cfg_t, mesh).fit(Y2)
+    spec_t = spec.replace(kind="tsne", lam=1.0, max_iters=8)
+    rt1 = Embedding(spec_t, mesh=mesh1).fit(Y2).result_
+    rt8 = Embedding(spec_t.replace(backend="sparse-sharded"),
+                    mesh=mesh).fit(Y2).result_
     assert rt8.energies[-1] < rt8.energies[0]
     np.testing.assert_allclose(rt8.energies, rt1.energies, rtol=5e-3)
 
     # -- mesh shapes the sparse path can't use are rejected ----------------
     mesh24 = jax.make_mesh((2, 4), ("data", "model"), **axis_types_kwargs(2))
     try:
-        DistributedEmbedding(cfg, mesh24).fit(Y2)
+        Embedding(sharded, mesh=mesh24).fit(Y2)
         raise SystemExit("expected ValueError for (2, 4) mesh")
     except ValueError as e:
         assert "size 1" in str(e), e

@@ -262,13 +262,14 @@ def test_sparse_sd_matches_cholesky_sd():
 
 
 def test_sparse_sd_native_graph_descends():
-    """minimize() with SparseSD initialized from SparseAffinities state."""
-    from repro.core import LSConfig, minimize
+    """A dense fit with SparseSD initialized from SparseAffinities state."""
+    from repro.core import LSConfig
+    from tests.conftest import dense_fit
     Y = three_loops(n_per=24, loops=2, dim=8)
     aff = make_affinities(Y, 10.0, model="ee")
     X0 = jax.random.normal(jax.random.PRNGKey(0), (Y.shape[0], 2)) * 0.1
-    res = minimize(X0, aff, "ee", 50.0, make_strategy("sparsesd"),
-                   max_iters=20, ls_cfg=LSConfig(init_step="adaptive_grow"))
+    res = dense_fit(X0, aff, "ee", 50.0, make_strategy("sparsesd"),
+                    max_iters=20, ls_cfg=LSConfig(init_step="adaptive_grow"))
     assert res.energies[-1] < 0.5 * res.energies[0]
 
 
@@ -324,26 +325,24 @@ def test_sparse_init_routes_to_power_iteration_above_cutoff():
 
 
 def test_trainer_sparse_path_descends():
-    from repro.embed.trainer import DistributedEmbedding, EmbedConfig
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    from repro.api import Embedding, EmbedSpec
     Y = three_loops(n_per=24, loops=2, dim=8)
-    cfg = EmbedConfig(kind="ee", lam=50.0, perplexity=8.0, max_iters=15,
-                      sparse=True, n_neighbors=20, n_negatives=8)
-    res = DistributedEmbedding(cfg, mesh).fit(Y)
+    spec = EmbedSpec(kind="ee", lam=50.0, perplexity=8.0, max_iters=15,
+                     backend="sparse", n_neighbors=20, n_negatives=8)
+    res = Embedding(spec).fit(Y).result_
     assert res.energies[-1] < res.energies[0]
     assert res.X.shape == (Y.shape[0], 2)
 
 
 @pytest.mark.parametrize("kind", ["ssne", "tsne"])
 def test_trainer_sparse_normalized_descends(kind):
-    """EmbedConfig(sparse=True) with a normalized kind routes through the
-    ratio-estimator backend (the pre-tentpole early ValueError is gone)."""
-    from repro.embed.trainer import DistributedEmbedding, EmbedConfig
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    """The sparse backend with a normalized kind routes through the
+    ratio-estimator objective."""
+    from repro.api import Embedding, EmbedSpec
     Y = three_loops(n_per=24, loops=2, dim=8)
-    cfg = EmbedConfig(kind=kind, lam=1.0, perplexity=8.0, max_iters=15,
-                      sparse=True, n_neighbors=20, n_negatives=8)
-    res = DistributedEmbedding(cfg, mesh).fit(Y)
+    spec = EmbedSpec(kind=kind, lam=1.0, perplexity=8.0, max_iters=15,
+                     backend="sparse", n_neighbors=20, n_negatives=8)
+    res = Embedding(spec).fit(Y).result_
     assert res.energies[-1] < res.energies[0]
     assert res.X.shape == (Y.shape[0], 2)
     assert np.all(np.isfinite(res.energies))

@@ -28,7 +28,7 @@ def _rand_graph(seed: int, n: int, k: int, d: int):
 def test_sparse_kernel_matches_oracle(n, k, d, br):
     X, idx, w = _rand_graph(0, n, k, d)
     r = ell_lap_matvec_ref(X, idx, w)
-    p = ops.ell_lap_matvec(X, idx, w, use_pallas=True, interpret=True,
+    p = ops.ell_lap_matvec(X, idx, w, impl="pallas", interpret=True,
                            block_rows=br, lane=8)
     np.testing.assert_allclose(
         np.asarray(p), np.asarray(r), rtol=5e-5,
@@ -41,7 +41,7 @@ def test_sparse_kernel_duplicate_columns_sum():
     w = jnp.ones((n, 4))
     X = jax.random.normal(jax.random.PRNGKey(0), (n, d))
     r = ell_lap_matvec_ref(X, idx, w)
-    p = ops.ell_lap_matvec(X, idx, w, use_pallas=True, interpret=True,
+    p = ops.ell_lap_matvec(X, idx, w, impl="pallas", interpret=True,
                            block_rows=8, lane=8)
     np.testing.assert_allclose(np.asarray(p), np.asarray(r), rtol=1e-5,
                                atol=1e-5)
@@ -52,7 +52,7 @@ def test_sparse_kernel_padding_rows_zero():
     for real rows must be unaffected and the pad sliced off."""
     n, k, d = 19, 4, 2
     X, idx, w = _rand_graph(1, n, k, d)
-    out = ops.ell_lap_matvec(X, idx, w, use_pallas=True, interpret=True,
+    out = ops.ell_lap_matvec(X, idx, w, impl="pallas", interpret=True,
                              block_rows=16, lane=8)
     assert out.shape == (n, d)
     np.testing.assert_allclose(np.asarray(out),
@@ -66,7 +66,7 @@ def test_sparse_kernel_on_calibrated_graph():
     g = saff.graph
     X = jax.random.normal(jax.random.PRNGKey(3), (48, 2))
     r = ell_lap_matvec_ref(X, g.indices, g.weights)
-    p = ops.ell_lap_matvec(X, g.indices, g.weights, use_pallas=True,
+    p = ops.ell_lap_matvec(X, g.indices, g.weights, impl="pallas",
                            interpret=True, block_rows=16, lane=8)
     np.testing.assert_allclose(np.asarray(p), np.asarray(r), rtol=5e-5,
                                atol=5e-6)

@@ -7,10 +7,10 @@ from tests._hypothesis_compat import given, settings, st
 
 from repro.core import (
     DiagH, FP, GD, SD, SDMinus, LBFGS, NonlinearCG,
-    LSConfig, energy_and_grad, make_affinities, minimize,
+    LSConfig, energy_and_grad, make_affinities,
     laplacian_eigenmaps, make_strategy,
 )
-from tests.conftest import three_loops
+from tests.conftest import dense_fit, three_loops
 
 ALL_STRATEGIES = [GD(), FP(), DiagH(), SD(), SDMinus(), LBFGS(m=10), NonlinearCG()]
 
@@ -80,9 +80,9 @@ def test_sd_beats_gd_in_fixed_iterations(problem):
     """The paper's headline: SD descends far deeper per iteration budget."""
     aff, X0 = problem
     lam = 100.0
-    r_gd = minimize(X0, aff, "ee", lam, GD(), max_iters=40, tol=0.0)
-    r_sd = minimize(X0, aff, "ee", lam, SD(), max_iters=40, tol=0.0,
-                    ls_cfg=LSConfig(init_step="adaptive_grow"))
+    r_gd = dense_fit(X0, aff, "ee", lam, GD(), max_iters=40, tol=0.0)
+    r_sd = dense_fit(X0, aff, "ee", lam, SD(), max_iters=40, tol=0.0,
+                     ls_cfg=LSConfig(init_step="adaptive_grow"))
     assert r_sd.energies[-1] < r_gd.energies[-1]
 
 
@@ -96,6 +96,6 @@ def test_make_strategy():
 def test_monotone_decrease(problem):
     aff, X0 = problem
     for strat in (SD(), SDMinus(), LBFGS(m=5)):
-        res = minimize(X0, aff, "ee", 50.0, strat, max_iters=25, tol=0.0)
+        res = dense_fit(X0, aff, "ee", 50.0, strat, max_iters=25, tol=0.0)
         e = res.energies
         assert np.all(np.diff(e) <= 1e-3 * np.maximum(np.abs(e[:-1]), 1.0)), strat.name

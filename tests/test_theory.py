@@ -5,13 +5,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import (SD, LSConfig, energy, energy_and_grad,
-                        make_affinities, minimize)
+                        make_affinities)
 from repro.configs import RunConfig, get_smoke_config
 from repro.configs.base import ShapeConfig
 from repro.data import batch_for
 from repro.models import build_model, init_train_state, make_train_step
 from repro.optim.adamw import AdamWConfig
-from tests.conftest import three_loops
+from tests.conftest import dense_fit, three_loops
 
 
 def test_sd_is_newton_at_lambda_zero():
@@ -40,13 +40,13 @@ def test_locally_linear_rate_improves_with_better_B():
     lam = 20.0
     # get near a minimum first
     X0 = jax.random.normal(jax.random.PRNGKey(1), (Y.shape[0], 2)) * 0.5
-    res = minimize(X0, aff, "ee", lam, SD(), max_iters=150, tol=1e-10,
-                   ls_cfg=LSConfig(init_step="adaptive_grow"))
+    res = dense_fit(X0, aff, "ee", lam, SD(), max_iters=150, tol=1e-10,
+                    ls_cfg=LSConfig(init_step="adaptive_grow"))
     Xstar_ish = res.X
 
     def contraction(strat, ls):
-        r = minimize(Xstar_ish, aff, "ee", lam, strat, max_iters=6, tol=0.0,
-                     ls_cfg=LSConfig(init_step=ls))
+        r = dense_fit(Xstar_ish, aff, "ee", lam, strat, max_iters=6, tol=0.0,
+                      ls_cfg=LSConfig(init_step=ls))
         g = r.grad_norms
         ratios = g[1:] / np.maximum(g[:-1], 1e-30)
         return float(np.median(ratios))
@@ -89,7 +89,7 @@ def test_extension_kinds_minimize():
     for kind in ("tee", "epan"):
         aff = make_affinities(Y, 6.0, model=kind)
         X0 = jax.random.normal(jax.random.PRNGKey(2), (Y.shape[0], 2)) * 0.3
-        res = minimize(X0, aff, kind, 10.0, SD(), max_iters=40, tol=0.0,
-                       ls_cfg=LSConfig(init_step="adaptive_grow"))
+        res = dense_fit(X0, aff, kind, 10.0, SD(), max_iters=40, tol=0.0,
+                        ls_cfg=LSConfig(init_step="adaptive_grow"))
         assert res.energies[-1] < res.energies[0]
         assert np.all(np.isfinite(res.energies)), kind
